@@ -318,13 +318,20 @@ def run_scheduler_kill(n_nodes: int = 16, n_pods: int = 48,
         means nothing device-shaped ran — untested protection = failure)
       - 0 confirmed invariant violations, no pod lost or left unbound
         (covers duplicate binds and stale-state mistakes post-resync)
+
+    The one leg that IGNORES ``JAX_COMPILATION_CACHE_DIR``, by design: its
+    first child must boot cold, so the cache is an owned ``aotCacheDir``
+    under this run's ``mkdtemp`` and the variable is withheld from the
+    children (an outside cache would be warm from some earlier run).
     """
     from kubernetes_tpu.chaos.apiserver import InProcessApiServer
     from kubernetes_tpu.chaos.scheduler import SchedulerProcess
     from kubernetes_tpu.client.clientset import HTTPClient
     from kubernetes_tpu.testing.wrappers import make_node, make_pod
 
+    from kubernetes_tpu.parallel.aot import CACHE_DIR_ENV
     data_dir = tempfile.mkdtemp(prefix="ktpu-schedkill-")
+    outside_cache = os.environ.pop(CACHE_DIR_ENV, None)  # spawn inherits env
     result: dict = {"case": "SchedulerKill",
                     "workload": f"{n_nodes}nodes_{n_pods}pods"}
     failures: list[str] = []
@@ -526,6 +533,8 @@ def run_scheduler_kill(n_nodes: int = 16, n_pods: int = 48,
                 except Exception:
                     pass
         shutil.rmtree(data_dir, ignore_errors=True)
+        if outside_cache is not None:
+            os.environ[CACHE_DIR_ENV] = outside_cache
     result["slo_failures"] = failures
     return result
 
@@ -535,6 +544,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     _log = lambda *a: print(*a, file=sys.stderr)
     case = os.environ.get("BENCH_DISASTER_CASE", "apiserver")
     if case == "scheduler-kill":

@@ -350,6 +350,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     res = run_fleet_churn(
         n_tenants=int(os.environ.get("BENCH_FLEET_TENANTS", "4")),
         nodes_per_tenant=int(os.environ.get("BENCH_FLEET_NODES", "8")),

@@ -120,6 +120,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     res = run_kubemark(
         n_hollow=int(os.environ.get("BENCH_KUBEMARK_NODES", "500")),
         n_pods=int(os.environ.get("BENCH_KUBEMARK_PODS", "1000")),

@@ -286,7 +286,10 @@ if __name__ == "__main__":
     import json
     import os
     import sys
-
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     res = run_planner_loop(
         n_nodes=int(os.environ.get("BENCH_PLANNER_NODES", "8")),
         window_cycles=int(os.environ.get("BENCH_PLANNER_CYCLES", "6")),

@@ -80,6 +80,8 @@ def run_preemption(n_nodes: int = 5000, n_preemptors: int = 256,
 
 if __name__ == "__main__":
     import json
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     res = run_preemption(
         n_nodes=int(os.environ.get("BENCH_PREEMPT_NODES", "5000")),
         n_preemptors=int(os.environ.get("BENCH_PREEMPT_PODS", "256")),

@@ -268,6 +268,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     sizes = [int(t) for t in os.environ.get(
         "BENCH_SCALE_NODES", "256 2048").replace(",", " ").split()]
     res = run_scale_fleet(

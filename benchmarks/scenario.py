@@ -210,6 +210,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     spec = os.environ.get("BENCH_SCENARIO", "builtin:smoke")
     res = run_scenario_replay(
         spec="builtin:smoke" if spec in ("", "1") else spec,

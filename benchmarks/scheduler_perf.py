@@ -364,4 +364,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     raise SystemExit(main())

@@ -192,6 +192,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     res = run_slice_carve(
         grid=os.environ.get("BENCH_SLICE_GRID", "4x4x2"),
         shape=os.environ.get("BENCH_SLICE_SHAPE", "2x2x2"),

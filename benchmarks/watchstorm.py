@@ -419,6 +419,8 @@ if __name__ == "__main__":
     import sys
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()
     _log = lambda *a: print(*a, file=sys.stderr)  # noqa: E731
     res = run_watch_storm(
         n_watchers=int(os.environ.get("BENCH_WATCHSTORM_WATCHERS",

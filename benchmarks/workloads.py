@@ -118,11 +118,10 @@ def mixed_heterogeneous(pods: int = 10000, nodes: int = 5000, seed: int = 0):
 
 
 def huge_cluster(pods: int = 4096, nodes: int = 16384, seed: int = 0):
-    """Beyond-threshold scale: crosses ops/topology.py's
-    ``_FACTORED_THRESHOLD`` (8192 nodes) so domain counting runs the
-    factored O(N+V) formulation instead of one-hot matmuls — the 50k-node
-    scaling design point. Hard AND soft spread constraints so both the
-    filter and scoring factored paths execute."""
+    """Past 8192 nodes, where an [N,N] same-domain matrix stops fitting:
+    ops/topology.py's O(N+V) scatter-per-value domain counting at the
+    50k-node scaling design point. Hard AND soft spread constraints so
+    both the filter and the scoring use of it execute."""
     rng = random.Random(seed)
     ns = []
     for i in range(nodes):

@@ -237,7 +237,9 @@ class ParitySentinel:
         self.every = max(0, int(every))
         self._breaker_ref = breaker_ref
         self._audit_dir = audit_dir
-        self._max_backlog = max_backlog
+        # samples are SHED (counted in ``skipped``) past this many queued
+        # verdicts; a harness that must judge every sample raises it
+        self.max_backlog = max_backlog
         self._q: "queue_mod.Queue" = queue_mod.Queue()
         self._thread: Optional[threading.Thread] = None
         self._spawn_lock = threading.Lock()
@@ -308,7 +310,7 @@ class ParitySentinel:
 
     def submit_drain(self, capture: dict, winners: list,
                      prior_winners: list) -> None:
-        if self._q.qsize() >= self._max_backlog:
+        if self._q.qsize() >= self.max_backlog:
             self.skipped += 1
             return
         capture["winners"] = list(winners)
@@ -332,7 +334,7 @@ class ParitySentinel:
         self._n_wave += 1
         if self._n_wave % self.every:
             return
-        if self._q.qsize() >= self._max_backlog:
+        if self._q.qsize() >= self.max_backlog:
             self.skipped += 1
             return
         self.samples["wave"] += 1
@@ -357,7 +359,7 @@ class ParitySentinel:
         self._n_carve += 1
         if self._n_carve % self.every:
             return
-        if self._q.qsize() >= self._max_backlog:
+        if self._q.qsize() >= self.max_backlog:
             self.skipped += 1
             return
         self.samples["carve"] += 1
@@ -451,6 +453,8 @@ class ParitySentinel:
                 "samples": dict(self.samples),
                 "divergences": self.divergences,
                 "skipped": self.skipped,
+                # submitted samples whose verdict has not landed yet
+                "pending": self._q.unfinished_tasks,
                 "lastDivergence": self.last_divergence}
 
     def drain(self, timeout: float = 5.0) -> None:

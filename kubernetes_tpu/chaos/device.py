@@ -3,8 +3,8 @@
 Wraps the three device program entries the connected loop dispatches —
 ``gang_schedule`` (per-batch path), ``drain_step`` (fused drain), and
 ``preempt_wave`` (preemption storm) — so scheduled cycles raise
-compile/runtime errors the way a miscompiling jaxlib or a dropped TPU
-tunnel does (the ROADMAP's virtual-CPU GSPMD miscompiles are the live
+compile/runtime errors the way a miscompiling jaxlib or a lost device
+does (the ROADMAP's virtual-CPU GSPMD miscompiles are the live
 precedent). The scheduler's circuit breaker is the consumer: enough
 consecutive device failures must degrade mesh -> single-device -> the
 pure-numpy oracle instead of killing the loop.

@@ -67,6 +67,8 @@ def _run_scheduler(conn, url: str, cfg_dict: dict, warm: Optional[dict],
     t_entry = time.monotonic()
     import faulthandler
     faulthandler.enable()  # a native abort must leave thread tracebacks
+    from kubernetes_tpu.parallel.aot import place_compile_cache
+    place_compile_cache()  # before the runner: an owned aotCacheDir re-points
     from kubernetes_tpu.client.clientset import HTTPClient
     from kubernetes_tpu.config.types import SchedulerConfiguration
     from kubernetes_tpu.sched.runner import SchedulerRunner

@@ -646,7 +646,7 @@ def _aot_cache_line(ac: dict) -> str:
     what's on disk, how this boot used it, and whether anything had to be
     swept or recompiled."""
     if not ac.get("enabled"):
-        return "Compile cache: off (no aotCacheDir configured)\n"
+        return "Compile cache: off (no cache directory placed)\n"
     if ac.get("error"):
         return f"Compile cache: on — {ac['error']}\n"
     mb = (ac.get("bytes") or 0) / 1e6

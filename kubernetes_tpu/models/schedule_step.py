@@ -44,7 +44,7 @@ def evaluate(ct: ClusterTensors, pb: PodBatch, seed: int = 0,
     context (no intra-batch interactions — gang.py supplies those).
 
     ``topo_keys``: static tuple of distinct topology key-ids in play
-    (meta.topo_keys) — unrolls into a handful of [N,N] domain matmuls.
+    (meta.topo_keys) — unrolls into a handful of per-key domain aggregations.
     ``weights`` / ``enabled_filters``: the active profile's plugin config
     (None = reference defaults / all filters). ``ext_mask``/``ext_scores``
     [P,N]: host-computed scheduler-extender feasibility veto and weighted

@@ -1,4 +1,4 @@
-"""Relational plugins: PodTopologySpread and InterPodAffinity as MXU matmuls.
+"""Relational plugins: PodTopologySpread and InterPodAffinity on the device.
 
 Reference semantics:
   PodTopologySpread  podtopologyspread/{common,filtering,scoring}.go
@@ -6,15 +6,22 @@ Reference semantics:
                      existing-pod anti-affinity *symmetry* veto)
 
 The reference precomputes per-domain pod counts in PreFilter with pods x nodes
-Go loops. The TPU design factors the counting into one-hot matmuls:
+Go loops. The TPU design factors the counting into a one-hot matmul and a
+scatter/gather over interned domain VALUES:
 
     match[E,P,T]   selector match of each term against existing pods
     cnt_pn[P,T,N]  = match x onehot(epod_node)        (contraction over E)
-    cnt_dom[P,T,N] = cnt_pn x same_domain_k[N,N]      (contraction over N)
+    cnt_val[P,T,V] = scatter-add of cnt_pn by each node's domain value
+    cnt_dom[P,T,N] = cnt_val gathered back per node
 
-same_domain_k is per *distinct topology key* (zone, hostname, ...), a static
-Python tuple at trace time — there are only ever a handful, so the loop
-unrolls into a few [N,N] matmuls that XLA tiles onto the systolic array.
+The aggregation is per *distinct topology key* (zone, hostname, ...), a
+static Python tuple at trace time — there are only ever a handful, so the
+loop unrolls. Memory is O(P*T*(N+V)) and the counts are float32 sums of
+integers, exact to 2^24. Not a cnt_pn x same_domain_k[N,N] matmul: a
+TPU's default matmul precision rounds the count operand to bfloat16 — 301
+matching pods on a node read as 300 and a hard spread admits a node the
+oracle refuses (chip_smoke.py count_precision) — and at 5,000 nodes the
+scatter/gather is also the faster of the two on the v5e (PERF.md, PR 21).
 
 Namespace semantics: a term with no explicit namespaces applies to the
 owning pod's own namespace; terms with ``namespaces``/``namespaceSelector``
@@ -30,34 +37,10 @@ minimum is 0 (filtering.go minMatchNum).
 
 from __future__ import annotations
 
-import os
-
 import jax.numpy as jnp
 
 from kubernetes_tpu.encode.snapshot import ClusterTensors, PodBatch
 from kubernetes_tpu.ops.exprs import eval_selector_set
-
-# Above this node count the [N,N] same-domain matmuls are replaced by a
-# FACTORED formulation — scatter-add per interned domain VALUE then gather
-# back per node: O(P*T*(N+V)) memory instead of O(N^2). The matmul rides
-# the MXU and wins at benchmark scale; the factored path is the blockwise/
-# long-context analog (SURVEY §5) that keeps 50k+-node clusters in HBM.
-# KTPU_DOMAIN_FACTORED=1/0 forces; unset = auto by threshold. The flag is
-# read at TRACE time: set it before the first compile (jit caches bake the
-# branch per tensor shape; toggling later does not recompile same-shape
-# programs). Auto mode is cache-consistent because the threshold is a pure
-# function of the static node-bucket shape.
-_FACTORED_THRESHOLD = 8192
-
-
-def _use_factored(n_nodes: int) -> bool:
-    flag = os.environ.get("KTPU_DOMAIN_FACTORED", "auto").lower()
-    if flag in ("1", "true", "on"):
-        return True
-    if flag in ("0", "false", "off"):
-        return False
-    return n_nodes > _FACTORED_THRESHOLD
-
 
 def _gather_ns(ns_mask, ids):
     """ns_mask [..., T, NSB] gathered at interned ids [M] -> [..., T, M]
@@ -120,7 +103,6 @@ def _domain_counts(ct: ClusterTensors, cnt_pn, term_topo, topo_keys,
     node-inclusion policies); ``want_domains`` additionally counts distinct
     domains with >=1 eligible node.
     """
-    N = ct.node_valid.shape[0]
     if elig is not None:
         cnt_pn = cnt_pn * elig.astype(jnp.float32)
     cnt_dom = jnp.zeros_like(cnt_pn)
@@ -128,8 +110,6 @@ def _domain_counts(ct: ClusterTensors, cnt_pn, term_topo, topo_keys,
     num_dom = jnp.zeros(cnt_pn.shape[:2], jnp.float32) if want_domains else None
     K = ct.node_labels.shape[1]
     V = ct.label_value_num.shape[0]
-    factored = _use_factored(int(N))
-    idx_n = jnp.arange(N)
     for k in topo_keys:
         if k < 0 or k >= K:
             continue
@@ -137,34 +117,20 @@ def _domain_counts(ct: ClusterTensors, cnt_pn, term_topo, topo_keys,
         present = dv >= 0
         sel = term_topo == k                                  # [P,T]
         dv_safe = jnp.clip(dv, 0, max(V - 1, 0))
-        if factored:
-            # scatter per-VALUE, gather per node: O(P*T*(N+V)), no [N,N]
-            src = cnt_pn * present[None, None, :].astype(jnp.float32)
-            cnt_val = jnp.zeros(cnt_pn.shape[:2] + (V,), jnp.float32) \
-                .at[:, :, dv_safe].add(src)                   # [P,T,V]
-            agg = cnt_val[:, :, dv_safe] * present[None, None, :]
-        else:
-            same = ((dv[:, None] == dv[None, :])
-                    & present[:, None] & present[None, :])
-            agg = jnp.einsum("ptn,nm->ptm", cnt_pn, same.astype(jnp.float32))
+        # scatter per-VALUE, gather per node: O(P*T*(N+V)), no [N,N]
+        src = cnt_pn * present[None, None, :].astype(jnp.float32)
+        cnt_val = jnp.zeros(cnt_pn.shape[:2] + (V,), jnp.float32) \
+            .at[:, :, dv_safe].add(src)                       # [P,T,V]
+        agg = cnt_val[:, :, dv_safe] * present[None, None, :]
         cnt_dom = jnp.where(sel[..., None], agg, cnt_dom)
         has_key = has_key | (sel[..., None] & present[None, None, :])
         if want_domains:
             ek = (present[None, None, :] if elig is None
                   else elig & present[None, None, :])         # [P,T,N]
-            if factored:
-                # distinct domains = distinct values hit by >=1 eligible node
-                hit = jnp.zeros(cnt_pn.shape[:2] + (V,), jnp.float32) \
-                    .at[:, :, dv_safe].add(ek.astype(jnp.float32))
-                nd_k = jnp.sum((hit > 0.0).astype(jnp.float32), axis=-1)
-            else:
-                # count nodes that are the FIRST eligible node of their
-                # domain (no eligible same-domain predecessor)
-                lower = (same & (idx_n[:, None] < idx_n[None, :])
-                         ).astype(jnp.float32)
-                prior = jnp.einsum("ptm,mn->ptn", ek.astype(jnp.float32),
-                                   lower) > 0.0
-                nd_k = jnp.sum((ek & ~prior).astype(jnp.float32), axis=-1)
+            # distinct domains = distinct values hit by >=1 eligible node
+            hit = jnp.zeros(cnt_pn.shape[:2] + (V,), jnp.float32) \
+                .at[:, :, dv_safe].add(ek.astype(jnp.float32))
+            nd_k = jnp.sum((hit > 0.0).astype(jnp.float32), axis=-1)
             num_dom = jnp.where(sel, nd_k, num_dom)
     return cnt_dom, has_key, num_dom
 
@@ -292,28 +258,20 @@ def interpod_symmetry_mask(ct: ClusterTensors, pb: PodBatch,
     veto = jnp.zeros((P, N), bool)
     K = ct.node_labels.shape[1]
     V = ct.label_value_num.shape[0]
-    factored = _use_factored(int(N))
     for k in topo_keys:
         if k < 0 or k >= K:
             continue
         dv = ct.node_labels[:, k]                             # [N]
-        E = ct.epod_node.shape[0]
         dv_e = dv[jnp.clip(ct.epod_node, 0, max(N - 1, 0))]
         dv_e = jnp.where(ct.epod_node >= 0, dv_e, -1)         # [E]
         wm = jnp.any(m & (ct.ea_topo == k)[None], axis=-1)    # [P,E]
-        if factored:
-            # veto per VALUE then gather per node: no [E,N] materialization
-            dve_safe = jnp.clip(dv_e, 0, max(V - 1, 0))
-            src = (wm & (dv_e >= 0)[None, :]).astype(jnp.float32)
-            vv = jnp.zeros((P, V), jnp.float32) \
-                .at[:, dve_safe].add(src)                     # [P,V]
-            dv_safe = jnp.clip(dv, 0, max(V - 1, 0))
-            veto |= (vv[:, dv_safe] > 0.0) & (dv >= 0)[None, :]
-        else:
-            same = ((dv_e[:, None] == dv[None, :])
-                    & (dv_e[:, None] >= 0))                   # [E,N]
-            veto |= jnp.einsum("pe,en->pn", wm.astype(jnp.float32),
-                               same.astype(jnp.float32)) > 0.0
+        # veto per VALUE then gather per node: no [E,N] materialization
+        dve_safe = jnp.clip(dv_e, 0, max(V - 1, 0))
+        src = (wm & (dv_e >= 0)[None, :]).astype(jnp.float32)
+        vv = jnp.zeros((P, V), jnp.float32) \
+            .at[:, dve_safe].add(src)                         # [P,V]
+        dv_safe = jnp.clip(dv, 0, max(V - 1, 0))
+        veto |= (vv[:, dv_safe] > 0.0) & (dv >= 0)[None, :]
     return ~veto
 
 

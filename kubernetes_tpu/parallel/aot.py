@@ -1,7 +1,8 @@
-"""AOT lowering/serialization helpers + the honest compile meter.
+"""AOT lowering/serialization helpers, the honest compile meter, and the
+one place that decides where the persistent compile cache lives.
 
-Two small pieces the durable executable cache (sched/aotcache.py) and the
-benchmarks build on:
+Three small pieces the durable executable cache (sched/aotcache.py), the
+process entry points and the benchmarks build on:
 
 1. ``lowering_fingerprint`` — one string that changes iff a cached
    compiled program could be invalid for THIS process: jax/jaxlib
@@ -19,6 +20,15 @@ benchmarks build on:
    hit events; the meter tracks all three so a "ZERO compiles" gate can
    be asserted honestly with the cache on, and degrades to the old
    meaning (hits are simply 0) with it off.
+
+3. ``place_compile_cache`` — where jax's persistent compilation cache
+   goes. ``JAX_COMPILATION_CACHE_DIR`` (placed from OUTSIDE the program)
+   always wins and is never re-pointed or cleaned by this code; without
+   it, process entry points (chip_smoke.py, bench.py, the benchmarks'
+   mains, ``ktpu-up``, the chaos scheduler child) use ONE fixed,
+   git-ignored directory inside the checkout — the path is part of the
+   cache key, so a directory that moves never hits. Library construction
+   of a ``SchedulerRunner`` places nothing (tier-1 stays cache-free).
 
 ``serialize_compiled``/``deserialize_compiled`` wrap
 ``jax.experimental.serialize_executable`` for explicit per-executable
@@ -38,6 +48,53 @@ from typing import Optional
 _METER_LOCK = threading.Lock()
 _METER: Optional["CompileMeter"] = None
 
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+# parallel/ -> kubernetes_tpu/ -> the checkout. Fixed on purpose: never a
+# temp name, a pid or a time (listed in .gitignore).
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_compile_cache")
+
+
+def external_cache_dir() -> Optional[str]:
+    """The cache directory placed from outside the program, or None."""
+    return os.environ.get(CACHE_DIR_ENV) or None
+
+
+def point_jax_cache_at(path: Optional[str]) -> None:
+    """Point jax's persistent compilation cache at ``path`` (None
+    detaches it). jax decides ONCE per process whether a cache is in use
+    and keeps the directory handle open, so a changed directory only
+    takes effect after ``reset_cache``."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    if jax.config.jax_compilation_cache_dir == path:
+        return
+    jax.config.update("jax_compilation_cache_dir", path)
+    cc.reset_cache()
+
+
+def persist_every_program() -> None:
+    """Drop jax's persist thresholds (entries under 1 s of compile time
+    are skipped by default): a zero-compile gate counts the tiny staging
+    jits too, so every warmed program must persist."""
+    import jax
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+
+
+def place_compile_cache() -> str:
+    """Process entry points call this once, before their first jit.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set jax already reads it and no
+    directory is set here; otherwise the cache goes to
+    ``DEFAULT_COMPILE_CACHE_DIR``. Returns the directory in effect."""
+    path = external_cache_dir()
+    if path is None:
+        path = DEFAULT_COMPILE_CACHE_DIR
+        point_jax_cache_at(path)
+    persist_every_program()
+    return path
+
 
 def lowering_fingerprint(knobs: Optional[dict] = None) -> str:
     """Hex digest of everything that must match for a cached executable
@@ -45,22 +102,17 @@ def lowering_fingerprint(knobs: Optional[dict] = None) -> str:
     lowering-relevant config (mesh shape, donation mode, ...); it must be
     JSON-serializable with a stable ordering."""
     import jax
-    backend = None
     try:
         backend = jax.devices()[0]
         device = {"platform": backend.platform,
-                  "kind": getattr(backend, "device_kind", "?"),
+                  "kind": backend.device_kind,
                   "count": jax.device_count()}
     except Exception:  # ktpu-lint: disable=KTL002 -- no backend yet is a legitimate state; the fingerprint records the absence
         device = {"platform": None, "kind": None, "count": 0}
-    try:
-        import jaxlib.version
-        jaxlib_v = jaxlib.version.__version__
-    except Exception:  # ktpu-lint: disable=KTL002 -- jaxlib layout varies across toolchains; "?" still participates in the digest
-        jaxlib_v = "?"
+    import jaxlib.version
     doc = {
         "jax": jax.__version__,
-        "jaxlib": jaxlib_v,
+        "jaxlib": jaxlib.version.__version__,
         "device": device,
         "xlaFlags": os.environ.get("XLA_FLAGS", ""),
         "knobs": knobs or {},

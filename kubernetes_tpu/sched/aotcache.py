@@ -35,6 +35,16 @@ that directory is the durability discipline the WAL established:
   bound         a size/rotation GC evicts oldest-read entries past
                 ``max_bytes`` (counted as ``reason="rotation"``).
 
+Ownership: all of the above applies ONLY to a directory the operator
+gave this scheduler (``aotCacheDir``). Where the cache was placed from
+outside — ``JAX_COMPILATION_CACHE_DIR``, or an entry point's fixed
+default (parallel/aot.place_compile_cache) — the directory may be shared
+with other programs and other configurations, so the runner attaches a
+``PlacedCacheObserver`` instead: it never re-points jax, writes no
+fingerprint or manifest, and deletes nothing; the compile meter, the
+boot entry count (which still arms the first-drain canary) and the
+``ktpu status`` line work the same.
+
 Correctness backstop: a loaded executable is canary-checked on first
 use — the runner forces the ParitySentinel to sample the FIRST drain
 dispatch after a warm-from-cache boot, so a wrong program trips the
@@ -58,7 +68,13 @@ from kubernetes_tpu.metrics.registry import (
     AOT_CACHE_ERRORS,
     AOT_CACHE_INVALIDATIONS,
 )
-from kubernetes_tpu.parallel.aot import compile_meter, lowering_fingerprint
+from kubernetes_tpu.parallel.aot import (
+    compile_meter,
+    external_cache_dir,
+    lowering_fingerprint,
+    persist_every_program,
+    point_jax_cache_at,
+)
 from kubernetes_tpu.utils.atomicio import atomic_write_json
 
 _LOG = logging.getLogger(__name__)
@@ -275,31 +291,14 @@ class AotExecutableCache:
         self._sealed_sig = self._dir_sig()
 
     def _arm_jax(self) -> None:
-        import jax
-        try:
-            # a prior activation in this process (tests, A/B benches) may
-            # have armed a different directory; drop its handle first
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # ktpu-lint: disable=KTL002 -- private-module best effort: absent reset just means first activation wins for already-open handles
-            pass
-        jax.config.update("jax_compilation_cache_dir", self.entries_dir)
-        # every warmed program must persist, however small/fast it
-        # compiled — the zero-compile gate counts the tiny staging jits too
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        point_jax_cache_at(self.entries_dir)
+        persist_every_program()
 
     @staticmethod
     def disarm() -> None:
         """Detach jax from any cache directory (tests restore the
         process-global default)."""
-        import jax
-        try:
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-        except Exception:  # ktpu-lint: disable=KTL002 -- private-module best effort mirror of _arm_jax's reset
-            pass
-        jax.config.update("jax_compilation_cache_dir", None)
+        point_jax_cache_at(None)
 
     # ---- steady state ----------------------------------------------------
 
@@ -363,19 +362,70 @@ class AotExecutableCache:
                  "invalidations": self.invalidations,
                  "bootEntries": self.boot.get("entries"),
                  "bootLoadMs": self.boot.get("loadMs")}
-        if self._meter_base is not None:
-            now = compile_meter().snapshot()
-            base = self._meter_base
-            stats["hits"] = now["cacheHits"] - base["cacheHits"]
-            stats["misses"] = now["cacheMisses"] - base["cacheMisses"]
-            stats["realCompiles"] = compile_meter().real_compiles(base, now)
+        stats.update(_meter_since(self._meter_base))
         return stats
 
 
+def _meter_since(base: Optional[dict]) -> dict:
+    """Persistent-cache traffic and genuine compiles since ``base``."""
+    if base is None:
+        return {}
+    now = compile_meter().snapshot()
+    return {"hits": now["cacheHits"] - base["cacheHits"],
+            "misses": now["cacheMisses"] - base["cacheMisses"],
+            "realCompiles": compile_meter().real_compiles(base, now)}
+
+
+class PlacedCacheObserver:
+    """A compile-cache directory jax was pointed at from OUTSIDE
+    (``JAX_COMPILATION_CACHE_DIR``, or an entry point's
+    place_compile_cache): other programs and configurations may share it,
+    so it is only observed — entry count, bytes, the compile meter — and
+    never re-pointed, written to or cleaned. The surface the runner uses
+    on AotExecutableCache: ``activate()`` -> ``boot``, ``seal()``,
+    ``stats()``."""
+
+    def __init__(self, root: str):
+        self.root = os.path.abspath(root)
+        self.boot: dict = {}
+        self._meter_base: Optional[dict] = None
+
+    def _entries(self) -> tuple[int, int]:
+        try:
+            sizes = [e.stat().st_size for e in os.scandir(self.root)
+                     if e.name.endswith(ENTRY_SUFFIX)]
+        except OSError:
+            sizes = []
+        return len(sizes), sum(sizes)
+
+    def activate(self) -> dict:
+        n, n_bytes = self._entries()
+        self._meter_base = compile_meter().snapshot()
+        self.boot = {"entries": n, "bytes": n_bytes, "loadMs": 0.0}
+        _LOG.info("compile cache placed from outside at %s, observing "
+                  "only: %d entries (%.1f KB)", self.root, n, n_bytes / 1e3)
+        return self.boot
+
+    def seal(self, force: bool = False) -> int:
+        return self._entries()[0]  # nothing of ours to commit
+
+    def stats(self) -> dict:
+        n, n_bytes = self._entries()
+        return {"enabled": True, "dir": self.root, "entries": n,
+                "bytes": n_bytes, "errors": 0, "invalidations": 0,
+                "bootEntries": self.boot.get("entries"),
+                "bootLoadMs": self.boot.get("loadMs"),
+                **_meter_since(self._meter_base)}
+
+
 def resolve_cache_dir(cfg) -> Optional[str]:
-    """The effective cache directory: ``KTPU_AOT_CACHE`` overrides
-    config (``"0"``/``"off"`` disable; any other value is a path), else
-    ``cfg.aot_cache_dir``; None = disabled (the tier-1 default)."""
+    """The cache directory this scheduler OWNS: ``KTPU_AOT_CACHE``
+    overrides config (``"0"``/``"off"`` disable; any other value is a
+    path), else ``cfg.aot_cache_dir``; None = none owned (the tier-1
+    default). Always None under ``JAX_COMPILATION_CACHE_DIR`` — a cache
+    placed from outside is never re-pointed (see ``runner_cache``)."""
+    if external_cache_dir() is not None:
+        return None
     env = os.environ.get("KTPU_AOT_CACHE")
     if env is not None:
         s = env.strip()
@@ -383,6 +433,22 @@ def resolve_cache_dir(cfg) -> Optional[str]:
             return None
         return s
     return getattr(cfg, "aot_cache_dir", None)
+
+
+def runner_cache(cfg):
+    """The cache object a SchedulerRunner boots with, not yet activated:
+    the owned ``aotCacheDir`` with its full discipline, else an observer
+    over whatever directory jax was pointed at from outside (the
+    environment variable, or an entry point's place_compile_cache), else
+    None (library construction under tier-1)."""
+    owned = resolve_cache_dir(cfg)
+    if owned:
+        return AotExecutableCache(
+            owned, knobs=cache_knobs(cfg),
+            max_bytes=cfg.aot_cache_max_mb * 1024 * 1024)
+    import jax
+    placed = jax.config.jax_compilation_cache_dir
+    return PlacedCacheObserver(placed) if placed else None
 
 
 def cache_knobs(cfg) -> dict:

@@ -80,7 +80,7 @@ class SchedulerRunner:
         if hasattr(client, "default_user_agent"):
             client.default_user_agent("kube-scheduler")
         # GIL tuning for the connected deployment shape: informer bursts
-        # (thousands of JSON decodes) and the device tunnel share one
+        # (thousands of JSON decodes) and the device fetches share one
         # interpreter; a finer switch interval caps how long either side
         # can starve the other between checks. Opt-in via env so library
         # embedders keep the interpreter default.
@@ -98,25 +98,19 @@ class SchedulerRunner:
         # never raises on cache damage; a cache too broken to use degrades
         # to plain recompiles.
         self.aot_cache = None
-        from kubernetes_tpu.sched.aotcache import (AotExecutableCache,
-                                                   cache_knobs,
-                                                   resolve_cache_dir)
-        cache_dir = resolve_cache_dir(self.cfg)
-        if cache_dir:
-            try:
-                self.aot_cache = AotExecutableCache(
-                    cache_dir, knobs=cache_knobs(self.cfg),
-                    max_bytes=self.cfg.aot_cache_max_mb * 1024 * 1024)
+        from kubernetes_tpu.sched.aotcache import runner_cache
+        try:
+            self.aot_cache = runner_cache(self.cfg)
+            if self.aot_cache is not None:
                 self.aot_cache.activate()
-            except Exception:
-                # the cache is an accelerant, never a dependency: a scheduler
-                # that cannot arm it runs cold, it does not stay down
-                from kubernetes_tpu.metrics.registry import AOT_CACHE_ERRORS
-                AOT_CACHE_ERRORS.inc({"reason": "activate"})
-                _LOG.exception("AOT cache activation failed at %s; "
-                               "running without executable persistence",
-                               cache_dir)
-                self.aot_cache = None
+        except Exception:
+            # the cache is an accelerant, never a dependency: a scheduler
+            # that cannot arm it runs cold, it does not stay down
+            from kubernetes_tpu.metrics.registry import AOT_CACHE_ERRORS
+            AOT_CACHE_ERRORS.inc({"reason": "activate"})
+            _LOG.exception("AOT cache activation failed; running without "
+                           "executable persistence")
+            self.aot_cache = None
         self.cache = SchedulerCache(assume_ttl=self.cfg.assume_ttl_s)
         self.queue = self._build_queue(self.cfg)
         self.scheduler = Scheduler(self.cfg, self.cache, self.queue, self._bind,

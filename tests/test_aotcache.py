@@ -21,6 +21,7 @@ from kubernetes_tpu.sched.aotcache import (
     FINGERPRINT_FILE,
     MANIFEST_FILE,
     AotExecutableCache,
+    PlacedCacheObserver,
     cache_knobs,
     resolve_cache_dir,
 )
@@ -181,3 +182,76 @@ def test_cache_knobs_cover_lowering_config():
     from kubernetes_tpu.parallel.aot import lowering_fingerprint
     flipped = dict(knobs, fusedFold=not knobs["fusedFold"])
     assert lowering_fingerprint(knobs) != lowering_fingerprint(flipped)
+
+
+# ---- a cache placed from outside ---------------------------------------------
+
+def _tree(root) -> dict:
+    out = {}
+    for d, _dirs, files in os.walk(root):
+        for f in files:
+            p = os.path.join(d, f)
+            out[os.path.relpath(p, root)] = open(p, "rb").read()
+    return out
+
+
+def test_outside_cache_dir_is_never_repointed_or_cleaned(
+        monkeypatch, tmp_path, cache_root):
+    """JAX_COMPILATION_CACHE_DIR wins over aotCacheDir: the runner
+    observes that directory — jax stays pointed at it, and nothing in it
+    is deleted or rewritten, whatever this scheduler's knobs say about
+    the fingerprint another program left there."""
+    from kubernetes_tpu.client.clientset import HTTPClient
+    from kubernetes_tpu.config.types import SchedulerConfiguration
+    from kubernetes_tpu.sched.runner import SchedulerRunner
+    from kubernetes_tpu.store.apiserver import APIServer
+    outside = tmp_path / "outside"
+    outside.mkdir()
+    # what another cell sharing the directory could have left behind
+    (outside / f"jit_other{ENTRY_SUFFIX}").write_bytes(b"not ours")
+    (outside / "jit_other-atime").write_bytes(b"0")
+    (outside / FINGERPRINT_FILE).write_text('{"fingerprint": "someone-else"}')
+    (outside / MANIFEST_FILE).write_text('{"entries": {}}')
+    before = _tree(outside)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(outside))
+    # jax reads the variable at import; this process imported it earlier
+    jax.config.update("jax_compilation_cache_dir", str(outside))
+    server = APIServer().start()
+    try:
+        cfg = SchedulerConfiguration(aot_cache_dir=cache_root, batch_size=8)
+        assert resolve_cache_dir(cfg) is None
+        runner = SchedulerRunner(HTTPClient(server.url), cfg)
+        try:
+            assert jax.config.jax_compilation_cache_dir == str(outside)
+            assert runner.aot_cache is not None
+            assert isinstance(runner.aot_cache, PlacedCacheObserver)
+            assert runner.aot_cache.boot["entries"] == 1
+            runner.aot_cache.seal(force=True)
+            st = runner._aot_cache_status()
+            assert st["enabled"] and st["dir"] == str(outside)
+            assert st["realCompiles"] >= 0  # the meter still reads
+        finally:
+            runner.scheduler.close()
+    finally:
+        server.stop()
+    assert _tree(outside) == before
+    assert not os.path.exists(cache_root)  # the owned dir was never armed
+
+
+def test_place_compile_cache_fixed_path_or_outside(monkeypatch, tmp_path,
+                                                   cache_root):
+    from kubernetes_tpu.parallel import aot
+    monkeypatch.delenv(aot.CACHE_DIR_ENV, raising=False)
+    monkeypatch.setattr(aot, "DEFAULT_COMPILE_CACHE_DIR",
+                        str(tmp_path / "fixed"))
+    assert aot.place_compile_cache() == str(tmp_path / "fixed")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0
+    # placed from outside: returned as is, jax's setting left alone
+    monkeypatch.setenv(aot.CACHE_DIR_ENV, str(tmp_path / "outside"))
+    assert aot.place_compile_cache() == str(tmp_path / "outside")
+    assert jax.config.jax_compilation_cache_dir == str(tmp_path / "fixed")
+    # the default is one fixed directory inside the checkout
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.undo()
+    assert os.path.dirname(aot.DEFAULT_COMPILE_CACHE_DIR) == repo

@@ -258,10 +258,9 @@ def test_spread_node_taints_policy_honor_shrinks_min():
 
 # ----------------------------------------------------- spread matchLabelKeys
 
-def test_factored_domain_counts_bit_parity(monkeypatch):
-    """The factored (scatter-per-value) domain path used above the node
-    threshold must be bit-identical to the [N,N] matmul path — same masks,
-    same scores, on a workload exercising spread (minDomains + policies),
+def test_domain_counts_random_mix_matches_oracle():
+    """The scatter-per-value domain counting against the serial oracle on
+    a random workload exercising spread (minDomains + policies),
     (anti-)affinity across namespaces, and the symmetry veto."""
     import random
 
@@ -291,20 +290,8 @@ def test_factored_domain_counts_bit_parity(monkeypatch):
                            namespaces=["default", "team-a"])
         pods.append(w.obj())
 
-    def full_eval():
-        enc = SnapshotEncoder()
-        ct, meta = enc.encode_cluster(nodes, bound, pending_pods=pods)
-        pb = enc.encode_pods(pods, meta)
-        res = evaluate(ct, pb, topo_keys=meta.topo_keys)
-        return (np.asarray(res.feasible)[:len(pods), :len(nodes)],
-                np.asarray(res.scores)[:len(pods), :len(nodes)])
-
-    monkeypatch.setenv("KTPU_DOMAIN_FACTORED", "0")
-    feas_mm, scores_mm = full_eval()
-    monkeypatch.setenv("KTPU_DOMAIN_FACTORED", "1")
-    feas_f, scores_f = full_eval()
-    np.testing.assert_array_equal(feas_mm, feas_f)
-    np.testing.assert_array_equal(scores_mm, scores_f)
+    tm = both_masks(nodes, pods, bound)
+    assert tm.any() and not tm.all()  # the workload decides something
 
 
 def test_spread_match_label_keys():
@@ -326,12 +313,11 @@ def test_spread_match_label_keys():
     np.testing.assert_array_equal(tm, [[True, True], [False, True]])
 
 
-def test_factored_boundary_parity_at_threshold_scale():
-    """Factored vs matmul domain counting agree AT the switchover scale:
-    one node past _FACTORED_THRESHOLD (8192), so the default path really is
-    the factored O(N+V) formulation, diffed against the forced-matmul path
-    on identical inputs (VERDICT r2: the boundary was only tested at toy N)."""
-    import os
+def test_domain_counts_match_oracle_past_8192_nodes():
+    """One node bucket past 8,192 (where an [N,N] formulation stopped
+    fitting and the scatter-per-value one was first introduced): hard and
+    soft zone spreads agree with the oracle at that scale, not only at
+    toy N (VERDICT r2)."""
     N = 8192 + 8
     nodes = [make_node(f"n{i}").capacity({"cpu": "8", "pods": "16"})
              .label("zone", f"z{i % 16}").obj() for i in range(N)]
@@ -341,24 +327,5 @@ def test_factored_boundary_parity_at_threshold_scale():
             .spread(1, "zone", "DoNotSchedule", {"app": "web"})
             .spread(2, "zone", "ScheduleAnyway", {"app": "web"})
             .obj() for i in range(4)]
-
-    def full_eval():
-        enc = SnapshotEncoder()
-        ct, meta = enc.encode_cluster(nodes, bound, pending_pods=pods)
-        pb = enc.encode_pods(pods, meta)
-        res = evaluate(ct, pb, topo_keys=meta.topo_keys)
-        return (np.asarray(res.feasible)[:len(pods), :N],
-                np.asarray(res.scores)[:len(pods), :N])
-
-    prev = os.environ.pop("KTPU_DOMAIN_FACTORED", None)
-    try:
-        feas_auto, scores_auto = full_eval()   # auto: factored (N > 8192)
-        os.environ["KTPU_DOMAIN_FACTORED"] = "0"
-        feas_mm, scores_mm = full_eval()       # forced matmul
-    finally:
-        if prev is None:
-            os.environ.pop("KTPU_DOMAIN_FACTORED", None)
-        else:
-            os.environ["KTPU_DOMAIN_FACTORED"] = prev
-    np.testing.assert_array_equal(feas_auto, feas_mm)
-    np.testing.assert_array_equal(scores_auto, scores_mm)
+    tm = both_masks(nodes, pods, bound)
+    assert tm.any() and not tm.all()

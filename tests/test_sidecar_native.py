@@ -18,16 +18,17 @@ NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(
 
 
 @pytest.fixture(scope="module")
-def client_bin():
+def client_bin(tmp_path_factory):
+    """Built from sidecar_client.c on every run, into a per-run directory:
+    a binary left in native/ (it is git-ignored, so it rides along in
+    copies of the tree) is never what gets tested."""
     cc = shutil.which("gcc") or shutil.which("cc")
     if cc is None:
         pytest.skip("no C compiler")
-    out = os.path.join(NATIVE_DIR, "sidecar_client")
-    src = os.path.join(NATIVE_DIR, "sidecar_client.c")
-    if (not os.path.exists(out)
-            or os.path.getmtime(out) < os.path.getmtime(src)):
-        subprocess.run([cc, "-O2", "-Wall", "-std=c11", "-o", out, src,
-                        "-ldl"], check=True, capture_output=True)
+    out = str(tmp_path_factory.mktemp("native") / "sidecar_client")
+    subprocess.run([cc, "-O2", "-Wall", "-std=c11", "-o", out,
+                    os.path.join(NATIVE_DIR, "sidecar_client.c"), "-ldl"],
+                   check=True, capture_output=True)
     return out
 
 
