@@ -12,14 +12,39 @@ from __future__ import annotations
 import logging
 import threading
 import time
+import weakref
 from typing import Callable, Optional
 
 from kubernetes_tpu.api.selectors import compile_list_selector
 from kubernetes_tpu.client.clientset import ResourceClient
-from kubernetes_tpu.metrics.registry import LOOP_ERRORS, WATCH_RELISTS
+from kubernetes_tpu.metrics.registry import (LOOP_ERRORS, REGISTRY,
+                                             WATCH_RELISTS, series_lines)
 from kubernetes_tpu.store.store import ADDED, DELETED, MODIFIED, TooOld
 
 _LOG = logging.getLogger("kubernetes_tpu.client.informer")
+
+# started informers, for the collector below (weak: an informer nobody
+# holds any more drops out of the exposition with its thread)
+_STARTED: "weakref.WeakSet[SharedInformer]" = weakref.WeakSet()
+
+
+@REGISTRY.collector
+def _informer_lines() -> list[str]:
+    """Watch events handled and wall time inside their handlers, by
+    resource — the plain attributes every informer's watch loop keeps."""
+    seconds: dict[str, float] = {}
+    events: dict[str, int] = {}
+    for inf in list(_STARTED):
+        plural = inf.plural
+        seconds[plural] = seconds.get(plural, 0.0) + inf.handler_ns * 1e-9
+        events[plural] = events.get(plural, 0) + inf.events
+    return (series_lines("scheduler_informer_handler_seconds_total",
+                         "counter", "Wall time inside the event handlers "
+                         "of watch events, on the informer's thread",
+                         "resource", seconds)
+            + series_lines("scheduler_informer_events_total", "counter",
+                           "Watch events dispatched to the handlers",
+                           "resource", events))
 
 
 def meta_namespace_key(obj: dict) -> str:
@@ -100,6 +125,11 @@ class SharedInformer:
                  label_selector: Optional[str] = None,
                  field_selector: Optional[str] = None):
         self.resource = resource
+        self.plural = getattr(resource, "plural", "?")
+        # written by the watch thread alone, read by the collector above:
+        # no lock, two clock reads an event
+        self.handler_ns = 0
+        self.events = 0
         self.store = ThreadSafeStore(indexers)
         self.label_selector = label_selector
         self.field_selector = field_selector
@@ -139,7 +169,9 @@ class SharedInformer:
         return self._synced.wait(timeout)
 
     def start(self):
-        self._thread = threading.Thread(target=self._run, daemon=True)
+        self._thread = threading.Thread(target=self._run, daemon=True,
+                                        name=f"informer-{self.plural}")
+        _STARTED.add(self)
         self._thread.start()
         return self
 
@@ -159,8 +191,7 @@ class SharedInformer:
                     # _list_and_notify are the resync
                     self.relists += 1
                     self.last_relist = time.time()
-                    WATCH_RELISTS.inc(
-                        {"resource": getattr(self.resource, "plural", "?")})
+                    WATCH_RELISTS.inc({"resource": self.plural})
                 self._synced.set()
                 gs = self.gap_since
                 if gs is not None:  # list succeeded: the gap healed
@@ -218,7 +249,10 @@ class SharedInformer:
                     self.store.delete(key)
                 else:
                     self.store.add(key, ev.object)
+                t0 = time.perf_counter_ns()
                 self._dispatch(ev.type, ev.object, old)
+                self.handler_ns += time.perf_counter_ns() - t0
+                self.events += 1
         finally:
             w.stop()
 

@@ -8,10 +8,13 @@ scrape unchanged.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from bisect import bisect_right
 from typing import Optional
+
+_LOG = logging.getLogger(__name__)
 
 DEFAULT_BUCKETS = (0.001, 0.002, 0.005, 0.01, 0.02, 0.05, 0.1, 0.15, 0.2,
                    0.25, 0.3, 0.4, 0.5, 0.6, 0.7, 0.75, 0.8, 0.85, 0.9,
@@ -27,6 +30,17 @@ def _fmt_labels(key: tuple) -> str:
     if not key:
         return ""
     return "{" + ",".join(f'{k}="{v}"' for k, v in key) + "}"
+
+
+def series_lines(name: str, kind: str, help_: str, label: str,
+                 values: dict) -> list[str]:
+    """Exposition lines of one collected series, ``values`` keyed by the
+    value of its single label (quotes and backslashes escaped)."""
+    out = [f"# HELP {name} {help_}", f"# TYPE {name} {kind}"]
+    for k, v in sorted(values.items()):
+        k = str(k).replace("\\", "\\\\").replace('"', '\\"')
+        out.append(f'{name}{{{label}="{k}"}} {v}')
+    return out
 
 
 class _Metric:
@@ -104,6 +118,27 @@ class Histogram(_Metric):
             self._sums[k] = self._sums.get(k, 0.0) + value * n
             self._totals[k] = self._totals.get(k, 0) + n
 
+    def observe_many(self, values, labels: Optional[dict] = None):
+        """Record every value of ``values`` under ONE lock acquisition (a
+        pop of a thousand pods observes a thousand queue waits)."""
+        per_bucket = [0] * (len(self.buckets) + 1)
+        total, n = 0.0, 0
+        for v in values:
+            per_bucket[bisect_right(self.buckets, v)] += 1
+            total += v
+            n += 1
+        if not n:
+            return
+        k = _label_key(labels)
+        with self._lock:
+            counts = self._counts.setdefault(k, [0] * len(self.buckets))
+            running = 0
+            for j in range(len(self.buckets)):
+                running += per_bucket[j]
+                counts[j] += running
+            self._sums[k] = self._sums.get(k, 0.0) + total
+            self._totals[k] = self._totals.get(k, 0) + n
+
     def time(self, labels: Optional[dict] = None):
         return _Timer(self, labels)
 
@@ -178,6 +213,7 @@ class _Timer:
 class Registry:
     def __init__(self):
         self._metrics: dict[str, _Metric] = {}
+        self._collectors: list = []
         self._lock = threading.Lock()
 
     def _register(self, m):
@@ -196,12 +232,30 @@ class Registry:
     def histogram(self, name, help_="", buckets=DEFAULT_BUCKETS) -> Histogram:
         return self._register(Histogram(name, help_, buckets))
 
+    def collector(self, fn):
+        """Register ``fn() -> list of exposition lines``, called by every
+        ``expose_text()``: a series read where it already lives (a clock of
+        the OS, a total some other object keeps) costs its hot path
+        nothing. Returns ``fn``, so it can decorate."""
+        with self._lock:
+            if fn not in self._collectors:
+                self._collectors.append(fn)
+        return fn
+
     def expose_text(self) -> str:
         with self._lock:
             metrics = list(self._metrics.values())
+            collectors = list(self._collectors)
         lines = []
         for m in metrics:
             lines.extend(m.expose())
+        for fn in collectors:
+            try:
+                lines.extend(fn())
+            except Exception:
+                # a scrape must not die of one collector
+                _LOG.warning("metrics collector %r failed", fn,
+                             exc_info=True)
         return "\n".join(lines) + "\n"
 
 
@@ -247,6 +301,13 @@ BIND_RESULTS = REGISTRY.counter(
 GANG_ROUNDS = REGISTRY.histogram(
     "scheduler_gang_rounds", "Conflict-resolution rounds per gang batch",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64))
+
+# How long a pod stood in the scheduling queue: pop time less the stamp the
+# queue put on it at add (sched/queue.py pop_batch, one pass a pop).
+QUEUE_WAIT = REGISTRY.histogram(
+    "scheduler_queue_wait_seconds",
+    "Time a popped pod had waited in the scheduling queue since it was "
+    "last added (activeQ, backoffQ or the unschedulable map)")
 
 # Connected-path dispatch pipeline (scheduler.py multi-deep drain queue):
 # depth/occupancy make the overlap attributable — a healthy run shows
@@ -571,3 +632,48 @@ SCENARIO_ATTEMPT = REGISTRY.histogram(
     "scenario_attempt_latency_seconds",
     "Trace-pod scheduling attempt latency (create dispatch to the "
     "driver observing the binding), by trace phase")
+
+
+# ---- series read at exposition (Registry.collector) ----------------------
+# Which thread had the CPU, and what each span cost: read from the OS and
+# from the tracer's own totals when somebody scrapes, never on a hot path.
+
+@REGISTRY.collector
+def _cpu_lines() -> list[str]:
+    """CPU seconds of the process and of every live thread by name
+    (threads sharing a name are summed). Linux reads a thread's CPU clock
+    through its pthread id; where that call is missing the per-thread
+    series is absent, never 0. A thread that exits takes its line along:
+    diff two scrapes only for threads alive at both."""
+    out = ["# HELP process_cpu_seconds_total User and system CPU time of "
+           "the process", "# TYPE process_cpu_seconds_total counter",
+           f"process_cpu_seconds_total {time.process_time()}"]
+    clock_of = getattr(time, "pthread_getcpuclockid", None)
+    if clock_of is None:
+        return out
+    per: dict[str, float] = {}
+    for t in threading.enumerate():
+        if t.ident is None or not t.is_alive():
+            continue
+        try:
+            cpu = time.clock_gettime(clock_of(t.ident))
+        except OSError:  # exited between enumerate() and the read
+            continue
+        per[t.name] = per.get(t.name, 0.0) + cpu
+    return out + series_lines(
+        "scheduler_thread_cpu_seconds_total", "counter",
+        "CPU time of every live thread of this process, by thread name",
+        "thread", per)
+
+
+@REGISTRY.collector
+def _span_lines() -> list[str]:
+    """Blocked time by span name, from the tracer's own sums
+    (utils/tracing.Tracer.blocked_totals). Wall time and count of a span
+    are read off the ring; this is the one road CPU time takes out."""
+    from kubernetes_tpu.utils.tracing import TRACER
+    return series_lines(
+        "scheduler_span_blocked_seconds_total", "counter",
+        "Wall time less the thread's CPU time inside finished spans "
+        "(waiting for a transfer, a lock or the GIL), by span name",
+        "span", TRACER.blocked_totals())

@@ -223,11 +223,12 @@ def _gang_round_impl(ct_ext: ClusterTensors, pb: PodBatch, state: GangState,
             ct_ext.epod_valid, state.committed, (slot_start,)),
     )
     pb_round = pb.replace(pod_valid=pb.pod_valid & ~state.committed)
-    res = evaluate(ct_round, pb_round, seed=seed,
-                   fit_strategy=fit_strategy, topo_keys=topo_keys,
-                   weights=dict(weights) if weights else None,
-                   enabled_filters=frozenset(enabled_filters) if enabled_filters else None,
-                   ext_mask=ext_mask, ext_scores=ext_scores, plugins=plugins)
+    with jax.named_scope("gang/evaluate"):
+        res = evaluate(ct_round, pb_round, seed=seed,
+                       fit_strategy=fit_strategy, topo_keys=topo_keys,
+                       weights=dict(weights) if weights else None,
+                       enabled_filters=frozenset(enabled_filters) if enabled_filters else None,
+                       ext_mask=ext_mask, ext_scores=ext_scores, plugins=plugins)
     want = res.assigned & ~state.committed & pb.pod_valid
     tried = state.tried
     n_attempted = jnp.int32(0)
@@ -243,32 +244,38 @@ def _gang_round_impl(ct_ext: ClusterTensors, pb: PodBatch, state: GangState,
         want = want & is_target
         tried = tried | is_target
         n_attempted = jnp.sum(is_target).astype(jnp.int32)
-    # rank: priority desc, batch index asc; non-proposing pods rank last
-    prio_key = jnp.where(want, -pb.priority, jnp.iinfo(jnp.int32).max)
-    order0 = jnp.lexsort((jnp.arange(P), prio_key))
-    rank = jnp.zeros(P, jnp.int32).at[order0].set(jnp.arange(P, dtype=jnp.int32))
-    free = ct_round.allocatable - state.requested                   # [N,R]
-    free_at_choice = free[jnp.clip(res.choice, 0, N - 1)]
-    # Balance guard: spread this round's acceptances across the nodes feasible
-    # for someone, approximating the serial loop's load feedback. cap_scale
-    # doubles every round (driver), so strict-preference workloads where the
-    # cap would serialize still converge in O(log P) rounds — early rounds do
-    # the balancing, late rounds drain.
-    distinct = jnp.sum(jnp.any(res.feasible & want[:, None], axis=0))
-    cap = jnp.maximum(1, -(-jnp.sum(want) // jnp.maximum(distinct, 1))) * cap_scale
-    accept = _segmented_capacity_accept(res.choice, want, rank, pb.requests,
-                                        free_at_choice, per_node_cap=cap)
-    accept = _relational_veto(ct_round, pb, res.choice, accept, rank, topo_keys)
-    onehot = (res.choice[:, None] == jnp.arange(N)[None, :]) & accept[:, None]
-    add = jnp.einsum("pn,pr->nr", onehot.astype(jnp.int32), pb.requests)
-    new_state = GangState(
-        requested=state.requested + add,
-        committed=state.committed | accept,
-        assignment=jnp.where(accept, res.choice, state.assignment),
-        tried=tried,
-        rounds=state.rounds + 1,
-    )
-    return new_state, jnp.sum(accept) + n_attempted
+    with jax.named_scope("gang/accept"):
+        # rank: priority desc, batch index asc; non-proposing pods rank last
+        prio_key = jnp.where(want, -pb.priority, jnp.iinfo(jnp.int32).max)
+        order0 = jnp.lexsort((jnp.arange(P), prio_key))
+        rank = jnp.zeros(P, jnp.int32).at[order0].set(jnp.arange(P, dtype=jnp.int32))
+        free = ct_round.allocatable - state.requested                   # [N,R]
+        free_at_choice = free[jnp.clip(res.choice, 0, N - 1)]
+        # Balance guard: spread this round's acceptances across the nodes feasible
+        # for someone, approximating the serial loop's load feedback. cap_scale
+        # doubles every round (driver), so strict-preference workloads where the
+        # cap would serialize still converge in O(log P) rounds — early rounds do
+        # the balancing, late rounds drain.
+        distinct = jnp.sum(jnp.any(res.feasible & want[:, None], axis=0))
+        cap = jnp.maximum(1, -(-jnp.sum(want) // jnp.maximum(distinct, 1))) * cap_scale
+        accept = _segmented_capacity_accept(res.choice, want, rank, pb.requests,
+                                            free_at_choice, per_node_cap=cap)
+    with jax.named_scope("gang/veto"):
+        accept = _relational_veto(ct_round, pb, res.choice, accept, rank,
+                                  topo_keys)
+    with jax.named_scope("gang/commit"):
+        onehot = ((res.choice[:, None] == jnp.arange(N)[None, :])
+                  & accept[:, None])
+        add = jnp.einsum("pn,pr->nr", onehot.astype(jnp.int32), pb.requests)
+        new_state = GangState(
+            requested=state.requested + add,
+            committed=state.committed | accept,
+            assignment=jnp.where(accept, res.choice, state.assignment),
+            tried=tried,
+            rounds=state.rounds + 1,
+        )
+        progress = jnp.sum(accept) + n_attempted
+    return new_state, progress
 
 
 gang_round = partial(jax.jit, static_argnames=(
@@ -542,8 +549,11 @@ def drain_step(ct_all: ClusterTensors, pb_stack: PodBatch, fill,
     aliases the whole resident encoding in place across steady-state
     drains (zero copy-on-donate, zero resharding between cycles).
     """
+    # the scopes below are metadata for the profiler's trace: the four
+    # stages of the resident program, findable by name after a refactor
     if patch is not None:
-        ct_all = _apply_patch(ct_all, patch)
+        with jax.named_scope("drain/patch"):
+            ct_all = _apply_patch(ct_all, patch)
     B, P = pb_stack.pod_valid.shape
     K = ct_all.epod_labels.shape[1]
     ET = ct_all.ea_valid.shape[1]
@@ -555,36 +565,37 @@ def drain_step(ct_all: ClusterTensors, pb_stack: PodBatch, fill,
     def ext(base, new):
         return jnp.concatenate([base[:e0], new], axis=0)
 
-    ct_r = ct_all.replace(
-        epod_node=ext(ct_all.epod_node, jnp.full(BP, -1, jnp.int32)),
-        epod_ns=ext(ct_all.epod_ns, _flat(pb_stack.pod_ns)),
-        epod_labels=ext(ct_all.epod_labels,
-                        _jpad(_flat(pb_stack.pod_labels), 1, K, -1)),
-        epod_valid=ext(ct_all.epod_valid, jnp.zeros(BP, bool)),
-        ea_sel=SelectorSet(
-            key=ext(ct_all.ea_sel.key,
-                    _jpad(_jpad(_flat(pb_stack.anti_sel.key), 1, ET, -1),
-                          2, AX, -1)),
-            op=ext(ct_all.ea_sel.op,
-                   _jpad(_jpad(_flat(pb_stack.anti_sel.op), 1, ET, 0),
-                         2, AX, 0)),
-            vals=ext(ct_all.ea_sel.vals,
-                     _jpad(_jpad(_jpad(_flat(pb_stack.anti_sel.vals),
-                                       1, ET, -1), 2, AX, -1), 3, AV, -1)),
-            expr_valid=ext(ct_all.ea_sel.expr_valid,
-                           _jpad(_jpad(_flat(pb_stack.anti_sel.expr_valid),
-                                       1, ET, False), 2, AX, False)),
-            valid=ext(ct_all.ea_sel.valid,
-                      _jpad(_flat(pb_stack.anti_sel.valid), 1, ET, False))),
-        ea_topo=ext(ct_all.ea_topo, _jpad(_flat(pb_stack.anti_topo), 1, ET, -1)),
-        ea_valid=ext(ct_all.ea_valid,
-                     _jpad(_flat(pb_stack.anti_valid), 1, ET, False)),
-        ea_ns_explicit=ext(ct_all.ea_ns_explicit,
-                           _jpad(_flat(pb_stack.anti_ns_explicit), 1, ET, False)),
-        ea_ns_mask=ext(ct_all.ea_ns_mask,
-                       _jpad(_jpad(_flat(pb_stack.anti_ns_mask), 1, ET, False),
-                             2, NSB, False)),
-    )
+    with jax.named_scope("drain/extend"):
+        ct_r = ct_all.replace(
+            epod_node=ext(ct_all.epod_node, jnp.full(BP, -1, jnp.int32)),
+            epod_ns=ext(ct_all.epod_ns, _flat(pb_stack.pod_ns)),
+            epod_labels=ext(ct_all.epod_labels,
+                            _jpad(_flat(pb_stack.pod_labels), 1, K, -1)),
+            epod_valid=ext(ct_all.epod_valid, jnp.zeros(BP, bool)),
+            ea_sel=SelectorSet(
+                key=ext(ct_all.ea_sel.key,
+                        _jpad(_jpad(_flat(pb_stack.anti_sel.key), 1, ET, -1),
+                              2, AX, -1)),
+                op=ext(ct_all.ea_sel.op,
+                       _jpad(_jpad(_flat(pb_stack.anti_sel.op), 1, ET, 0),
+                             2, AX, 0)),
+                vals=ext(ct_all.ea_sel.vals,
+                         _jpad(_jpad(_jpad(_flat(pb_stack.anti_sel.vals),
+                                           1, ET, -1), 2, AX, -1), 3, AV, -1)),
+                expr_valid=ext(ct_all.ea_sel.expr_valid,
+                               _jpad(_jpad(_flat(pb_stack.anti_sel.expr_valid),
+                                           1, ET, False), 2, AX, False)),
+                valid=ext(ct_all.ea_sel.valid,
+                          _jpad(_flat(pb_stack.anti_sel.valid), 1, ET, False))),
+            ea_topo=ext(ct_all.ea_topo, _jpad(_flat(pb_stack.anti_topo), 1, ET, -1)),
+            ea_valid=ext(ct_all.ea_valid,
+                         _jpad(_flat(pb_stack.anti_valid), 1, ET, False)),
+            ea_ns_explicit=ext(ct_all.ea_ns_explicit,
+                               _jpad(_flat(pb_stack.anti_ns_explicit), 1, ET, False)),
+            ea_ns_mask=ext(ct_all.ea_ns_mask,
+                           _jpad(_jpad(_flat(pb_stack.anti_ns_mask), 1, ET, False),
+                                 2, NSB, False)),
+        )
 
     def batch_body(carry, xs):
         requested, epod_node, epod_valid = carry
@@ -609,37 +620,39 @@ def drain_step(ct_all: ClusterTensors, pb_stack: PodBatch, fill,
                 (st.assignment, st.rounds))
 
     carry0 = (ct_r.requested, ct_r.epod_node, ct_r.epod_valid)
-    (requested, epod_node, epod_valid), (assignments, rounds) = jax.lax.scan(
-        batch_body, carry0, (pb_stack, jnp.arange(B)))
+    with jax.named_scope("drain/converge"):
+        (requested, epod_node, epod_valid), (assignments, rounds) = (
+            jax.lax.scan(batch_body, carry0, (pb_stack, jnp.arange(B))))
 
     # ---- fold committed pods into base slots [fill, fill+n) --------------
-    flags = _flat(assignments >= 0)
-    # exclusive prefix count -> packed destinations; uncommitted rows get an
-    # out-of-bounds index and are dropped by the scatter
-    dest = jnp.where(flags, fill + jnp.cumsum(flags) - flags, e0 + BP)
+    with jax.named_scope("drain/fold"):
+        flags = _flat(assignments >= 0)
+        # exclusive prefix count -> packed destinations; uncommitted rows get an
+        # out-of-bounds index and are dropped by the scatter
+        dest = jnp.where(flags, fill + jnp.cumsum(flags) - flags, e0 + BP)
 
-    def fold(arr):
-        return arr.at[dest].set(arr[e0:], mode="drop")
+        def fold(arr):
+            return arr.at[dest].set(arr[e0:], mode="drop")
 
-    ct_out = ct_r.replace(
-        requested=requested,
-        epod_node=epod_node.at[dest].set(_flat(assignments), mode="drop"),
-        epod_ns=fold(ct_r.epod_ns),
-        epod_labels=fold(ct_r.epod_labels),
-        # fold then invalidate the extension region (labels/terms of dead
-        # rows are inert once the valid flags drop)
-        epod_valid=epod_valid.at[dest].set(flags, mode="drop")
-                             .at[e0:].set(False),
-        ea_sel=SelectorSet(key=fold(ct_r.ea_sel.key), op=fold(ct_r.ea_sel.op),
-                           vals=fold(ct_r.ea_sel.vals),
-                           expr_valid=fold(ct_r.ea_sel.expr_valid),
-                           valid=fold(ct_r.ea_sel.valid)),
-        ea_topo=fold(ct_r.ea_topo),
-        ea_valid=fold(ct_r.ea_valid).at[e0:].set(False),
-        ea_ns_explicit=fold(ct_r.ea_ns_explicit),
-        ea_ns_mask=fold(ct_r.ea_ns_mask),
-    )
-    new_fill = fill + jnp.sum(flags, dtype=jnp.int32)
+        ct_out = ct_r.replace(
+            requested=requested,
+            epod_node=epod_node.at[dest].set(_flat(assignments), mode="drop"),
+            epod_ns=fold(ct_r.epod_ns),
+            epod_labels=fold(ct_r.epod_labels),
+            # fold then invalidate the extension region (labels/terms of dead
+            # rows are inert once the valid flags drop)
+            epod_valid=epod_valid.at[dest].set(flags, mode="drop")
+                                 .at[e0:].set(False),
+            ea_sel=SelectorSet(key=fold(ct_r.ea_sel.key), op=fold(ct_r.ea_sel.op),
+                               vals=fold(ct_r.ea_sel.vals),
+                               expr_valid=fold(ct_r.ea_sel.expr_valid),
+                               valid=fold(ct_r.ea_sel.valid)),
+            ea_topo=fold(ct_r.ea_topo),
+            ea_valid=fold(ct_r.ea_valid).at[e0:].set(False),
+            ea_ns_explicit=fold(ct_r.ea_ns_explicit),
+            ea_ns_mask=fold(ct_r.ea_ns_mask),
+        )
+        new_fill = fill + jnp.sum(flags, dtype=jnp.int32)
     if mesh is not None:
         from kubernetes_tpu.parallel.mesh import constrain_cluster
         ct_out = constrain_cluster(mesh, ct_out)

@@ -560,7 +560,8 @@ class FleetQueue(SchedulingQueue):
                 out.append((item.pod, item.attempts))
                 t = self._tenant(item.pod)
                 self.batch_share[t] = self.batch_share.get(t, 0) + 1
-            return out
+        self._observe_waits(chosen)
+        return out
 
     def _fill_fair(self, groups: dict, order: list, max_batch: int):
         """Weighted round-robin block fill. The first block that comes up

@@ -21,6 +21,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from kubernetes_tpu.api.types import Pod
+from kubernetes_tpu.metrics.registry import QUEUE_WAIT
 from kubernetes_tpu.utils.tracing import FLIGHT
 
 # Cluster events that can make unschedulable pods schedulable again
@@ -209,15 +210,24 @@ class SchedulingQueue:
         with self._lock:
             if not self._wait_for_work_locked(deadline):
                 return []
-            out = []
-            while self._active and len(out) < max_batch:
+            popped = []
+            while self._active and len(popped) < max_batch:
                 item = heapq.heappop(self._active)
                 if not self._current_locked(item):
                     continue  # lazily-deleted or superseded entry
                 self._keys_queued.discard(item.pod.key)
                 self._entries.pop(item.pod.key, None)
-                out.append((item.pod, item.attempts))
-            return out
+                popped.append(item)
+        self._observe_waits(popped)
+        return [(item.pod, item.attempts) for item in popped]
+
+    @staticmethod
+    def _observe_waits(popped: list) -> None:
+        """scheduler_queue_wait_seconds for every popped item: one clock
+        read and one histogram pass a pop, outside the queue's lock."""
+        now = time.time()  # ktpu-lint: disable=KTL003 -- measured against _QueuedPod.timestamp, which the producers stamp with time.time(): one clock for both ends
+        QUEUE_WAIT.observe_many(
+            [max(now - item.timestamp, 0.0) for item in popped])
 
     def close(self):
         with self._lock:

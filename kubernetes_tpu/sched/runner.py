@@ -49,8 +49,9 @@ EXPLAIN_CONFIGMAP = "scheduler-explanations"
 # (loads directly in Perfetto). Bounded — see _publish_trace.
 TRACE_CONFIGMAP = "kubernetes-tpu-scheduler-trace"
 # span events / pod tracks kept in the published trace ConfigMap (the
-# full in-process ring is TRACER.max_spans and FLIGHT.max_pods; the
-# ConfigMap is a bounded API object rewritten on the audit cadence)
+# full in-process ring is TRACER.max_spans spans and FLIGHT.max_pods
+# timelines, growing to FLIGHT.OPEN_FACTOR times that while none is bound;
+# the ConfigMap is a bounded API object rewritten on the audit cadence)
 TRACE_PUBLISH_EVENTS = 1000
 TRACE_PUBLISH_PODS = 200
 
@@ -545,6 +546,13 @@ class SchedulerRunner:
         only the informer layer — callers that need to warm caches/JIT
         against synced state first (benchmarks, tests) call ``start_loop()``
         afterwards."""
+        # the shared clock: every program span also opens a TraceAnnotation
+        # on its own thread, so a running profiler trace holds the spans on
+        # /host:CPU beside the device lines (a flag test when none runs).
+        # Set here, in the scheduler's process: utils/tracing imports no jax
+        import jax
+        from kubernetes_tpu.utils.tracing import TRACER
+        TRACER.annotate = jax.profiler.TraceAnnotation
         return self._start(wait_sync, start_loop)
 
     def start_loop(self):
@@ -807,7 +815,8 @@ class SchedulerRunner:
 
         self._loop_expected = True
         self._loop_stop = stop
-        self._loop_thread = threading.Thread(target=term, daemon=True)
+        self._loop_thread = threading.Thread(target=term, daemon=True,
+                                             name="scheduler-loop")
         self._loop_thread.start()
         self._watch_threads()
 

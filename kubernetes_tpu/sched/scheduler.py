@@ -201,10 +201,6 @@ class Scheduler:
         # patches at 0)
         self.ctx_stats = {"patches": 0, "folds": 0, "rebuilds": 0,
                           "unfit": 0, "reasons": {}}
-        # per-drain-cycle debug trail (pop size, t_pop, t_dispatch,
-        # t_resolve) when KTPU_CYCLE_LOG=1
-        self.cycle_log: list = [] if _os.environ.get(
-            "KTPU_CYCLE_LOG") else None
         # Multi-deep software pipeline: in-flight drains awaiting resolution,
         # oldest first (the device executes them in dispatch order). Bounded
         # by cfg.pipeline_depth — dispatch of drain k+1..k+N overlaps the
@@ -231,6 +227,9 @@ class Scheduler:
         self.fleet_mode = False
         # fragment pops parked while the device is busy (see run_once)
         self._staged: list = []
+        # the ring's scheduler/pop_wait span of the idle stretch the loop is
+        # in (run_once folds the stretch's later empty waits into it)
+        self._idle_wait = None
         self._staged_once = False   # a parked fragment merges at most once
         self._last_pop_full = False  # burst heuristic: arrivals are hot
         # ---- topology slice carving (topology/) --------------------------
@@ -338,13 +337,23 @@ class Scheduler:
         time exceeding stage_swap. EVERY drain staging site goes through
         here (warm_drain included) so bench attribution can never miss a
         transfer again."""
+        import jax
+        from kubernetes_tpu.sched.staging import _tree_nbytes
         from kubernetes_tpu.utils.tracing import TRACER
-        with TRACER.span("scheduler/stage_batch", pods=n_pods):
+        with TRACER.span("scheduler/stage_batch", pods=n_pods,
+                         path="arena" if ticket is not None else "inline"
+                         ) as sp:
+            if sp is not None:
+                leaves = jax.tree_util.tree_leaves(pb_stack)
+                sp.attributes.update(leaves=len(leaves),
+                                     bytes=_tree_nbytes(leaves))
             if ticket is not None:
                 with TRACER.span("scheduler/stage_swap", pods=n_pods):
                     staged = self.cache.stage_redeem(ticket)
                 if staged is not None:
                     return staged
+                if sp is not None:  # the arena declined: staged here after all
+                    sp.attributes["path"] = "inline"
             return self.cache.stage_drain_batch(pb_stack)
 
     def _stage_fill(self, fill: int):
@@ -489,6 +498,7 @@ class Scheduler:
 
     def _resolver_loop(self, q: "queue_mod.Queue") -> None:
         import jax
+        from kubernetes_tpu.utils.tracing import TRACER
         while True:
             pend = q.get()
             if pend is None:  # poison pill from close()/restart
@@ -496,8 +506,10 @@ class Scheduler:
             try:
                 self.resolver_heartbeat()
                 chaos_point("resolver")
-                pend["resolved"] = jax.device_get(
-                    (pend["assignments"], pend["rounds"]))
+                with TRACER.span("scheduler/resolver_fetch",
+                                 pods=sum(len(c) for c in pend["chunks"])):
+                    pend["resolved"] = jax.device_get(
+                        (pend["assignments"], pend["rounds"]))
             except Exception:
                 # surface on the scheduling thread: _resolve_one retries the
                 # fetch inline and handles the real error
@@ -515,29 +527,57 @@ class Scheduler:
         backlog takes the fused drain path (one device program for many
         batches, models/gang.py gang_drain) while shallow pops run the
         single-batch program."""
+        from kubernetes_tpu.utils.tracing import TRACER
         self._fold_staged_nominations()
-        # land finished drains' bindings as soon as the device is done
-        # (don't let finished results sit behind a blocking pop)
-        n_early = self._resolve_ready()
+        # The scheduling thread's time is pop_wait + cycle, end to end:
+        # scheduler/cycle is the root of every span of the work below, so
+        # what no child covers shows as the cycle's own time. An idle loop
+        # records ONE pop_wait a stretch of empty waits, grown in place: a
+        # span a wait would turn the ring over in half an hour and evict
+        # the last real drain from /debug/traces and `ktpu trace dump`.
+        n_early = 0
+        if self._pending and self._drain_ready(self._pending[0]):
+            # land finished drains' bindings as soon as the device is done
+            # (don't let finished results sit behind a blocking pop)
+            with TRACER.span("scheduler/cycle", pods=0):
+                n_early = self._resolve_ready()
         cap = self.cfg.batch_size * max(1, self.cfg.max_drain_batches)
-        batch = self.queue.pop_batch(
-            max(1, cap - len(self._staged)),
-            wait=0.05 if self._pending else wait)
+        with TRACER.span("scheduler/pop_wait",
+                         inflight=len(self._pending)) as sp:
+            batch = self.queue.pop_batch(
+                max(1, cap - len(self._staged)),
+                wait=0.05 if self._pending else wait)
+            idle = not (batch or self._pending or self._staged)
+            if sp is not None:
+                sp.attributes["got"] = len(batch)
+                sp.discard = idle and self._idle_wait is not None
+        if not idle:
+            self._idle_wait = None
+        elif sp is not None:
+            if sp.discard:
+                self._idle_wait.end = sp.end
+                self._idle_wait.cpu_s += sp.cpu_s
+            else:
+                self._idle_wait = sp
         if self._staged:
             batch = self._staged + batch
             self._staged = []
-        if not batch:
-            return n_early + self._resolve_pending()
-        try:
-            return n_early + self._run_batch(batch, cap)
-        except BaseException:
-            # mid-cycle failure with the popped batch in hand: the pods
-            # are in no queue and no watch event will re-deliver them —
-            # requeue before the exception escapes to run()'s self-healing
-            # (or kills the thread for the watchdog). Without this, an
-            # absorbed failure would silently strand the whole pop.
-            self._rescue_batch(batch)
-            raise
+        if not batch and not self._pending:
+            return n_early
+        with TRACER.span("scheduler/cycle", pods=len(batch)):
+            if not batch:
+                return n_early + self._resolve_pending()
+            try:
+                return n_early + self._run_batch(batch, cap)
+            except BaseException:
+                # mid-cycle failure with the popped batch in hand: the pods
+                # are in no queue and no watch event will re-deliver them —
+                # requeue before the exception escapes to run()'s
+                # self-healing (or kills the thread for the watchdog).
+                # Without this, an absorbed failure would silently strand
+                # the whole pop.
+                self._rescue_batch(batch)
+                raise
 
     def _rescue_batch(self, batch) -> None:
         self._staged = []  # a fragment staged THIS cycle is part of batch
@@ -1140,45 +1180,20 @@ class Scheduler:
                 ATTEMPT_DURATION.observe(dt, {"result": result}, n=n)
         return n_bound
 
-    def _schedule_drain(self, profile, items, slot_headroom: int = 0) -> int:
-        """Deep-backlog path: fuse the whole pop into ONE device program over
-        a DEVICE-RESIDENT cluster encoding.
-
-        Per-batch dispatches cost ~100ms each on remote-attached TPUs and
-        re-uploading the multi-MB cluster encoding per drain dominated the
-        connected path, so the steady state here is: cluster tensors live in
-        HBM (``_drain_ctx``), each drain ships only the new pod batches,
-        and ``drain_step`` folds what it commits into free existing-pod
-        slots on device (models/gang.py). Foreign changes — node churn, pod
-        deletes, rebinds, preemption nominees — are replayed from the
-        cache's delta log as DEVICE-SIDE PATCHES (encode/patch.py +
-        apply_ctx_patch) before the next dispatch; the context rebuilds
-        from a host snapshot only when a delta doesn't fit the resident
-        buckets (new resource kind / topology key, bucket overflow,
-        port/volume-owning pods)."""
-        import numpy as np
-        import jax
-        from kubernetes_tpu.models.gang import (
-            apply_ctx_patch, batch_shapes, build_drain_context, drain_step,
-            drain_widths_fit, pad_batch_to, unify_batches)
+    def _drain_gate(self, profile, pods: list, nom_target: dict) -> tuple:
+        """The head of a drain cycle (span ``scheduler/drain_gate``): may
+        this pop ride the resident context, and with which churn patch?
+        Replays the cache's delta log against the context's patch state.
+        -> (ctx, use_ctx, fused_patch, pods bound by drains it had to
+        resolve first, delta-log entries looked at). ``use_ctx`` False
+        means the caller rebuilds from a host snapshot."""
+        from kubernetes_tpu.models.gang import apply_ctx_patch
         from kubernetes_tpu.utils.tracing import TRACER
-        t0 = time.time()
-        self._cyc_marks = []  # fresh debug trail per cycle (KTPU_CYCLE_LOG)
-        pods = [p for p, _ in items]
-        batch_keys = {p.key for p in pods}
-        now = time.time()
-        self._nominated = {
-            k: e for k, e in self._nominated.items()
-            if now - e[3] < self._nominated_ttl and not self.cache.is_bound(k)}
-        # desired resident reservation set: nominees NOT in this pop (a
-        # nominee scheduling itself must not be blocked by its own hold)
-        nom_target = {k: (n, prio, p) for k, (n, prio, p, _ts)
-                      in self._nominated.items() if k not in batch_keys}
-
         ctx = self._drain_ctx
         use_ctx = False
         fused_patch = None  # churn deltas riding THIS dispatch (fused fold)
         n_prev = 0
+        n_deltas = 0  # delta-log entries the gate looked at (span attribute)
         if (ctx is not None
                 and ctx.get("mesh_epoch") != self._mesh_epoch):
             # mesh reshape since this context was staged: its arrays carry
@@ -1198,6 +1213,7 @@ class Scheduler:
                 self._ctx_reason("tainted" if cs.tainted else "capacity")
             else:
                 entries = self.cache.deltas_since(ctx["seq"])
+                n_deltas = len(entries or ())
                 nom_dirty = (set(nom_target) != set(cs.nom_applied)
                              or any(cs.nom_applied[k][1:] != (n, prio)
                                     for k, (n, prio, _p)
@@ -1234,16 +1250,9 @@ class Scheduler:
                                 cs, entries,
                                 {p.key for pend in self._pending
                                  for c in pend["chunks"] for p, _ in c})):
-                        if self.cycle_log is not None:
-                            self._cyc_marks.append(("resolve_prev_start",
-                                                    round(time.time() - t0,
-                                                          3)))
                         n_prev += self._resolve_pending()
-                        if self.cycle_log is not None:
-                            self._cyc_marks.append(
-                                ("resolve_prev_end",
-                                 round(time.time() - t0, 3)))
                         entries = self.cache.deltas_since(ctx["seq"])
+                        n_deltas = len(entries or ())
                     if entries is not None:
                         new_seq = (entries[-1][0] + 1 if entries
                                    else ctx["seq"])
@@ -1309,6 +1318,47 @@ class Scheduler:
                             self._ctx_reason("patch_unfit")
                         else:
                             self._ctx_reason("capacity")
+        return ctx, use_ctx, fused_patch, n_prev, n_deltas
+
+    def _schedule_drain(self, profile, items, slot_headroom: int = 0) -> int:
+        """Deep-backlog path: fuse the whole pop into ONE device program over
+        a DEVICE-RESIDENT cluster encoding.
+
+        Per-batch dispatches cost ~100ms each on remote-attached TPUs and
+        re-uploading the multi-MB cluster encoding per drain dominated the
+        connected path, so the steady state here is: cluster tensors live in
+        HBM (``_drain_ctx``), each drain ships only the new pod batches,
+        and ``drain_step`` folds what it commits into free existing-pod
+        slots on device (models/gang.py). Foreign changes — node churn, pod
+        deletes, rebinds, preemption nominees — are replayed from the
+        cache's delta log as DEVICE-SIDE PATCHES (encode/patch.py +
+        apply_ctx_patch) before the next dispatch; the context rebuilds
+        from a host snapshot only when a delta doesn't fit the resident
+        buckets (new resource kind / topology key, bucket overflow,
+        port/volume-owning pods)."""
+        import numpy as np
+        import jax
+        from kubernetes_tpu.models.gang import (
+            apply_ctx_patch, batch_shapes, build_drain_context, drain_step,
+            drain_widths_fit, pad_batch_to, unify_batches)
+        from kubernetes_tpu.utils.tracing import TRACER
+        t0 = time.time()
+        pods = [p for p, _ in items]
+        batch_keys = {p.key for p in pods}
+        now = time.time()
+        self._nominated = {
+            k: e for k, e in self._nominated.items()
+            if now - e[3] < self._nominated_ttl and not self.cache.is_bound(k)}
+        # desired resident reservation set: nominees NOT in this pop (a
+        # nominee scheduling itself must not be blocked by its own hold)
+        nom_target = {k: (n, prio, p) for k, (n, prio, p, _ts)
+                      in self._nominated.items() if k not in batch_keys}
+
+        with TRACER.span("scheduler/drain_gate") as sp_gate:
+            ctx, use_ctx, fused_patch, n_prev, n_deltas = self._drain_gate(
+                profile, pods, nom_target)
+            if sp_gate is not None:
+                sp_gate.attributes.update(deltas=n_deltas, use_ctx=use_ctx)
         if use_ctx:
             nodes, meta = ctx["nodes"], ctx["meta"]
         else:
@@ -1327,9 +1377,6 @@ class Scheduler:
                 return n_prev
 
         P = self.cfg.batch_size
-        if self.cycle_log is not None:
-            self._cyc_marks.append(("encode_start",
-                                    round(time.time() - t0, 3)))
         chunks = self._tenant_chunks(items, P)
         with TRACER.span("scheduler/encode_pods", pods=len(pods)) as sp_enc:
             pbs = [self.cache.encode_pods(
@@ -1342,12 +1389,13 @@ class Scheduler:
         # pad to the fixed drain width with all-invalid batches (their pods
         # propose nothing; the scan converges them in one dead round)
         B = max(1, self.cfg.max_drain_batches)
-        while len(pbs) < B:
-            pad = pbs[-1]
-            pbs.append(pad.replace(
-                pod_valid=np.zeros_like(np.asarray(pad.pod_valid))))
-        pb_stack = jax.tree_util.tree_map(
-            lambda *xs: np.stack(xs), *unify_batches(pbs))
+        with TRACER.span("scheduler/stack_batch", pods=len(pods)):
+            while len(pbs) < B:
+                pad = pbs[-1]
+                pbs.append(pad.replace(
+                    pod_valid=np.zeros_like(np.asarray(pad.pod_valid))))
+            pb_stack = jax.tree_util.tree_map(
+                lambda *xs: np.stack(xs), *unify_batches(pbs))
 
         if not use_ctx:
             from kubernetes_tpu.encode.patch import fork_meta
@@ -1397,8 +1445,11 @@ class Scheduler:
         else:
             # pin the batch to the context's compiled shapes: pop-dependent
             # bucket widths would otherwise recompile the drain mid-stream
-            padded = pad_batch_to(pb_stack, ctx["pb_shape"])
-            if padded is None or not drain_widths_fit(ctx["ct"], padded):
+            with TRACER.span("scheduler/stack_batch", pods=len(pods)):
+                padded = pad_batch_to(pb_stack, ctx["pb_shape"])
+                fits = (padded is not None
+                        and drain_widths_fit(ctx["ct"], padded))
+            if not fits:
                 # wider than anything compiled so far: rebuild the context
                 self._ctx_reason("batch_shape")
                 n_prev += self._resolve_pending()
@@ -1422,17 +1473,17 @@ class Scheduler:
         # before this one, so their placements are collected at resolve.
         parity_cap = None
         if self.sentinel is not None and not self._extenders:
-            parity_cap = self.sentinel.maybe_capture_drain(
-                self.cache, profile, self._attempt_level, ctx["seq"])
+            with TRACER.span("scheduler/parity_capture") as sp_cap:
+                parity_cap = self.sentinel.maybe_capture_drain(
+                    self.cache, profile, self._attempt_level, ctx["seq"])
+                if sp_cap is not None:
+                    sp_cap.attributes["sampled"] = parity_cap is not None
             if parity_cap is not None:
                 parity_cap["prior"] = list(self._pending)
         # ---- dispatch (async): the device crunches this drain while the
         # host resolves the PREVIOUS one — assume/bind/requeue and the next
         # pop's decode all overlap device execution (software pipelining;
         # jax dispatch is asynchronous, only device_get blocks)
-        if self.cycle_log is not None:
-            self._cyc_marks.append(("dispatch_start",
-                                    round(time.time() - t0, 3)))
         # staging is its OWN span (scheduler/stage_batch, with the arena
         # redeem nested as scheduler/stage_swap): MULTICHIP_r06's sharded
         # gang_dispatch growth (381ms -> 1641ms) was the per-dispatch
@@ -1506,10 +1557,6 @@ class Scheduler:
         if FLIGHT.enabled:
             for pod, _a in items:
                 FLIGHT.record(pod.key, "dispatch", span=sp_disp)
-        if self.cycle_log is not None:
-            marks = dict(self._cyc_marks)
-            marks["done"] = round(time.time() - t0, 3)
-            pend["cyc"] = (len(pods), t0, marks)
         self._submit_resolve(pend)
         self._pending.append(pend)
         PIPELINE_DEPTH.observe(len(self._pending))
@@ -1549,10 +1596,6 @@ class Scheduler:
             return 0
         pend = self._pending.popleft()
         PIPELINE_INFLIGHT.set(len(self._pending))
-        if self.cycle_log is not None and "cyc" in pend:
-            n, tp, marks = pend["cyc"]
-            marks["resolve_at"] = round(time.time() - tp, 3)
-            self.cycle_log.append((n, round(tp, 3), marks))
         import jax
         import numpy as np
         from kubernetes_tpu.utils.tracing import TRACER
@@ -1715,29 +1758,33 @@ class Scheduler:
         pend["winners"] = list(to_bind)
         cap = pend.get("parity")
         if cap is not None and self.sentinel is not None:
-            prior = [w for pp in cap.pop("prior", ())
-                     for w in pp.get("winners", ())]
-            self.sentinel.submit_drain(cap, list(to_bind), prior)
+            with TRACER.span("scheduler/parity_submit", sampled=True):
+                prior = [w for pp in cap.pop("prior", ())
+                         for w in pp.get("winners", ())]
+                self.sentinel.submit_drain(cap, list(to_bind), prior)
         n_bound = len(to_bind)
         n_unsched = len(failures)
-        if FLIGHT.enabled:
-            for pod, _n in to_bind:
-                FLIGHT.record(pod.key, "resolve", span=sp_res)
-            for pod, _a in failures:
-                FLIGHT.record(pod.key, "resolve", span=sp_res)
-        self._handle_failures(failures)
-        # fill_bound is ADJUSTED, never overwritten: drains dispatched after
-        # this one already reserved their own += len(pods) on top, so only
-        # this drain's unused reservation (pend_count - n_bound) is released
-        if active and self._drain_ctx is ctx:
-            ctx["fill_bound"] -= (pend_count - n_bound)
-        self._bind_async_batch(to_bind, profile)
-        dt = time.time() - pend["t0"]
-        for result, n in (("scheduled", n_bound),
-                          ("unschedulable", n_unsched)):
-            if n:
-                SCHEDULE_ATTEMPTS.inc({"result": result}, by=n)
-                ATTEMPT_DURATION.observe(dt, {"result": result}, n=n)
+        with TRACER.span("scheduler/resolve_tail", bound=n_bound,
+                         failed=n_unsched):
+            if FLIGHT.enabled:
+                for pod, _n in to_bind:
+                    FLIGHT.record(pod.key, "resolve", span=sp_res)
+                for pod, _a in failures:
+                    FLIGHT.record(pod.key, "resolve", span=sp_res)
+            self._handle_failures(failures)
+            # fill_bound is ADJUSTED, never overwritten: drains dispatched
+            # after this one already reserved their own += len(pods) on top,
+            # so only this drain's unused reservation (pend_count - n_bound)
+            # is released
+            if active and self._drain_ctx is ctx:
+                ctx["fill_bound"] -= (pend_count - n_bound)
+            self._bind_async_batch(to_bind, profile)
+            dt = time.time() - pend["t0"]
+            for result, n in (("scheduled", n_bound),
+                              ("unschedulable", n_unsched)):
+                if n:
+                    SCHEDULE_ATTEMPTS.inc({"result": result}, by=n)
+                    ATTEMPT_DURATION.observe(dt, {"result": result}, n=n)
         return n_bound
 
     def warm_drain(self, sample_pods: list, slot_headroom: int) -> bool:
@@ -2442,35 +2489,40 @@ class Scheduler:
     def _bind_bulk(self, pairs: list[tuple[Pod, str]]):
         """One API call binds the whole chunk; per-item results fan back out
         into the same success/failure handling as _bind_one."""
-        try:
-            results = self._bulk_binder(pairs)
-        except Exception:
-            _LOG.exception("bulk binding failed (%d pods)", len(pairs))
-            results = [False] * len(pairs)
-        if len(results) != len(pairs):
-            results = list(results) + [False] * (len(pairs) - len(results))
-        for (pod, node_name), ok in zip(pairs, results):
-            if ok:
-                self.cache.finish_binding(pod.key)
-                FLIGHT.record(pod.key, "bind", node=node_name)
-                self.recorder.event(
-                    pod, "Normal", "Scheduled",
-                    f"Successfully assigned {pod.key} to {node_name}")
-            elif ok is None:
-                # the pod vanished while its binding was in flight (e.g. a
-                # churn delete): drop the assumption quietly — requeueing
-                # would retry-404 forever with no future event to clear it,
-                # and it is not a scheduling error either. The informer's
-                # DELETED event owns the queue cleanup; deleting here by
-                # ns/name could strand a just-RE-CREATED pod's queue entry.
-                self.cache.forget(pod.key)
-            else:
-                self.cache.forget(pod.key)
-                if not self.cache.is_bound(pod.key):
-                    self.queue.add_unschedulable(pod, 1)
-                    if self.cache.is_bound(pod.key):  # event raced the requeue
-                        self.queue.delete(pod)
-                SCHEDULE_ATTEMPTS.inc({"result": "error"})
+        from kubernetes_tpu.utils.tracing import TRACER
+        with TRACER.span("scheduler/bind_bulk", pods=len(pairs)):
+            try:
+                with TRACER.span("scheduler/bind_call", pods=len(pairs)):
+                    results = self._bulk_binder(pairs)
+            except Exception:
+                _LOG.exception("bulk binding failed (%d pods)", len(pairs))
+                results = [False] * len(pairs)
+            if len(results) != len(pairs):
+                results = list(results) + [False] * (
+                    len(pairs) - len(results))
+            for (pod, node_name), ok in zip(pairs, results):
+                if ok:
+                    self.cache.finish_binding(pod.key)
+                    FLIGHT.record(pod.key, "bind", node=node_name)
+                    self.recorder.event(
+                        pod, "Normal", "Scheduled",
+                        f"Successfully assigned {pod.key} to {node_name}")
+                elif ok is None:
+                    # the pod vanished while its binding was in flight
+                    # (e.g. a churn delete): drop the assumption quietly —
+                    # requeueing would retry-404 forever with no future
+                    # event to clear it, and it is not a scheduling error
+                    # either. The informer's DELETED event owns the queue
+                    # cleanup; deleting here by ns/name could strand a
+                    # just-RE-CREATED pod's queue entry.
+                    self.cache.forget(pod.key)
+                else:
+                    self.cache.forget(pod.key)
+                    if not self.cache.is_bound(pod.key):
+                        self.queue.add_unschedulable(pod, 1)
+                        if self.cache.is_bound(pod.key):
+                            self.queue.delete(pod)  # event raced the requeue
+                    SCHEDULE_ATTEMPTS.inc({"result": "error"})
 
     def close(self, timeout: float = 5.0):
         """Stop the binding pool: poison-pill every worker and join them.

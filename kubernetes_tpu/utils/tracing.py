@@ -9,9 +9,17 @@ where one per pod would not be.
 Two layers:
 
 - :class:`Tracer` — batch-granularity spans with real span/trace ids and a
-  true ring buffer (drop-oldest, drops counted). Exports OTLP/JSON (the
-  apiserver's ``/debug/traces``) and Chrome trace-event JSON
-  (``export_chrome`` — loads directly in Perfetto / chrome://tracing).
+  true ring buffer (drop-oldest, drops counted). A span carries the thread
+  it ran on and that thread's CPU time, so wall − CPU says how long the
+  thread stood blocked (a transfer, a lock, the GIL). The blocked time is
+  summed by span name beside the ring (``blocked_totals()``;
+  metrics/registry.py exposes it).
+  Exports OTLP/JSON (the apiserver's ``/debug/traces``) and Chrome
+  trace-event JSON (``export_chrome`` — loads directly in Perfetto /
+  chrome://tracing). ``Tracer.annotate`` mirrors every sampled span into
+  another tracing system (the scheduler sets jax's ``TraceAnnotation``);
+  this module itself must import without jax — the apiserver and kubelet
+  processes use it.
 - :class:`FlightRecorder` — a per-pod ring buffer of lifecycle stages
   (informer event -> precompile -> queue admit -> dispatch -> resolve ->
   bind/requeue), each stage optionally linked to the batch span it rode in.
@@ -30,7 +38,7 @@ import time
 from collections import OrderedDict, deque
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 
 @dataclass
@@ -44,14 +52,24 @@ class Span:
     span_id: int = 0
     parent_id: int = 0
     trace_id: int = 0
-    # parent NAME kept as a display convenience (diagnostics print it);
-    # exporters link by id only.
-    parent: Optional[str] = None
     attributes: dict[str, Any] = field(default_factory=dict)
+    # name of the thread the span ran on, and that thread's CPU seconds
+    # between start and end (time.thread_time_ns)
+    thread: str = ""
+    cpu_s: float = 0.0
+    # set inside the span to keep it out of the ring and the totals (the
+    # scheduling loop folds an idle stretch's empty waits into one span)
+    discard: bool = False
 
     @property
     def duration_ms(self) -> float:
         return (self.end - self.start) * 1000.0
+
+    @property
+    def blocked_s(self) -> float:
+        """Wall time the thread was not on a CPU: waiting for a transfer,
+        a lock, a condition or the GIL."""
+        return max(self.end - self.start - self.cpu_s, 0.0)
 
 
 class Tracer:
@@ -68,6 +86,13 @@ class Tracer:
         self._counter = 0
         self._ids = itertools.count(1)
         self.dropped = 0
+        # blocked seconds (wall - thread CPU) by span name since process
+        # start — never reset with the ring, so a reader diffs two reads.
+        # Wall time and count are not kept here: the ring's readers sum them
+        self._blocked: dict[str, float] = {}  # guarded by: self._lock
+        # optional name -> context manager, entered inside every sampled
+        # span (sched/runner.py sets jax.profiler.TraceAnnotation)
+        self.annotate: Optional[Callable[[str], Any]] = None
 
     @property
     def max_spans(self) -> int:
@@ -96,22 +121,39 @@ class Tracer:
         sp = Span(name=name, start=time.time(), span_id=sid,
                   parent_id=top.span_id if top else 0,
                   trace_id=top.trace_id if top else sid,
-                  parent=top.name if top else None,
-                  attributes=dict(attributes))
+                  attributes=dict(attributes),
+                  thread=threading.current_thread().name)
+        annotate = self.annotate
+        mirror = annotate(name) if annotate is not None else None
+        if mirror is not None:
+            mirror.__enter__()
         stack.append(sp)
+        cpu0 = time.thread_time_ns()
         try:
             yield sp
         finally:
+            if mirror is not None:
+                mirror.__exit__(None, None, None)
+            sp.cpu_s = (time.thread_time_ns() - cpu0) * 1e-9
             sp.end = time.time()
             stack.pop()
-            with self._lock:
-                if len(self._spans) == self._spans.maxlen:
-                    self.dropped += 1
-                self._spans.append(sp)
+            if not sp.discard:
+                with self._lock:
+                    if len(self._spans) == self._spans.maxlen:
+                        self.dropped += 1
+                    self._spans.append(sp)
+                    self._blocked[name] = (self._blocked.get(name, 0.0)
+                                           + sp.blocked_s)
 
     def spans(self, name: Optional[str] = None) -> list[Span]:
         with self._lock:
             return [s for s in self._spans if name is None or s.name == name]
+
+    def blocked_totals(self) -> dict[str, float]:
+        """{span name: blocked seconds} over every span finished since
+        process start (``reset`` does not touch them)."""
+        with self._lock:
+            return dict(self._blocked)
 
     def reset(self):
         with self._lock:
@@ -123,9 +165,10 @@ class Tracer:
                       max_flight_pods: Optional[int] = None) -> dict:
         """Finished spans (+ the flight recorder's per-pod timelines) in
         Chrome trace-event JSON — the format Perfetto and chrome://tracing
-        load directly. Spans are complete ("X") events grouped per trace id
-        (pid 1); pod lifecycles are per-pod tracks (pid 2) whose stage
-        slices carry the linked batch span id in ``args``. ``path`` also
+        load directly. Spans are complete ("X") events on one track a thread
+        (pid 1), with the span's CPU time in ``args``; pod lifecycles are
+        per-pod tracks (pid 2) whose stage slices carry the linked batch
+        span id in ``args``. ``path`` also
         writes the document to disk; ``max_events`` keeps only the newest
         N span events and ``max_flight_pods`` the newest N pod tracks —
         the runner's periodically-published trace ConfigMap bounds both
@@ -135,16 +178,24 @@ class Tracer:
         finished = self.spans()
         if max_events is not None and len(finished) > max_events:
             finished = finished[-max_events:]
+        # one lane a thread: the loop, the resolver and the binders show
+        # side by side, and nesting within a lane is the span tree
+        lanes: dict[str, int] = {}
         for sp in finished:
             events.append({
                 "name": sp.name, "cat": "scheduler", "ph": "X",
                 "ts": sp.start * 1e6,
                 "dur": max(sp.end - sp.start, 0.0) * 1e6,
-                "pid": 1, "tid": sp.trace_id,
+                "pid": 1, "tid": lanes.setdefault(sp.thread, len(lanes) + 1),
                 "args": {"span_id": sp.span_id,
                          "parent_id": sp.parent_id,
+                         "trace_id": sp.trace_id,
+                         "cpu_ms": round(sp.cpu_s * 1000.0, 3),
                          **{k: str(v) for k, v in sp.attributes.items()}},
             })
+        for thread, tid in lanes.items():
+            events.append({"name": "thread_name", "ph": "M", "pid": 1,
+                           "tid": tid, "args": {"name": thread}})
         events.append({"name": "process_name", "ph": "M", "pid": 1,
                        "args": {"name": "kubernetes-tpu-scheduler"}})
         if flight is None:
@@ -201,11 +252,19 @@ class FlightRecorder:
     """Per-pod lifecycle ring buffer keyed by pod key.
 
     Each ``record(key, stage)`` appends (stage, ts, span_id, attrs) to the
-    pod's bounded timeline; the recorder itself holds at most ``max_pods``
-    pods (oldest-inserted dropped first, counted in ``dropped_pods``).
-    ``span`` links the stage to the batch span it rode in (the Span object
-    from ``TRACER.span(...) as sp`` or a raw id). Stage ``bind`` closes
-    the timeline and derives the end-to-end scheduling SLI histograms."""
+    pod's bounded timeline. The recorder holds ``max_pods`` timelines and
+    makes room by dropping CLOSED (bound) ones first, oldest close first —
+    a pod still on its way keeps its first stamp however many pods bind
+    around it. Only with no closed timeline left does it grow, up to
+    ``OPEN_FACTOR`` x ``max_pods`` (16,384 by default: a 10,000-pod burst
+    fits); past that the oldest open timeline goes, counted in
+    ``dropped_pods``. ``span`` links the stage to the batch span it rode in
+    (the Span object from ``TRACER.span(...) as sp`` or a raw id). Stage
+    ``bind`` closes the timeline and derives the end-to-end scheduling SLI
+    histograms; a ``bind`` for a key with no timeline (evicted) observes
+    nothing and counts a drop — the histograms are right or silent."""
+
+    OPEN_FACTOR = 4
 
     def __init__(self, max_pods: int = 4096, max_events: int = 32,
                  enabled: Optional[bool] = None):
@@ -217,7 +276,19 @@ class FlightRecorder:
         self.max_events = max_events
         self._lock = threading.Lock()
         self._pods: "OrderedDict[str, deque]" = OrderedDict()
+        # keys of bound timelines in the order they closed
+        self._closed: "OrderedDict[str, None]" = OrderedDict()
         self.dropped_pods = 0
+
+    def _make_room_locked(self) -> None:
+        if len(self._pods) < self.max_pods:
+            return
+        if self._closed:
+            old, _ = self._closed.popitem(last=False)
+            del self._pods[old]
+        elif len(self._pods) >= self.OPEN_FACTOR * self.max_pods:
+            self._pods.popitem(last=False)
+            self.dropped_pods += 1
 
     def record(self, key: str, stage: str, span=None, **attrs) -> None:
         if not self.enabled:
@@ -227,21 +298,26 @@ class FlightRecorder:
         with self._lock:
             tl = self._pods.get(key)
             if tl is None:
-                if len(self._pods) >= self.max_pods:
-                    self._pods.popitem(last=False)
+                if stage == "bind":
+                    # its timeline was evicted: the first stamp is gone, and
+                    # a fresh timeline would observe the bind against itself
                     self.dropped_pods += 1
+                    return
+                self._make_room_locked()
                 tl = self._pods[key] = deque(maxlen=self.max_events)
-            elif stage == "informer" and any(e[0] == "bind" for e in tl):
+            elif stage == "informer" and key in self._closed:
                 # a fresh informer event on a CLOSED (bound) timeline is a
                 # recreated pod under the same ns/name: start a new
                 # incarnation instead of stitching two lifecycles into one
                 # (which would poison the derived e2e histogram with the
                 # gap between them)
                 tl.clear()
+                del self._closed[key]
             tl.append((stage, now, span_id, attrs or None))
             first_ts = tl[0][1]
             queued_ts = None
             if stage == "bind":
+                self._closed[key] = None
                 for st, ts, _sid, _a in tl:
                     if st == "queue_add":
                         queued_ts = ts
@@ -271,6 +347,7 @@ class FlightRecorder:
     def reset(self) -> None:
         with self._lock:
             self._pods.clear()
+            self._closed.clear()
             self.dropped_pods = 0
 
     def export_chrome_events(self, pid: int = 2,
@@ -330,8 +407,11 @@ def export_otlp_json(tracer: "Tracer", service_name: str = "kubernetes-tpu"
             "startTimeUnixNano": str(int(sp.start * 1e9)),
             "endTimeUnixNano": str(int(sp.end * 1e9)),
             "attributes": [
-                {"key": k, "value": {"stringValue": str(v)}}
-                for k, v in sp.attributes.items()],
+                {"key": "thread.name", "value": {"stringValue": sp.thread}},
+                {"key": "thread.cpu_time_s",
+                 "value": {"doubleValue": sp.cpu_s}},
+                *({"key": k, "value": {"stringValue": str(v)}}
+                  for k, v in sp.attributes.items())],
         })
     return {"resourceSpans": [{
         "resource": {"attributes": [

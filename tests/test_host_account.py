@@ -1,0 +1,362 @@
+"""The host's account of a served window: which thread held which span,
+which thread had the CPU, how long pods waited in the queue and the loop
+for pods — the series and spans the benchmark's per-layer metrics read
+(PERF.md section 3, table "span or counter -> thread -> metric")."""
+
+import re
+import threading
+import time
+
+import pytest
+
+from kubernetes_tpu.client.clientset import HTTPClient
+from kubernetes_tpu.config.types import SchedulerConfiguration
+from kubernetes_tpu.metrics.registry import (QUEUE_WAIT, REGISTRY, Histogram,
+                                             Registry, series_lines)
+from kubernetes_tpu.sched.fleet import FleetQueue
+from kubernetes_tpu.sched.queue import SchedulingQueue
+from kubernetes_tpu.sched.runner import SchedulerRunner
+from kubernetes_tpu.store.apiserver import APIServer
+from kubernetes_tpu.testing.wrappers import make_node, make_pod
+from kubernetes_tpu.utils.tracing import FLIGHT, TRACER
+
+# table C of ISSUE 25: span -> thread it runs on, for every span that can
+# fire on one device
+SPAN_THREADS = {
+    "scheduler/pop_wait": "scheduler-loop",
+    "scheduler/cycle": "scheduler-loop",
+    "scheduler/drain_gate": "scheduler-loop",
+    "scheduler/stack_batch": "scheduler-loop",
+    "scheduler/parity_capture": "scheduler-loop",
+    "scheduler/parity_submit": "scheduler-loop",
+    "scheduler/resolve_tail": "scheduler-loop",
+    "scheduler/encode_pods": "scheduler-loop",
+    "scheduler/stage_batch": "scheduler-loop",
+    "scheduler/gang_dispatch": "scheduler-loop",
+    "scheduler/resolve_wait": "scheduler-loop",
+    "scheduler/apply": "scheduler-loop",
+    "scheduler/resolver_fetch": "drain-resolver",
+    "scheduler/bind_bulk": "binder-",
+    "scheduler/bind_call": "binder-",
+}
+
+_SERIES = re.compile(r"^([^#\s]+)\s+(\S+)$")
+
+
+def series() -> dict:
+    """The exposition as the benchmark reads it (yardstick/program.py)."""
+    out = {}
+    for line in REGISTRY.expose_text().splitlines():
+        m = _SERIES.match(line)
+        if m:
+            out[m.group(1)] = float(m.group(2))
+    return out
+
+
+def wait_for(pred, timeout=60.0, interval=0.05):
+    deadline = time.time() + timeout
+    while time.time() < deadline:
+        if pred():
+            return True
+        time.sleep(interval)
+    return bool(pred())
+
+
+# ---------------------------------------------------------------- registry
+
+def test_registry_collector_is_called_at_exposition_only():
+    r = Registry()
+    calls = []
+
+    @r.collector
+    def lines():
+        calls.append(1)
+        return series_lines("thing_total", "counter", "help", "who",
+                            {"a b": 1.5, 'q"uote': 2})
+
+    r.collector(lines)  # registering twice exposes once
+    r.counter("plain_total").inc()
+    assert calls == []
+    text = r.expose_text()
+    assert calls == [1]
+    assert 'thing_total{who="a b"} 1.5' in text
+    assert 'thing_total{who="q\\"uote"} 2' in text
+    assert text.count("# TYPE thing_total counter") == 1
+    assert "plain_total 1.0" in text
+
+    # a collector that raises costs its own lines, not the scrape
+    r.collector(lambda: 1 / 0)
+    assert "plain_total 1.0" in r.expose_text()
+
+
+def test_observe_many_equals_observe_one_by_one():
+    one, many = Histogram("h"), Histogram("h")
+    values = [0.0, 0.001, 0.0015, 0.3, 0.31, 1.0, 59.0, 500.0]
+    for v in values:
+        one.observe(v)
+    many.observe_many(values)
+    many.observe_many([])
+    assert one.expose() == many.expose()
+    assert many.count() == len(values)
+
+
+def test_cpu_series_name_every_live_thread():
+    stop = threading.Event()
+
+    def spin():
+        while not stop.is_set():
+            sum(range(1000))
+
+    t = threading.Thread(target=spin, name="spinner-under-test", daemon=True)
+    t.start()
+    try:
+        key = 'scheduler_thread_cpu_seconds_total{thread="spinner-under-test"}'
+        first = series()
+        assert wait_for(lambda: series().get(key, 0.0) > first.get(key, 0.0)
+                        + 0.02, timeout=10.0)
+        second = series()
+        assert second["process_cpu_seconds_total"] > \
+            first["process_cpu_seconds_total"]
+        assert second[key] <= second["process_cpu_seconds_total"]
+    finally:
+        stop.set()
+        t.join(5.0)
+    assert not t.is_alive()
+    # a thread that is gone takes its line along: absent, never 0
+    assert key not in series()
+
+
+# ------------------------------------------------------------------- queue
+
+@pytest.mark.parametrize("queue_cls", [SchedulingQueue, FleetQueue])
+def test_queue_wait_counts_one_observation_a_popped_pod(queue_cls):
+    q = queue_cls()
+    for i in range(5):
+        q.add(make_pod(f"p{i}").obj())
+    q.add(make_pod("gone").obj())
+    q.delete_key("default/gone")  # lazily deleted: never popped, never seen
+    time.sleep(0.05)
+    n0, s0 = QUEUE_WAIT.count(), series().get(
+        "scheduler_queue_wait_seconds_sum", 0.0)
+    assert len(q.pop_batch(3, wait=0.1)) == 3
+    assert QUEUE_WAIT.count() == n0 + 3
+    assert len(q.pop_batch(10, wait=0.1)) == 2
+    assert QUEUE_WAIT.count() == n0 + 5
+    assert q.pop_batch(10, wait=0.01) == []
+    assert QUEUE_WAIT.count() == n0 + 5
+    waited = series()["scheduler_queue_wait_seconds_sum"] - s0
+    assert 5 * 0.05 <= waited < 5 * 5.0
+
+
+# ------------------------------------------------------------- served path
+
+@pytest.fixture(scope="module")
+def served():
+    """A small served run through the drain path with every drain sampled
+    by the sentinel: 4 nodes, 40 pods in two bulk creates."""
+    server = APIServer().start()
+    client = HTTPClient(server.url)
+    for i in range(4):
+        client.nodes().create(
+            make_node(f"n{i}").capacity(
+                {"cpu": "16", "memory": "32Gi", "pods": "64"})
+            .label("kubernetes.io/hostname", f"n{i}").obj().to_dict())
+    runner = SchedulerRunner(HTTPClient(server.url), SchedulerConfiguration(
+        batch_size=8, max_drain_batches=2, parity_sample_every=1))
+    before = series()
+    TRACER.reset()
+    flight_was, FLIGHT.enabled = FLIGHT.enabled, True
+    runner.start()
+    try:
+        pods = client.pods("default")
+        for wave in range(2):
+            pods.create_many([
+                make_pod(f"w{wave}-p{i}").req({"cpu": "100m"})
+                .obj().to_dict() for i in range(20)])
+            assert wait_for(lambda: sum(
+                1 for p in pods.list() if p["spec"].get("nodeName"))
+                == 20 * (wave + 1)), "pods never bound"
+        runner.scheduler.wait_for_bindings(10.0)
+        yield {"runner": runner, "before": before, "after": series(),
+               "spans": TRACER.spans(),
+               "threads": {t.name for t in threading.enumerate()}}
+    finally:
+        FLIGHT.enabled = flight_was
+        runner.stop()
+        server.stop()
+
+
+def test_every_span_of_the_served_path_fires_on_its_thread(served):
+    by_name: dict = {}
+    for sp in served["spans"]:
+        by_name.setdefault(sp.name, set()).add(sp.thread)
+    for name, thread in SPAN_THREADS.items():
+        assert name in by_name, (name, sorted(by_name))
+        assert all(t.startswith(thread) for t in by_name[name]), (
+            name, by_name[name])
+    ids = {sp.span_id: sp for sp in served["spans"]}
+    for sp in served["spans"]:
+        if sp.thread != "scheduler-loop":
+            continue
+        # the loop thread is pop_wait + cycle and nothing beside them
+        if sp.name in ("scheduler/pop_wait", "scheduler/cycle"):
+            assert sp.parent_id == 0, sp
+        elif sp.name.startswith("scheduler/"):
+            root = sp
+            while root.parent_id in ids:
+                root = ids[root.parent_id]
+            assert root.name == "scheduler/cycle", (sp.name, root.name)
+    staged = [sp for sp in served["spans"]
+              if sp.name == "scheduler/stage_batch"]
+    assert all(sp.attributes["path"] == "inline"
+               and sp.attributes["bytes"] > 0 and sp.attributes["leaves"] > 0
+               for sp in staged)
+    assert all(0.0 <= sp.cpu_s <= (sp.end - sp.start) + 0.05
+               for sp in served["spans"])
+
+
+def test_served_threads_have_names_and_their_cpu_series_grow(served):
+    threads = served["threads"]
+    for name in ("scheduler-loop", "informer-pods", "informer-nodes",
+                 "drain-resolver", "parity-sentinel", "invariant-auditor"):
+        assert name in threads, sorted(threads)
+    assert any(t.startswith("binder-") for t in threads)
+    before, after = served["before"], served["after"]
+    for name in ("scheduler-loop", "informer-pods"):
+        key = f'scheduler_thread_cpu_seconds_total{{thread="{name}"}}'
+        assert after[key] > before.get(key, 0.0), key
+    assert after["process_cpu_seconds_total"] > \
+        before["process_cpu_seconds_total"]
+
+
+def test_span_and_informer_series_cover_the_served_run(served):
+    before, after = served["before"], served["after"]
+
+    def grew(key):
+        return after.get(key, 0.0) - before.get(key, 0.0)
+
+    wall: dict = {}
+    for sp in served["spans"]:
+        wall[sp.name] = wall.get(sp.name, 0.0) + sp.end - sp.start
+    for name in SPAN_THREADS:
+        key = f'scheduler_span_blocked_seconds_total{{span="{name}"}}'
+        assert key in after, name
+        assert 0.0 <= grew(key) <= wall[name] + 1e-9, name
+    # a wait is all blocked time; it holds no CPU
+    assert grew('scheduler_span_blocked_seconds_total'
+                '{span="scheduler/pop_wait"}') > \
+        0.8 * wall["scheduler/pop_wait"] > 0.0
+    # 40 ADDED events at least, and their bind confirmations
+    events = grew('scheduler_informer_events_total{resource="pods"}')
+    assert events >= 40
+    assert grew('scheduler_informer_handler_seconds_total{resource="pods"}') \
+        > 0.0
+    assert grew("scheduler_queue_wait_seconds_count") == 40
+    # the e2e SLI saw every pod once, from a first stamp that was there
+    assert grew("scheduler_e2e_scheduling_duration_seconds_count") == 40
+    assert grew("scheduler_e2e_scheduling_duration_seconds_sum") > 0.0
+
+
+def test_an_idle_loop_does_not_turn_the_ring_over():
+    """A stretch of empty waits is ONE scheduler/pop_wait, grown in place:
+    however long the loop idles, the last drain's spans stay in the ring
+    for /debug/traces and `ktpu trace dump`."""
+    from kubernetes_tpu.sched.cache import SchedulerCache
+    from kubernetes_tpu.sched.scheduler import Scheduler
+    cache = SchedulerCache()
+    cache.add_node(make_node("n0").capacity(
+        {"cpu": "8", "memory": "16Gi", "pods": "32"}).obj())
+    queue = SchedulingQueue()
+    sched = Scheduler(SchedulerConfiguration(batch_size=4), cache, queue,
+                      lambda pod, node: True)
+    ring_was = TRACER.max_spans
+    TRACER.reset()
+    try:
+        for i in range(3):
+            queue.add(make_pod(f"idle-p{i}").req({"cpu": "100m"}).obj())
+        while sched.run_once(wait=0.01) or sched._pending:
+            pass
+        sched.wait_for_bindings(10.0)
+        # the loop's last, empty wait opened the idle stretch
+        drain = TRACER.spans()
+        assert "scheduler/cycle" in [sp.name for sp in drain]
+        idle, end_was = drain[-1], drain[-1].end
+        assert idle.name == "scheduler/pop_wait"
+        assert idle.attributes["got"] == 0
+        TRACER.max_spans = len(drain) + 8
+        for _ in range(3 * TRACER.max_spans):
+            assert sched.run_once(wait=0.001) == 0
+        assert TRACER.spans() == drain and TRACER.dropped == 0
+        assert idle.end - end_was >= 3 * TRACER.max_spans * 0.001
+        # pods end the stretch: their wait and the next idle one are new
+        queue.add(make_pod("idle-late").req({"cpu": "100m"}).obj())
+        while sched.run_once(wait=0.01) or sched._pending:
+            pass
+        assert sched.run_once(wait=0.001) == 0
+        waits = TRACER.spans("scheduler/pop_wait")
+        assert idle in waits and waits[-1] is not idle
+        assert waits[-1].attributes["got"] == 0
+        assert any(sp.attributes["got"] == 1 for sp in waits)
+    finally:
+        sched.close()
+        TRACER.max_spans = ring_was
+        TRACER.reset()
+
+
+def test_every_span_and_series_of_the_account_feeds_a_metric_file():
+    """The rule that keeps the account honest: a span or series of the
+    served path that no per-layer metric reads does not stay. Every span
+    of the table above and every series the collectors add is named in the
+    args of a file under yardstick/layer_metrics/."""
+    import glob
+    import json
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    read = set()
+    for path in glob.glob(os.path.join(root, "yardstick", "layer_metrics",
+                                       "*.json")):
+        with open(path) as f:
+            args = json.load(f)["args"]
+        for names in args.values():
+            if isinstance(names, list):
+                read.update(n.split("{")[0] for n in names)
+    assert set(SPAN_THREADS) <= read, sorted(set(SPAN_THREADS) - read)
+    collected = {line.split()[2] for line in REGISTRY.expose_text()
+                 .splitlines() if line.startswith("# TYPE ")} - {
+        m.name for m in REGISTRY._metrics.values()}
+    assert collected and collected <= read, sorted(collected - read)
+    assert "scheduler_queue_wait_seconds_sum" in read
+
+
+# ----------------------------------------------------------- device scopes
+
+def test_drain_step_carries_its_named_scopes():
+    """Metadata only (tests/test_bulk_and_drain.py holds the placements
+    bit-equal): the stages of the resident program and of a round are
+    findable by name in the lowered module, so in a profiler trace."""
+    import jax
+    import numpy as np
+    from kubernetes_tpu.models.gang import (build_drain_context, drain_step,
+                                            unify_batches)
+    from kubernetes_tpu.sched.cache import SchedulerCache
+    cache = SchedulerCache()
+    for i in range(4):
+        cache.add_node(make_node(f"n{i}").capacity(
+            {"cpu": "8", "memory": "16Gi", "pods": "32"}).obj())
+    pods = [make_pod(f"d{i}").req({"cpu": "500m"}).obj() for i in range(8)]
+    _, ct, meta = cache.snapshot(pending_pods=pods[:4], slot_headroom=32)
+    pbs = [cache.encode_pods(pods[i * 4:(i + 1) * 4], meta, min_p=4)
+           for i in range(2)]
+    ct_dev, e0, fill = build_drain_context(ct, pbs)
+    pb_stack = jax.tree_util.tree_map(
+        lambda *xs: np.stack(xs), *unify_batches(pbs))
+    text = drain_step.lower(
+        ct_dev, pb_stack, fill, e0=e0, seed=0,
+        fit_strategy="LeastAllocated", topo_keys=meta.topo_keys,
+        weights=(), enabled_filters=(), max_rounds=8
+    ).as_text(debug_info=True)
+    for scope in ("drain/extend", "drain/converge", "drain/fold",
+                  "gang/evaluate", "gang/accept", "gang/veto",
+                  "gang/commit"):
+        assert scope in text, scope
+    assert "drain/patch" not in text  # no churn patch rode this dispatch

@@ -66,8 +66,8 @@ def test_tracer_spans_nest_and_sample():
     spans = tr.spans()
     assert [s.name for s in spans] == ["inner", "outer"]
     outer = tr.spans("outer")[0]
-    assert outer.parent is None and outer.duration_ms >= 10
-    assert tr.spans("inner")[0].parent == "outer"
+    assert outer.parent_id == 0 and outer.duration_ms >= 10
+    assert tr.spans("inner")[0].parent_id == outer.span_id
     assert outer.attributes == {"a": 1}
 
 

@@ -61,8 +61,9 @@ def test_span_ids_unique_and_parent_by_id():
     assert outer.parent_id == 0
     # one trace: every span shares the root's trace id
     assert {s.trace_id for s in tr.spans()} == {outer.span_id}
-    # display-name convenience still present
-    assert inner.parent == "mid" and outer.parent is None
+    # every span knows the thread it ran on
+    assert {s.thread for s in tr.spans()} == {
+        threading.current_thread().name}
 
 
 def test_same_name_spans_link_to_the_right_parent():
@@ -104,6 +105,153 @@ def test_nesting_is_per_thread():
     # the worker's span must NOT have picked up main's stack as a parent
     assert seen["worker"].parent_id == 0
     assert seen["worker"].trace_id != main_sp.trace_id
+
+
+def test_nesting_is_per_thread_with_cycle_as_root():
+    """Two threads, each with its own root: children hang under their own
+    thread's root (``scheduler/cycle`` on the loop), never the other's."""
+    tr = Tracer()
+    go, done = threading.Event(), threading.Event()
+
+    def binder():
+        go.wait(5.0)
+        with tr.span("scheduler/bind_bulk"):
+            with tr.span("scheduler/bind_call"):
+                pass
+        done.set()
+
+    t = threading.Thread(target=binder, name="binder-0")
+    t.start()
+    with tr.span("scheduler/cycle") as root:
+        with tr.span("scheduler/drain_gate"):
+            go.set()
+            assert done.wait(5.0)
+        with tr.span("scheduler/encode_pods"):
+            pass
+    t.join(5.0)
+    assert not t.is_alive()
+    by = {s.name: s for s in tr.spans()}
+    me = threading.current_thread().name
+    for child in ("scheduler/drain_gate", "scheduler/encode_pods"):
+        assert by[child].parent_id == root.span_id
+        assert by[child].trace_id == root.trace_id
+        assert by[child].thread == me
+    assert by["scheduler/bind_bulk"].parent_id == 0
+    assert by["scheduler/bind_bulk"].trace_id != root.trace_id
+    assert by["scheduler/bind_call"].parent_id == \
+        by["scheduler/bind_bulk"].span_id
+    assert by["scheduler/bind_call"].thread == "binder-0"
+
+
+@pytest.mark.parametrize("kind", ["sleeping", "spinning"])
+def test_span_records_thread_and_cpu_time(kind):
+    tr = Tracer()
+    with tr.span(kind) as sp:
+        if kind == "sleeping":
+            time.sleep(0.2)
+        else:
+            end = time.perf_counter() + 0.2
+            while time.perf_counter() < end:
+                pass
+    wall = sp.end - sp.start
+    assert sp.thread == threading.current_thread().name
+    assert wall >= 0.19
+    if kind == "sleeping":
+        assert sp.cpu_s < 0.25 * wall       # wall >> CPU: it stood
+        assert sp.blocked_s > 0.75 * wall
+    else:
+        assert sp.cpu_s > 0.5 * wall        # it had the CPU (shared box)
+        assert sp.blocked_s == pytest.approx(max(wall - sp.cpu_s, 0.0))
+
+
+def test_blocked_totals_match_the_rings_sums():
+    tr = Tracer()
+    for i in range(7):
+        with tr.span("a" if i % 2 else "b"):
+            time.sleep(0.002)
+    ring = {}
+    for sp in tr.spans():
+        ring[sp.name] = ring.get(sp.name, 0.0) + sp.blocked_s
+        assert 0.0 <= sp.blocked_s <= sp.end - sp.start
+    totals = tr.blocked_totals()
+    assert set(totals) == {"a", "b"}
+    for name, blocked in totals.items():
+        assert blocked == pytest.approx(ring[name])
+    # the ring turns over and resets; the totals are since process start
+    tr.reset()
+    with tr.span("a"):
+        time.sleep(0.002)
+    assert tr.blocked_totals()["a"] > totals["a"]
+    assert tr.blocked_totals()["b"] == totals["b"]
+
+
+def test_a_discarded_span_reaches_neither_the_ring_nor_the_totals():
+    tr = Tracer()
+    with tr.span("kept"):
+        with tr.span("gone") as sp:
+            sp.discard = True
+            with tr.span("child") as child:
+                pass
+    assert [s.name for s in tr.spans()] == ["child", "kept"]
+    assert child.parent_id == sp.span_id      # nesting is untouched
+    assert "gone" not in tr.blocked_totals()
+    assert tr.dropped == 0
+
+
+def test_annotate_hook_once_a_sampled_span_never_an_unsampled_one():
+    entered, exited = [], []
+
+    class Mirror:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            exited.append(self.name)
+
+    tr = Tracer(ratio=0.25)
+    tr.annotate = Mirror
+    kept = 0
+    for i in range(40):
+        with tr.span(f"s{i}") as sp:
+            kept += sp is not None
+    assert kept == 10 == len(tr.spans())
+    assert entered == exited == [s.name for s in tr.spans()]
+    # the mirror closes when the body raises, too
+    tr2 = Tracer()
+    tr2.annotate = Mirror
+    with pytest.raises(KeyError):
+        with tr2.span("boom"):
+            raise KeyError("x")
+    assert entered[-1] == exited[-1] == "boom"
+    assert [s.name for s in tr2.spans()] == ["boom"]
+
+
+def test_tracing_imports_and_records_with_jax_unimportable():
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "sys.modules['jax'] = None\n"   # any 'import jax' now raises
+        "sys.modules['jaxlib'] = None\n"
+        "from kubernetes_tpu.utils.tracing import TRACER, FLIGHT\n"
+        "from kubernetes_tpu.metrics.registry import REGISTRY\n"
+        "with TRACER.span('apiserver/request') as sp:\n"
+        "    pass\n"
+        "assert sp.thread == 'MainThread' and TRACER.annotate is None\n"
+        "FLIGHT.record('ns/p', 'informer')\n"
+        "text = REGISTRY.expose_text()\n"
+        "assert 'scheduler_span_blocked_seconds_total{span=\"apiserver/"
+        "request\"} ' in text\n"
+        "assert 'jax' not in [m for m in sys.modules if sys.modules[m]]\n"
+        "print('ok')\n")
+    import os
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=root,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0 and proc.stdout.strip() == "ok", proc.stderr
 
 
 # ---------------------------------------------------------------- sampling
@@ -169,6 +317,39 @@ def test_export_chrome_schema_and_content(tmp_path):
     assert any(e["args"].get("name") == "default/p0" for e in meta)
 
 
+def test_exports_carry_thread_lanes_and_cpu_time():
+    tr = Tracer()
+
+    def worker():
+        with tr.span("scheduler/resolver_fetch"):
+            pass
+
+    with tr.span("scheduler/cycle"):
+        with tr.span("scheduler/apply"):
+            pass
+    t = threading.Thread(target=worker, name="drain-resolver")
+    t.start()
+    t.join(5.0)
+    doc = tr.export_chrome(flight=FlightRecorder(enabled=False))
+    assert validate_chrome_trace(doc) == []
+    xs = {e["name"]: e for e in doc["traceEvents"] if e["ph"] == "X"}
+    lanes = {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+             if e["name"] == "thread_name" and e["pid"] == 1}
+    # one lane a thread, named after it; a child shares its parent's lane
+    assert xs["scheduler/cycle"]["tid"] == xs["scheduler/apply"]["tid"]
+    assert lanes[xs["scheduler/cycle"]["tid"]] == \
+        threading.current_thread().name
+    assert lanes[xs["scheduler/resolver_fetch"]["tid"]] == "drain-resolver"
+    assert len(lanes) == 2
+    for e in xs.values():
+        assert e["args"]["cpu_ms"] >= 0.0 and e["args"]["trace_id"]
+    otlp = export_otlp_json(tr)
+    for sp in otlp["resourceSpans"][0]["scopeSpans"][0]["spans"]:
+        attrs = {a["key"]: a["value"] for a in sp["attributes"]}
+        assert attrs["thread.name"]["stringValue"] in lanes.values()
+        assert attrs["thread.cpu_time_s"]["doubleValue"] >= 0.0
+
+
 def test_export_chrome_max_events_keeps_newest():
     tr = Tracer()
     for i in range(10):
@@ -201,17 +382,54 @@ def test_validate_chrome_trace_rejects_garbage():
 
 # --------------------------------------------------------- flight recorder
 
-def test_flight_recorder_per_pod_ring_and_pod_eviction():
-    fl = FlightRecorder(max_pods=2, max_events=3, enabled=True)
-    for i in range(5):
-        fl.record("ns/a", f"stage{i}")
-    tl = fl.timeline("ns/a")
-    assert [e["stage"] for e in tl] == ["stage2", "stage3", "stage4"]
-    fl.record("ns/b", "informer")
-    fl.record("ns/c", "informer")  # evicts ns/a (oldest inserted)
-    assert fl.timeline("ns/a") == []
-    assert fl.stats()["droppedPods"] == 1
-    assert set(fl.keys()) == {"ns/b", "ns/c"}
+@pytest.mark.parametrize("case", ["open_past_hard_cap", "closed_first",
+                                  "window_of_10000"])
+def test_flight_recorder_per_pod_ring_and_pod_eviction(case):
+    if case == "open_past_hard_cap":
+        # nothing is closed: the recorder grows to OPEN_FACTOR x max_pods,
+        # then the oldest OPEN timeline goes, counted
+        fl = FlightRecorder(max_pods=1, max_events=3, enabled=True)
+        cap = fl.OPEN_FACTOR * fl.max_pods
+        for i in range(5):
+            fl.record("ns/a", f"stage{i}")
+        tl = fl.timeline("ns/a")
+        assert [e["stage"] for e in tl] == ["stage2", "stage3", "stage4"]
+        for i in range(1, cap):
+            fl.record(f"ns/b{i}", "informer")
+        assert len(fl.keys()) == cap and fl.stats()["droppedPods"] == 0
+        fl.record("ns/c", "informer")  # evicts ns/a (oldest inserted)
+        assert fl.timeline("ns/a") == []
+        assert fl.stats()["droppedPods"] == 1
+        assert len(fl.keys()) == cap and "ns/c" in fl.keys()
+    elif case == "closed_first":
+        # a bound timeline makes room before any open one does, however
+        # old the open one is; that is turnover, not a drop
+        fl = FlightRecorder(max_pods=2, enabled=True)
+        fl.record("ns/old-open", "informer")
+        fl.record("ns/bound", "informer")
+        fl.record("ns/bound", "bind", node="n0")
+        fl.record("ns/new", "informer")
+        assert set(fl.keys()) == {"ns/old-open", "ns/new"}
+        assert fl.stats()["droppedPods"] == 0
+        # with nothing closed left the recorder grows past max_pods
+        fl.record("ns/newer", "informer")
+        assert set(fl.keys()) == {"ns/old-open", "ns/new", "ns/newer"}
+        assert fl.stats()["droppedPods"] == 0
+    else:
+        # the benchmark's burst: 10,000 pods open at once fit the DEFAULT
+        # recorder and keep their first stamp through 10,000 later pods
+        # that come and bind around them
+        fl = FlightRecorder(enabled=True)
+        for i in range(10_000):
+            fl.record(f"burst/p{i}", "informer")
+        first = fl.timeline("burst/p0")[0]["ts"]
+        for i in range(10_000):
+            fl.record(f"later/p{i}", "informer")
+            fl.record(f"later/p{i}", "bind", node="n0")
+        assert fl.stats()["droppedPods"] == 0
+        assert fl.timeline("burst/p0")[0]["ts"] == first
+        assert sum(k.startswith("burst/") for k in fl.keys()) == 10_000
+        assert fl.stats()["pods"] <= fl.OPEN_FACTOR * fl.max_pods
 
 
 def test_flight_recorder_disabled_is_noop():
@@ -220,17 +438,30 @@ def test_flight_recorder_disabled_is_noop():
     assert fl.timeline("ns/a") == [] and fl.stats()["pods"] == 0
 
 
-def test_flight_recorder_bind_observes_e2e_histograms():
+@pytest.mark.parametrize("evicted", [False, True])
+def test_flight_recorder_bind_observes_e2e_histograms(evicted):
     from kubernetes_tpu.metrics.registry import E2E_DURATION, E2E_SCHEDULING
-    base_e2e = E2E_SCHEDULING.count()
-    base_sli = E2E_DURATION.count()
-    fl = FlightRecorder(enabled=True)
+    fl = FlightRecorder(max_pods=1, enabled=True)
     fl.record("ns/p", "informer")
     fl.record("ns/p", "queue_add")
     fl.record("ns/p", "dispatch")
+    if evicted:
+        for i in range(fl.OPEN_FACTOR):  # pushes ns/p out, still open
+            fl.record(f"ns/other{i}", "informer")
+        assert fl.timeline("ns/p") == []
+    base_e2e = E2E_SCHEDULING.count()
+    base_sli = E2E_DURATION.count()
+    drops = fl.stats()["droppedPods"]
     fl.record("ns/p", "bind", node="n0")
-    assert E2E_SCHEDULING.count() == base_e2e + 1
-    assert E2E_DURATION.count() == base_sli + 1
+    if evicted:
+        # the first stamp is gone: the histograms stay silent, never ~0 s
+        assert E2E_SCHEDULING.count() == base_e2e
+        assert E2E_DURATION.count() == base_sli
+        assert fl.stats()["droppedPods"] == drops + 1
+        assert fl.timeline("ns/p") == []
+    else:
+        assert E2E_SCHEDULING.count() == base_e2e + 1
+        assert E2E_DURATION.count() == base_sli + 1
 
 
 def test_flight_recorder_new_incarnation_resets_closed_timeline():
