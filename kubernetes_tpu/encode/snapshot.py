@@ -27,6 +27,7 @@ Design notes:
 from __future__ import annotations
 
 import math
+import weakref
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Optional
 
@@ -59,6 +60,7 @@ from kubernetes_tpu.encode.termprep import (
     resolve_term_namespaces,
     spread_selector,
 )
+from kubernetes_tpu.metrics.registry import REGISTRY, series_lines
 
 # --- integer op/effect codes used inside tensors -------------------------------
 
@@ -112,6 +114,30 @@ EMPTY_VALUE_ID = 0  # "" pre-interned: empty taint values / tolerations compare 
 # row-pack cache keys on (resources, K, NSB) + these widths)
 _ROW_DIMS = ("TREQ", "TPREF", "VT", "VG", "VB", "X", "VV", "S", "TOL",
              "PP", "CI", "AT", "BT", "CT", "SC", "AX", "AV")
+
+# The twelve constraint groups of a pod's row pack and the pack fields each
+# brings (``SnapshotEncoder._build_rows``). A pack holds a group only when
+# the pod populates it: absence is the representation, and a group's first
+# field is the membership probe ``encode_pods`` reads.
+_TERM_FIELDS = ("key", "op", "vals", "num", "expr_valid", "term_valid",
+                "weight")
+_SEL_FIELDS = ("sel_key", "sel_op", "sel_vals", "sel_expr_valid",
+               "sel_valid", "topo", "valid", "ns_explicit", "ns_mask")
+_ROW_GROUPS = {
+    "tols": ("tol_key", "tol_op", "tol_val", "tol_effect", "tol_valid"),
+    "sel": ("sel_key", "sel_val", "sel_valid"),
+    "req": tuple(f"req_{f}" for f in _TERM_FIELDS),
+    "pref": tuple(f"pref_{f}" for f in _TERM_FIELDS),
+    "vol": tuple(f"vol_{f}" for f in _TERM_FIELDS),
+    "volumes": ("vol_group", "vol_group_valid", "rwo_pv", "rwo_valid"),
+    "ports": ("port_proto", "port_port", "port_ip", "port_valid"),
+    "images": ("images",),
+    "aff": tuple(f"aff_{f}" for f in _SEL_FIELDS),
+    "anti": tuple(f"anti_{f}" for f in _SEL_FIELDS),
+    "paff": tuple(f"paff_{f}" for f in _SEL_FIELDS + ("weight",)),
+    "sc": tuple(f"sc_{f}" for f in _SEL_FIELDS[:-2] + (
+        "maxskew", "hard", "min_domains", "honor_affinity", "honor_taints")),
+}
 
 
 class TermSet(struct.PyTreeNode):
@@ -350,6 +376,24 @@ def _resource_union(nodes: list[Node], pods: list[Pod]) -> list[str]:
     return seen
 
 
+# live encoders, for the collector below (weak: an encoder nobody holds
+# any more drops out of the exposition)
+_ENCODERS: "weakref.WeakSet[SnapshotEncoder]" = weakref.WeakSet()
+
+
+@REGISTRY.collector
+def _row_group_lines() -> list[str]:
+    """Constraint groups built into row packs against those left to the
+    batch arrays' defaults — the two plain integers every encoder keeps."""
+    encoders = list(_ENCODERS)
+    return series_lines(
+        "scheduler_encode_row_groups_total", "counter",
+        "Constraint groups of pod row packs: built as arrays because the "
+        "pod populates them, or left to the batch default", "kind",
+        {"built": sum(e.row_groups_built for e in encoders),
+         "default": sum(e.row_groups_default for e in encoders)})
+
+
 class SnapshotEncoder:
     """Persistent encoder: intern tables survive across snapshots so ids are
     stable and incremental re-encoding stays cheap."""
@@ -417,6 +461,12 @@ class SnapshotEncoder:
         self._row_env: Optional[tuple] = None  # (resources, K, NSB, widths)
         self.pod_rows_stacked = 0  # rows bulk-assembled from prebuilt packs
         self.pod_rows_filled = 0   # rows built by the per-pod fill loop
+        # constraint groups of _ROW_GROUPS built into row packs / left to
+        # the batch arrays' defaults, bumped once a pack (read at exposition
+        # by _row_group_lines; both writers hold the cache's encode lock)
+        self.row_groups_built = 0
+        self.row_groups_default = 0
+        _ENCODERS.add(self)
 
     def set_volumes(self, catalog) -> None:
         """Attach the PVC/PV/StorageClass catalog consulted by the next
@@ -1132,11 +1182,11 @@ class SnapshotEncoder:
 
     def _compile_pod(self, p: Pod) -> dict:
         """Host-side compile of ONE pod: selectors/affinity terms to int-set
-        tables, tolerations/ports/images interned. This is the expensive
-        half of ``encode_pods`` (the array fill is cheap); it only reads the
-        intern tables (append-only) and the volume/namespace/DRA catalogs,
-        so it can run at informer-event time (``precompile_pod``) instead of
-        on the drain hot path."""
+        tables, tolerations/ports/images interned. One of the two per-pod
+        halves of ``encode_pods`` (the other is the row pack,
+        ``_build_rows``); it only reads the intern tables (append-only) and
+        the volume/namespace/DRA catalogs, so it can run at informer-event
+        time (``precompile_pod``) instead of on the drain hot path."""
         aff = p.spec.affinity
         na = aff.node_affinity if aff else None
         req_pairs = [(t, 1.0) for t in (na.required if na else [])]
@@ -1222,12 +1272,16 @@ class SnapshotEncoder:
         )
 
     def precompile_pod(self, p: Pod) -> bool:
-        """Compile a pod's encode record AND its numpy row pack AHEAD of
+        """Compile a pod's encode record AND its row pack AHEAD of
         batch-encode time — the informer layer calls this per watch event,
-        so by the time the drain pops the pod, ``encode_pods`` pays one
-        np.stack per field, zero per-pod fill work (the incremental-encode
-        half of the connected-path pipeline; see sched/cache.py
-        precompile_pod for the locking discipline).
+        so when the drain pops the pod, ``encode_pods`` stacks the pack's
+        always-present fields and copies the few constraint groups it holds
+        (see sched/cache.py precompile_pod for the locking discipline).
+        This MOVES the compile and the pack's allocations to the informer's
+        thread; it does not make them free. Both threads run on one
+        interpreter, so a pod costs the sum of the two halves wherever they
+        run — which is why a pack holds only what the pod populates
+        (``_build_rows``).
 
         Volume-carrying pods are skipped: their compile reads catalog state
         (``_rwop_in_use``) that every cluster encode rewrites. Returns True
@@ -1350,6 +1404,7 @@ class SnapshotEncoder:
         for k in _ROW_DIMS:
             w[k] = max(w[k], self._row_widths.get(k, 0))
         self._row_widths = {k: w[k] for k in _ROW_DIMS}
+        AX, AV = w["AX"], w["AV"]  # the batch's selector sets, promoted too
         # namespace-mask width: all term ns sets are already interned above
         NSB = next_bucket(len(self.namespaces) + self.ns_headroom, minimum=1)
         sig = (tuple(meta.resources), K, NSB) + tuple(w[k] for k in _ROW_DIMS)
@@ -1357,8 +1412,8 @@ class SnapshotEncoder:
         self._row_env = (list(meta.resources), K, NSB, dict(w))
 
         # Second pass: one row pack per pod — PREBUILT at informer-event
-        # time when the signature matches (the steady state: zero per-pod
-        # fill work on this path), built here otherwise and cached back so
+        # time when the signature matches (the steady state: this thread
+        # then only assembles), built here otherwise and cached back so
         # failure re-pops stack too.
         packs = []
         forced = []
@@ -1469,7 +1524,9 @@ class SnapshotEncoder:
         sc_honor_affinity = np.zeros((P, SC), bool)
         sc_honor_taints = np.zeros((P, SC), bool)
 
-        # ---- assembly: one bulk np.stack per field (no per-pod fill) -----
+        # ---- assembly: the fields every pod has stack whole; a constraint
+        # group writes only the rows of the pods that carry it, over the
+        # defaults the arrays above were allocated with ----------------------
         if n:
             def put(dst, key):
                 dst[:n] = np.stack([pk[key] for pk in packs])
@@ -1485,52 +1542,47 @@ class SnapshotEncoder:
             put_scalar(pod_ns, "ns", np.int32)
             put_scalar(attach_req, "attach_req", np.int32)
             put(pod_labels, "labels")
-            for dst, f in ((tol_key, "tol_key"), (tol_op, "tol_op"),
-                           (tol_val, "tol_val"), (tol_effect, "tol_effect"),
-                           (tol_valid, "tol_valid")):
-                put(dst, f)
-            put(sel_key, "sel_key")
-            put(sel_val, "sel_val")
-            put(sel_valid, "sel_valid")
+
+            dst = dict(
+                tol_key=tol_key, tol_op=tol_op, tol_val=tol_val,
+                tol_effect=tol_effect, tol_valid=tol_valid,
+                sel_key=sel_key, sel_val=sel_val, sel_valid=sel_valid,
+                vol_group=vol_group, vol_group_valid=vol_group_valid,
+                rwo_pv=rwo_pv, rwo_valid=rwo_valid,
+                port_proto=pport_proto, port_port=pport_port,
+                port_ip=pport_ip, port_valid=pport_valid, images=pod_images,
+                aff_topo=aff_topo, aff_valid=aff_valid,
+                aff_ns_explicit=aff_ns_explicit, aff_ns_mask=aff_ns_mask,
+                anti_topo=anti_topo, anti_valid=anti_valid,
+                anti_ns_explicit=anti_ns_explicit, anti_ns_mask=anti_ns_mask,
+                paff_topo=paff_topo, paff_valid=paff_valid,
+                paff_weight=paff_weight, paff_ns_explicit=paff_ns_explicit,
+                paff_ns_mask=paff_ns_mask,
+                sc_topo=sc_topo, sc_valid=sc_valid, sc_maxskew=sc_maxskew,
+                sc_hard=sc_hard, sc_min_domains=sc_min_domains,
+                sc_honor_affinity=sc_honor_affinity,
+                sc_honor_taints=sc_honor_taints)
+            has_any = {}
             for prefix, arrs in (("req", req_a), ("pref", pref_a),
                                  ("vol", vol_a)):
-                for f in ("key", "op", "vals", "num", "expr_valid",
-                          "term_valid", "weight"):
-                    put(arrs[f], f"{prefix}_{f}")
-                put_scalar(arrs["has_any"], f"{prefix}_has_any", bool)
-            put(vol_group, "vol_group")
-            put(vol_group_valid, "vol_group_valid")
-            put(rwo_pv, "rwo_pv")
-            put(rwo_valid, "rwo_valid")
-            put(pport_proto, "port_proto")
-            put(pport_port, "port_port")
-            put(pport_ip, "port_ip")
-            put(pport_valid, "port_valid")
-            put(pod_images, "images")
-            for prefix, selset, extras in (
-                    ("aff", aff_sel,
-                     ((aff_topo, "topo"), (aff_valid, "valid"),
-                      (aff_ns_explicit, "ns_explicit"),
-                      (aff_ns_mask, "ns_mask"))),
-                    ("anti", anti_sel,
-                     ((anti_topo, "topo"), (anti_valid, "valid"),
-                      (anti_ns_explicit, "ns_explicit"),
-                      (anti_ns_mask, "ns_mask"))),
-                    ("paff", paff_sel,
-                     ((paff_topo, "topo"), (paff_valid, "valid"),
-                      (paff_weight, "weight"),
-                      (paff_ns_explicit, "ns_explicit"),
-                      (paff_ns_mask, "ns_mask"))),
-                    ("sc", sc_sel,
-                     ((sc_topo, "topo"), (sc_valid, "valid"),
-                      (sc_maxskew, "maxskew"), (sc_hard, "hard"),
-                      (sc_min_domains, "min_domains"),
-                      (sc_honor_affinity, "honor_affinity"),
-                      (sc_honor_taints, "honor_taints")))):
-                for f in ("key", "op", "vals", "expr_valid", "valid"):
-                    put(selset[f], f"{prefix}_sel_{f}")
-                for dst, f in extras:
-                    put(dst, f"{prefix}_{f}")
+                has_any[prefix] = arrs["has_any"]
+                for f in _TERM_FIELDS:
+                    dst[f"{prefix}_{f}"] = arrs[f]
+            for prefix, selset in (("aff", aff_sel), ("anti", anti_sel),
+                                   ("paff", paff_sel), ("sc", sc_sel)):
+                for f, arr in selset.items():
+                    dst[f"{prefix}_sel_{f}"] = arr
+
+            for group, fields in _ROW_GROUPS.items():
+                idx = [i for i, pk in enumerate(packs) if fields[0] in pk]
+                if not idx:
+                    continue
+                members = [packs[i] for i in idx]
+                at = slice(n) if len(idx) == n else np.array(idx)
+                for f in fields:
+                    dst[f][at] = np.stack([pk[f] for pk in members])
+                if group in has_any:
+                    has_any[group][at] = True
 
         batch_topo = {int(k) for k in np.concatenate([
             aff_topo[aff_valid], anti_topo[anti_valid],
@@ -1565,13 +1617,18 @@ class SnapshotEncoder:
 
     def _build_rows(self, c: dict, resources: list[str], K: int, NSB: int,
                     w: dict) -> dict:
-        """ONE pod's PodBatch rows as small numpy arrays at the bucket
-        signature ``(resources, K, NSB, w)`` — the per-pod half of the
-        vectorized ``encode_pods`` assembly. Runs at informer-event time
-        (``precompile_pod``) in the steady state; the batch hot path then
-        does one np.stack per field and no per-pod fill work. Raises
-        IndexError when the pod outgrows the widths (callers treat that as
-        "no pack"; encode_pods always passes covering widths)."""
+        """ONE pod's row pack at the bucket signature ``(resources, K, NSB,
+        w)``: the fields every pod has (``requests``, ``labels``, three
+        scalars) and, for each constraint group of ``_ROW_GROUPS`` in which
+        the compiled record ``c`` has entries, that group's small numpy
+        arrays. A group the pod does not populate is ABSENT from the pack —
+        ``encode_pods``' batch arrays already hold its defaults, so there
+        is nothing to build here and nothing to copy there: a pod with no
+        constraints allocates two arrays. Runs on the informer's thread
+        (``precompile_pod``) or on the loop's (cold and re-pop path), the
+        same function either way. Raises IndexError when the pod outgrows
+        the widths (callers treat that as "no pack"; encode_pods always
+        passes covering widths)."""
         X, VV, AX, AV = w["X"], w["VV"], w["AX"], w["AV"]
         p: Pod = c["pod"]
         rows: dict = {
@@ -1586,27 +1643,31 @@ class SnapshotEncoder:
             labels[kid] = vid
         rows["labels"] = labels
 
-        tol_key = np.full(w["TOL"], -1, np.int32)
-        tol_op = np.zeros(w["TOL"], np.int32)
-        tol_val = np.full(w["TOL"], -1, np.int32)
-        tol_effect = np.full(w["TOL"], -1, np.int32)
-        tol_valid = np.zeros(w["TOL"], bool)
-        for t_idx, (kid, opc, vid, eff) in enumerate(c["tols"]):
-            tol_key[t_idx], tol_op[t_idx] = kid, opc
-            tol_val[t_idx], tol_effect[t_idx] = vid, eff
-            tol_valid[t_idx] = True
-        rows.update(tol_key=tol_key, tol_op=tol_op, tol_val=tol_val,
-                    tol_effect=tol_effect, tol_valid=tol_valid)
+        if c["tols"]:
+            tol_key = np.full(w["TOL"], -1, np.int32)
+            tol_op = np.zeros(w["TOL"], np.int32)
+            tol_val = np.full(w["TOL"], -1, np.int32)
+            tol_effect = np.full(w["TOL"], -1, np.int32)
+            tol_valid = np.zeros(w["TOL"], bool)
+            for t_idx, (kid, opc, vid, eff) in enumerate(c["tols"]):
+                tol_key[t_idx], tol_op[t_idx] = kid, opc
+                tol_val[t_idx], tol_effect[t_idx] = vid, eff
+                tol_valid[t_idx] = True
+            rows.update(tol_key=tol_key, tol_op=tol_op, tol_val=tol_val,
+                        tol_effect=tol_effect, tol_valid=tol_valid)
 
-        sel_key = np.full(w["S"], -1, np.int32)
-        sel_val = np.full(w["S"], -1, np.int32)
-        sel_valid = np.zeros(w["S"], bool)
-        for s_idx, (kid, vid) in enumerate(c["sel"]):
-            sel_key[s_idx], sel_val[s_idx] = kid, vid
-            sel_valid[s_idx] = True
-        rows.update(sel_key=sel_key, sel_val=sel_val, sel_valid=sel_valid)
+        if c["sel"]:
+            sel_key = np.full(w["S"], -1, np.int32)
+            sel_val = np.full(w["S"], -1, np.int32)
+            sel_valid = np.zeros(w["S"], bool)
+            for s_idx, (kid, vid) in enumerate(c["sel"]):
+                sel_key[s_idx], sel_val[s_idx] = kid, vid
+                sel_valid[s_idx] = True
+            rows.update(sel_key=sel_key, sel_val=sel_val, sel_valid=sel_valid)
 
         def termset_rows(prefix, T, terms):
+            if not terms:
+                return
             a = dict(
                 key=np.full((T, X), -1, np.int32),
                 op=np.zeros((T, X), np.int32),
@@ -1628,71 +1689,77 @@ class SnapshotEncoder:
                         a["vals"][t_idx, x_idx, v_idx] = v
             for f, arr in a.items():
                 rows[f"{prefix}_{f}"] = arr
-            rows[f"{prefix}_has_any"] = len(terms) > 0
 
-        vol_terms = [(float(g), e) for g, e in c["vol_terms"]]
         termset_rows("req", w["TREQ"], c["req_terms"])
         termset_rows("pref", w["TPREF"], c["pref_terms"])
         # vol terms reuse the TermSet layout with group id in place of
         # weight, then split the group id out into vol_group
-        termset_rows("vol", w["VT"], vol_terms)
-        vol_group = np.full(w["VT"], -1, np.int32)
-        for t_idx, (g, _e) in enumerate(c["vol_terms"]):
-            vol_group[t_idx] = g
-        vol_group_valid = np.zeros(w["VG"], bool)
-        vol_group_valid[:c["vol_groups"]] = True
-        rwo_pv = np.full(w["VB"], -1, np.int32)
-        rwo_valid = np.zeros(w["VB"], bool)
-        for b_idx, pvid in enumerate(c["vol_rwo"]):
-            rwo_pv[b_idx] = pvid
-            rwo_valid[b_idx] = True
-        rows.update(vol_group=vol_group, vol_group_valid=vol_group_valid,
-                    rwo_pv=rwo_pv, rwo_valid=rwo_valid)
+        termset_rows("vol", w["VT"],
+                     [(float(g), e) for g, e in c["vol_terms"]])
+        if c["vol_terms"] or c["vol_groups"] or c["vol_rwo"]:
+            vol_group = np.full(w["VT"], -1, np.int32)
+            for t_idx, (g, _e) in enumerate(c["vol_terms"]):
+                vol_group[t_idx] = g
+            vol_group_valid = np.zeros(w["VG"], bool)
+            vol_group_valid[:c["vol_groups"]] = True
+            rwo_pv = np.full(w["VB"], -1, np.int32)
+            rwo_valid = np.zeros(w["VB"], bool)
+            for b_idx, pvid in enumerate(c["vol_rwo"]):
+                rwo_pv[b_idx] = pvid
+                rwo_valid[b_idx] = True
+            rows.update(vol_group=vol_group, vol_group_valid=vol_group_valid,
+                        rwo_pv=rwo_pv, rwo_valid=rwo_valid)
 
-        port_proto = np.full(w["PP"], -1, np.int32)
-        port_port = np.full(w["PP"], -1, np.int32)
-        port_ip = np.full(w["PP"], -1, np.int32)
-        port_valid = np.zeros(w["PP"], bool)
-        for pt_idx, (proto, port, ip) in enumerate(c["ports"]):
-            port_proto[pt_idx], port_port[pt_idx] = proto, port
-            port_ip[pt_idx] = ip
-            port_valid[pt_idx] = True
-        rows.update(port_proto=port_proto, port_port=port_port,
-                    port_ip=port_ip, port_valid=port_valid)
+        if c["ports"]:
+            port_proto = np.full(w["PP"], -1, np.int32)
+            port_port = np.full(w["PP"], -1, np.int32)
+            port_ip = np.full(w["PP"], -1, np.int32)
+            port_valid = np.zeros(w["PP"], bool)
+            for pt_idx, (proto, port, ip) in enumerate(c["ports"]):
+                port_proto[pt_idx], port_port[pt_idx] = proto, port
+                port_ip[pt_idx] = ip
+                port_valid[pt_idx] = True
+            rows.update(port_proto=port_proto, port_port=port_port,
+                        port_ip=port_ip, port_valid=port_valid)
 
-        images = np.full(w["CI"], -1, np.int32)
-        for ci_idx, img in enumerate(c["images"]):
-            images[ci_idx] = img
-        rows["images"] = images
+        if c["images"]:
+            images = np.full(w["CI"], -1, np.int32)
+            for ci_idx, img in enumerate(c["images"]):
+                images[ci_idx] = img
+            rows["images"] = images
 
-        def selset_rows(prefix, T, items, scalars):
-            """items: [(topo, valid, exprs, *extras, ns_ids)] with extras
-            per ``scalars``: [(name, dtype, default)]."""
+        def selset_rows(prefix, T, items, scalars, ns=True):
+            """items: [(topo, valid, exprs, *extras)] with extras per
+            ``scalars``: [(name, dtype, default)], and ns_ids last when the
+            set has namespaces (``ns``)."""
+            if not items:
+                return
             a = _selset_arrays((T,), AX, AV)
             topo = np.full(T, -1, np.int32)
             valid = np.zeros(T, bool)
-            ns_explicit = np.zeros(T, bool)
-            ns_mask = np.zeros((T, NSB), bool)
             extra_arrs = {nm: np.full(T, dflt, dt)
                           for nm, dt, dflt in scalars}
+            if ns:
+                ns_explicit = np.zeros(T, bool)
+                ns_mask = np.zeros((T, NSB), bool)
             for t_idx, item in enumerate(items):
                 tk, sv, exprs = item[0], item[1], item[2]
-                ns_ids = item[-1]
                 topo[t_idx] = tk
                 valid[t_idx] = True
                 _selset_fill(a, (t_idx,), sv, exprs)
-                for (nm, _dt, _df), val in zip(scalars, item[3:-1]):
+                for (nm, _dt, _df), val in zip(scalars, item[3:]):
                     extra_arrs[nm][t_idx] = val
-                if ns_ids is not None:
+                if ns and item[-1] is not None:
                     ns_explicit[t_idx] = True
-                    for nid in ns_ids:
+                    for nid in item[-1]:
                         ns_mask[t_idx, nid] = True
             for f, arr in a.items():
                 rows[f"{prefix}_sel_{f}"] = arr
             rows[f"{prefix}_topo"] = topo
             rows[f"{prefix}_valid"] = valid
-            rows[f"{prefix}_ns_explicit"] = ns_explicit
-            rows[f"{prefix}_ns_mask"] = ns_mask
+            if ns:
+                rows[f"{prefix}_ns_explicit"] = ns_explicit
+                rows[f"{prefix}_ns_mask"] = ns_mask
             for nm, arr in extra_arrs.items():
                 rows[f"{prefix}_{nm}"] = arr
 
@@ -1700,12 +1767,14 @@ class SnapshotEncoder:
         selset_rows("anti", w["BT"], c["anti_req"], [])
         selset_rows("paff", w["CT"], c["paff"],
                     [("weight", np.float32, 0.0)])
-        # spreads: (topo, valid, exprs, skew, hard, mind, haff, htaint) —
-        # no ns_ids slot, so append a None sentinel for the shared driver
-        selset_rows("sc", w["SC"],
-                    [t + (None,) for t in c["spreads"]],
+        # spreads: (topo, valid, exprs, skew, hard, mind, haff, htaint),
+        # no namespaces
+        selset_rows("sc", w["SC"], c["spreads"],
                     [("maxskew", np.int32, 1), ("hard", bool, False),
                      ("min_domains", np.int32, 0),
                      ("honor_affinity", bool, False),
-                     ("honor_taints", bool, False)])
+                     ("honor_taints", bool, False)], ns=False)
+        built = sum(fields[0] in rows for fields in _ROW_GROUPS.values())
+        self.row_groups_built += built
+        self.row_groups_default += len(_ROW_GROUPS) - built
         return rows

@@ -328,14 +328,16 @@ ENCODE_POD_CACHE_HITS = REGISTRY.gauge(
 ENCODE_POD_CACHE_MISSES = REGISTRY.gauge(
     "scheduler_encode_pod_cache_misses",
     "Pod rows compiled on the batch-encode hot path")
-# Row-pack vectorized batch assembly (encode/snapshot.py encode_pods):
-# stacked rows arrived prebuilt (informer-time) and were bulk np.stack'ed;
-# filled rows paid the per-pod Python array-fill loop on the hot path. A
-# healthy connected run shows stacked >> filled (fill-only cycles do no
-# per-pod fill work at all).
+# Row-pack batch assembly (encode/snapshot.py encode_pods): a stacked row's
+# pack arrived prebuilt (informer-time); a filled row's pack was built by
+# the loop's thread on the hot path. A healthy connected run shows stacked
+# >> filled — which says WHERE the pack was built, not that it was free:
+# the two threads share one interpreter. What a pack costs is the groups it
+# holds, counted by scheduler_encode_row_groups_total{kind} (a collector in
+# encode/snapshot.py).
 ENCODE_POD_ROWS_STACKED = REGISTRY.gauge(
     "scheduler_encode_pod_rows_stacked",
-    "Pod rows bulk-assembled from prebuilt row packs (no per-pod fill)")
+    "Pod rows assembled from row packs prebuilt on the informer's thread")
 ENCODE_POD_ROWS_FILLED = REGISTRY.gauge(
     "scheduler_encode_pod_rows_filled",
     "Pod rows built by the per-pod array-fill loop on the encode hot path")

@@ -668,11 +668,16 @@ class SchedulerCache:
 
     def precompile_pod(self, pod: Pod) -> None:
         """Informer-event-time half of the incremental encode: compile the
-        pod's encode record NOW (watch thread) so the drain's encode_pods
-        later is array-fill only. NON-BLOCKING on the encode lock — if the
-        scheduling loop is mid-encode, skipping is strictly better than
-        convoying the watch thread behind a multi-hundred-ms encode (the
-        pod simply compiles on the hot path as before)."""
+        pod's encode record and build its row pack NOW (watch thread) so
+        the drain's encode_pods later only assembles. That moves the work
+        between two threads of one interpreter, it does not hide it: the
+        loop waits for the GIL while this runs, so what a pack costs here
+        is paid by the window all the same (PERF.md section 5), and a pack
+        holds only the constraint groups its pod populates. NON-BLOCKING
+        on the encode lock — if the scheduling loop is mid-encode, skipping
+        is strictly better than convoying the watch thread behind a
+        multi-hundred-ms encode (the pod simply compiles on the hot path
+        as before)."""
         if not self._encode_lock.acquire(blocking=False):
             return
         try:
@@ -685,8 +690,11 @@ class SchedulerCache:
     def encode_cache_stats(self) -> dict[str, int]:
         """Hit/miss counters of the pod compile cache plus the row-pack
         assembly split (benchmarks report these: a healthy connected run
-        shows hits >> misses and rows_stacked >> rows_filled — fill-only
-        cycles do no per-pod fill work at all)."""
+        shows hits >> misses and rows_stacked >> rows_filled). A stacked
+        row was built on the informer's thread rather than the loop's —
+        moved, not saved; how much a pack holds is the encoder's
+        ``row_groups_built`` / ``row_groups_default`` pair
+        (``scheduler_encode_row_groups_total``)."""
         return {"hits": self._encoder.pod_cache_hits,
                 "misses": self._encoder.pod_cache_misses,
                 "rows_stacked": self._encoder.pod_rows_stacked,

@@ -3,6 +3,7 @@ which thread had the CPU, how long pods waited in the queue and the loop
 for pods — the series and spans the benchmark's per-layer metrics read
 (PERF.md section 3, table "span or counter -> thread -> metric")."""
 
+import gc
 import re
 import threading
 import time
@@ -163,6 +164,10 @@ def served():
             .label("kubernetes.io/hostname", f"n{i}").obj().to_dict())
     runner = SchedulerRunner(HTTPClient(server.url), SchedulerConfiguration(
         batch_size=8, max_drain_batches=2, parity_sample_every=1))
+    # the informer and encoder collectors sum over weakly held objects: one
+    # that an earlier test of this process left unreachable must drop out
+    # BEFORE the first scrape, not between the two
+    gc.collect()
     before = series()
     TRACER.reset()
     flight_was, FLIGHT.enabled = FLIGHT.enabled, True

@@ -146,14 +146,20 @@ def test_nesting_is_per_thread_with_cycle_as_root():
 @pytest.mark.parametrize("kind", ["sleeping", "spinning"])
 def test_span_records_thread_and_cpu_time(kind):
     tr = Tracer()
-    with tr.span(kind) as sp:
-        if kind == "sleeping":
-            time.sleep(0.2)
-        else:
-            end = time.perf_counter() + 0.2
-            while time.perf_counter() < end:
-                pass
-    wall = sp.end - sp.start
+    # a spin can lose its core to another xdist worker's compile threads
+    # for most of 0.2 s: the best of a few attempts says what the span
+    # records when the thread does have the CPU
+    for _attempt in range(5):
+        with tr.span(kind) as sp:
+            if kind == "sleeping":
+                time.sleep(0.2)
+            else:
+                end = time.perf_counter() + 0.2
+                while time.perf_counter() < end:
+                    pass
+        wall = sp.end - sp.start
+        if kind == "sleeping" or sp.cpu_s > 0.5 * wall:
+            break
     assert sp.thread == threading.current_thread().name
     assert wall >= 0.19
     if kind == "sleeping":
