@@ -11,7 +11,7 @@ from __future__ import annotations
 import logging
 import threading
 import time
-from bisect import bisect_right
+from bisect import bisect_left
 from typing import Optional
 
 _LOG = logging.getLogger(__name__)
@@ -112,7 +112,8 @@ class Histogram(_Metric):
         k = _label_key(labels)
         with self._lock:
             counts = self._counts.setdefault(k, [0] * len(self.buckets))
-            i = bisect_right(self.buckets, value)
+            # a bucket's bound is inclusive (Prometheus ``le``)
+            i = bisect_left(self.buckets, value)
             for j in range(i, len(self.buckets)):
                 counts[j] += n
             self._sums[k] = self._sums.get(k, 0.0) + value * n
@@ -124,7 +125,7 @@ class Histogram(_Metric):
         per_bucket = [0] * (len(self.buckets) + 1)
         total, n = 0.0, 0
         for v in values:
-            per_bucket[bisect_right(self.buckets, v)] += 1
+            per_bucket[bisect_left(self.buckets, v)] += 1
             total += v
             n += 1
         if not n:

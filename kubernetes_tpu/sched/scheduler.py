@@ -544,10 +544,14 @@ class Scheduler:
         cap = self.cfg.batch_size * max(1, self.cfg.max_drain_batches)
         with TRACER.span("scheduler/pop_wait",
                          inflight=len(self._pending)) as sp:
+            # a parked fragment is work in hand like a drain in flight:
+            # it waits the short wait for arrivals to merge with, never
+            # the idle one
+            in_hand = bool(self._pending or self._staged)
             batch = self.queue.pop_batch(
                 max(1, cap - len(self._staged)),
-                wait=0.05 if self._pending else wait)
-            idle = not (batch or self._pending or self._staged)
+                wait=0.05 if in_hand else wait)
+            idle = not (batch or in_hand)
             if sp is not None:
                 sp.attributes["got"] = len(batch)
                 sp.discard = idle and self._idle_wait is not None
@@ -1670,7 +1674,10 @@ class Scheduler:
         ctx, meta, profile = pend["ctx"], pend["meta"], pend["profile"]
         active = self._drain_ctx is ctx
         pend_count = sum(len(c) for c in pend["chunks"])
-        GANG_ROUNDS.observe(int(np.sum(rounds)))
+        # one observation a batch that held pods (the drain's padding
+        # batches converge in one dead round and are not a gang batch)
+        GANG_ROUNDS.observe_many(
+            int(r) for chunk, r in zip(pend["chunks"], rounds) if chunk)
         # nominations that arrived while this drain was on the device (the
         # descheduler writes them right before evicting): the dispatched
         # program could not reserve them, so winners re-check here — same

@@ -308,6 +308,43 @@ def test_an_idle_loop_does_not_turn_the_ring_over():
         TRACER.reset()
 
 
+def test_a_parked_fragment_is_not_held_for_the_idle_wait():
+    """A short pop right after a full one is parked once to merge with
+    later arrivals (_run_batch). When none come and nothing is in flight,
+    the next pop waits the short wait of a busy loop, not the idle one:
+    the burst's tail is dispatched, not held."""
+    from kubernetes_tpu.sched.cache import SchedulerCache
+    from kubernetes_tpu.sched.scheduler import Scheduler
+    cache = SchedulerCache()
+    for i in range(4):
+        cache.add_node(make_node(f"n{i}").capacity(
+            {"cpu": "8", "memory": "16Gi", "pods": "32"}).obj())
+    queue = SchedulingQueue()
+    sched = Scheduler(SchedulerConfiguration(batch_size=4,
+                                             max_drain_batches=2),
+                      cache, queue, lambda pod, node: True)
+    try:
+        for i in range(10):
+            queue.add(make_pod(f"tail-p{i}").req({"cpu": "100m"}).obj())
+        assert sched.run_once(wait=0.01) == 0  # a full pop of 8, in flight
+        assert len(sched._pending) == 1
+        assert sched.run_once(wait=0.01) == 8  # the tail is parked,
+        assert len(sched._staged) == 2         # the drain before it settled
+        assert not sched._pending
+        TRACER.reset()
+        sched.run_once(wait=30.0)
+        assert not sched._staged
+        wait, = TRACER.spans("scheduler/pop_wait")
+        assert wait.attributes["got"] == 0 and wait.end - wait.start < 1.0
+        while sched.run_once(wait=0.01) or sched._pending:
+            pass
+        sched.wait_for_bindings(10.0)
+        assert len(cache.bound_pods(include_assumed=True)) == 10
+    finally:
+        sched.close()
+        TRACER.reset()
+
+
 def test_every_span_and_series_of_the_account_feeds_a_metric_file():
     """The rule that keeps the account honest: a span or series of the
     served path that no per-layer metric reads does not stay. Every span
@@ -331,6 +368,84 @@ def test_every_span_and_series_of_the_account_feeds_a_metric_file():
         m.name for m in REGISTRY._metrics.values()}
     assert collected and collected <= read, sorted(collected - read)
     assert "scheduler_queue_wait_seconds_sum" in read
+
+
+# ------------------------------------------------------------- gang rounds
+
+def test_gang_rounds_are_observed_once_a_batch_that_held_pods():
+    """scheduler_gang_rounds is what its help text and buckets say: one
+    observation a gang batch, with that batch's rounds. A drain is padded
+    to max_drain_batches; the padding batches are not observed. Every pod
+    repels every other on hostname (the antiaffinity deployment's pods),
+    so a batch takes more than its one placing round."""
+    import json
+    import os
+
+    import numpy as np
+    from kubernetes_tpu.api import Node, Pod
+    from kubernetes_tpu.metrics.registry import GANG_ROUNDS
+    from kubernetes_tpu.sched.cache import SchedulerCache
+    from kubernetes_tpu.sched.scheduler import Scheduler
+    from yardstick.generators import upstream_pod_anti_affinity
+    from yardstick.readers import series_ratio
+    assert GANG_ROUNDS.help == "Conflict-resolution rounds per gang batch"
+    nodes, pods = upstream_pod_anti_affinity.generate(0, 12, 9)
+    for p in pods:
+        p["metadata"]["namespace"] = "sched-1"
+    cache = SchedulerCache()
+    for n in nodes:
+        cache.add_node(Node.from_dict(n))
+    queue = SchedulingQueue()
+    cfg = SchedulerConfiguration(batch_size=4, max_drain_batches=2)
+    sched = Scheduler(cfg, cache, queue, lambda pod, node: True)
+    drains = []
+    submit = sched._submit_resolve
+
+    def spy(pend):
+        drains.append(pend)
+        submit(pend)
+
+    sched._submit_resolve = spy
+    before = series()
+    at_most = dict(GANG_ROUNDS.bucket_counts())
+    try:
+        # 6 pods: a drain of two batches, 4 and 2; then 3 more: one batch
+        # of 3 and a padding batch
+        for wave in (pods[:6], pods[6:]):
+            for p in wave:
+                queue.add(Pod.from_dict(p))
+            while sched.run_once(wait=0.01) or sched._pending:
+                pass
+        sched.wait_for_bindings(10.0)
+    finally:
+        sched.close()
+    after = series()
+    held = [[len(c) for c in d["chunks"]] for d in drains]
+    assert held == [[4, 2], [3]], held
+    rounds = [np.asarray(d["rounds"]) for d in drains]
+    assert all(r.shape == (cfg.max_drain_batches,) for r in rounds)
+    want = [int(r[b]) for r, h in zip(rounds, held) for b in range(len(h))]
+    assert all(2 <= r <= cfg.max_gang_rounds for r in want), want
+    assert max(want) > 2  # a batch whose members contend takes more rounds
+    rose = {k: after[k] - before.get(k, 0.0) for k in after
+            if k.startswith("scheduler_gang_rounds")}
+    assert rose["scheduler_gang_rounds_count"] == len(want) == 3
+    assert rose["scheduler_gang_rounds_sum"] == sum(want)
+    # every observation lies in 1..max_gang_rounds, and a bound is inclusive
+    grew = {b: c - at_most.get(b, 0) for b, c in GANG_ROUNDS.bucket_counts()}
+    assert grew[cfg.max_gang_rounds] == 3
+    for bound in (1, 2, 3, 4):
+        assert grew[bound] == sum(r <= bound for r in want), (bound, want)
+    # the metric file that reads the series finds them in the exposition
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "yardstick", "layer_metrics",
+                           "gang_rounds_per_batch.burst.json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == "series_ratio"
+    for name in spec["args"]["num"] + spec["args"]["den"]:
+        assert name in after, name
+    assert series_ratio.read({"counters": rose}, spec["args"]) == \
+        pytest.approx(sum(want) / 3)
 
 
 # ----------------------------------------------------------- device scopes
