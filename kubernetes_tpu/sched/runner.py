@@ -26,6 +26,7 @@ from kubernetes_tpu.metrics.registry import (
     NODE_LIVENESS_SKIPS,
 )
 from kubernetes_tpu.sched.cache import SchedulerCache
+from kubernetes_tpu.sched.gcpolicy import GC_POLICY
 from kubernetes_tpu.sched.resilience import ThreadWatchdog
 from kubernetes_tpu.utils.retry import with_retries
 from kubernetes_tpu.sched.queue import (
@@ -175,6 +176,10 @@ class SchedulerRunner:
             pre_sweep=self.sweep_stale_nominations,
             post_sweep=self.publish_status,
             relists=self._total_relists)
+        # the collector's policy for this process (sched/gcpolicy.py):
+        # start() takes it, the first loop freezes, stop() gives it back
+        self._gc_held = False
+        self._gc_frozen = False
 
     def _build_queue(self, cfg: SchedulerConfiguration) -> SchedulingQueue:
         """Queue factory hook — the FleetRunner (sched/fleet.py) swaps in
@@ -553,6 +558,11 @@ class SchedulerRunner:
         import jax
         from kubernetes_tpu.utils.tracing import TRACER
         TRACER.annotate = jax.profiler.TraceAnnotation
+        if not self._gc_held:
+            # before set-up allocates: the informers' sync decodes every
+            # node, the warm ladder traces and compiles
+            self._gc_held = True
+            GC_POLICY.acquire()
         return self._start(wait_sync, start_loop)
 
     def start_loop(self):
@@ -805,6 +815,11 @@ class SchedulerRunner:
         # a leader that schedules nothing until the next transition.
         prev_t, prev_s = self._loop_thread, self._loop_stop
         stop = threading.Event()
+        if self._gc_held and not self._gc_frozen:
+            # the set-up heap is whole here on every road to a running
+            # loop; once a runner, not once a lease term
+            self._gc_frozen = True
+            GC_POLICY.freeze()
 
         def term():
             if prev_t is not None and prev_t.is_alive():
@@ -892,6 +907,11 @@ class SchedulerRunner:
         if t is not None:
             t.join(timeout=5.0)
 
+    def _release_gc(self) -> None:
+        if self._gc_held:
+            self._gc_held = self._gc_frozen = False
+            GC_POLICY.release()
+
     def stop(self):
         self._stop.set()
         self._watchdog.stop()
@@ -900,6 +920,7 @@ class SchedulerRunner:
         self.queue.close()
         self.scheduler.close()
         self.factory.stop_all()
+        self._release_gc()
 
     def kill(self):
         """Crash simulation (recovery tests): tear the runner down WITHOUT
@@ -916,3 +937,4 @@ class SchedulerRunner:
             self._loop_stop.set()
         self.queue.close()
         self.factory.stop_all()
+        self._release_gc()

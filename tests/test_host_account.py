@@ -368,6 +368,18 @@ def test_every_span_and_series_of_the_account_feeds_a_metric_file():
         m.name for m in REGISTRY._metrics.values()}
     assert collected and collected <= read, sorted(collected - read)
     assert "scheduler_queue_wait_seconds_sum" in read
+    # the collector's two series (sched/gcpolicy.py), each by its own file
+    for series_name, metric in (
+            ("scheduler_gc_pause_seconds_total",
+             "gc_pause_us_per_pod_event.burst"),
+            ("scheduler_gc_collections_total",
+             "gc_full_collections_per_kevent.burst")):
+        assert series_name in collected
+        with open(os.path.join(root, "yardstick", "layer_metrics",
+                               metric + ".json")) as f:
+            spec = json.load(f)
+        assert spec["args"]["num"][0].split("{")[0] == series_name
+        assert spec["reader"] == "series_ratio"  # absent reads None, not 0
 
 
 # ------------------------------------------------------------- gang rounds
