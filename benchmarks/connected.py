@@ -369,20 +369,16 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
                   timeout: float = 300.0, churn: bool = False,
                   churn_period_s: float = 0.1, min_churn_ops: int = 500,
                   pipeline_depth: int | None = None,
-                  chaos_seed: int | None = None,
                   fault_schedule=None,
-                  explain: bool = True,
-                  trace_tag: str | None = None,
                   seed: int = 0,
                   cfg_extra: dict | None = None,
                   log=lambda *a: None) -> dict:
     """One served-path window. ``seed`` makes the cluster and the pods;
     ``cfg_extra`` adds SchedulerConfiguration fields (mesh_shape,
     parity_sample_every, ...); ``fault_schedule`` is a ready FaultSchedule
-    to run under instead of the one ``chaos_seed`` generates. Whatever
-    the mode, the result carries the resilience block, the loop-error and
-    attempt deltas over the run (warm ladder included) and where the
-    resident context lives — a run the breaker carried on the host binds
+    to run under. Whatever the mode, the result carries the resilience
+    block, the loop-error and attempt deltas over the run (warm ladder
+    included) and where the resident context lives — a run the breaker carried on the host binds
     every pod too, and only these say so."""
     from kubernetes_tpu.client.clientset import HTTPClient
     from kubernetes_tpu.config.types import SchedulerConfiguration
@@ -390,11 +386,6 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
     from kubernetes_tpu.sched.runner import SchedulerRunner
     from kubernetes_tpu.utils.tracing import FLIGHT
     from benchmarks.workloads import mixed_heterogeneous
-
-    # explain=False is the A/B's baseline leg: explainer off AND flight
-    # recorder off (run_explain_ab gates the on-leg's throughput cost)
-    flight_was = FLIGHT.enabled
-    FLIGHT.enabled = explain
 
     ctx = mp.get_context("spawn")  # never fork a live TPU client
     parent, child = ctx.Pipe()
@@ -412,17 +403,12 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
         log(f"  seeded {n_nodes} nodes in {time.time()-t0:.1f}s")
 
         cfg_kw = dict(batch_size=batch_size,
-                      max_drain_batches=drain_batches,
-                      explainer_enabled=explain)
+                      max_drain_batches=drain_batches)
         if pipeline_depth is not None:
             # clamp like the scheduler does, so the reported depth is the
             # depth that actually ran (depth 0 would silently run as 1)
             cfg_kw["pipeline_depth"] = max(1, int(pipeline_depth))
         sched_client = HTTPClient(url)
-        if chaos_seed is not None and fault_schedule is None:
-            from kubernetes_tpu.chaos import FaultSchedule
-            fault_schedule = FaultSchedule.generate(chaos_seed,
-                                                    profile="churn")
         if fault_schedule is not None:
             # ChaosChurn: the SCHEDULER's transport is chaos-wrapped (the
             # harness's own seed/verify clients stay clean — the bench
@@ -466,8 +452,9 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
         # ride a CLEAN client (the bench owns ground truth; the chaos
         # wrapper stays on the scheduler's transport only) and any
         # confirmed violation is recorded + repro-bundled, then reported
-        # as invariant_violations in this case's JSON — bench.py exits
-        # non-zero on it (the loud-failure lesson, applied to correctness)
+        # as invariant_violations in this case's JSON — chip_smoke.py
+        # exits non-zero on it (the loud-failure lesson, applied to
+        # correctness)
         runner.auditor = _bench_auditor(runner, HTTPClient(url))
         # informers first (nodes sync into the scheduler cache); the loop
         # starts after pod creation so the first pop drains a deep backlog
@@ -612,28 +599,9 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
         e2e_block = {"count": E2E_SCHEDULING.count(),
                      "p50_s": E2E_SCHEDULING.percentile(0.50),
                      "p99_s": E2E_SCHEDULING.percentile(0.99)}
-        # Perfetto-loadable dump of the measured window (batch spans +
-        # per-pod flight tracks): BENCH_TRACE_PATH (bench.py defaults it
-        # next to the result JSON; empty string disables). The path is
-        # suffixed per CASE — several cases run run_connected in one bench
-        # process, and the last one must not silently overwrite the
-        # headline window's trace.
-        import os as _os
         case_name = ("ChaosChurn" if schedule is not None
                      else "ConnectedChurn" if churn
                      else "ConnectedScheduler")
-        trace_file = _os.environ.get("BENCH_TRACE_PATH") or None
-        if trace_file:
-            from kubernetes_tpu.utils.tracing import TRACER
-            tag = trace_tag or case_name
-            root, dot, ext = trace_file.rpartition(".")
-            trace_file = (f"{root}.{tag}.{ext}" if dot
-                          else f"{trace_file}.{tag}")
-            try:
-                TRACER.export_chrome(trace_file)
-                log(f"  perfetto trace -> {trace_file}")
-            except Exception:
-                trace_file = None
         if churn_stop is not None:
             # fixed churn-op budget DECOUPLED from drain duration: a fast
             # drain must not mean the churn path went unexercised (r05: the
@@ -710,12 +678,9 @@ def run_connected(n_pods: int = 2000, n_nodes: int = 1000,
         out["explain"] = explain_block
         out["flight"] = flight_block
         out["e2e"] = e2e_block
-        out["trace_file"] = trace_file
         out.update(audit_block)
         return out
     finally:
-        from kubernetes_tpu.utils.tracing import FLIGHT as _FL
-        _FL.enabled = flight_was
         if schedule is not None:  # crash path: never leak installed chaos
             from kubernetes_tpu.chaos import hooks as _hooks
             _hooks.uninstall()
@@ -788,101 +753,13 @@ def run_warm_ladder(n_pods: int = 2000, n_nodes: int = 1000,
             server.terminate()
 
 
-def run_chaos_churn(n_pods: int = 2000, n_nodes: int = 1000,
-                    batch_size: int = 512, drain_batches: int = 2,
-                    timeout: float = 300.0, seed: int | None = None,
-                    log=lambda *a: None) -> dict:
-    """ChaosChurn: the standard churn workload under the default fault
-    schedule — API error/conflict/latency storms on the scheduler's
-    transport, truncated watch streams + forced relists, a device-failure
-    burst that trips the circuit breaker (and must half-open back), and
-    thread stalls. The gate is absolute: 100% of pods must still bind;
-    ``chaos.lost`` > 0 fails the bench run (bench.py exits non-zero).
-    Recovery spans per fault class land in the result JSON."""
-    from kubernetes_tpu.chaos import seed_from_env
-    if seed is None:
-        seed = seed_from_env(0)
-    return run_connected(n_pods=n_pods, n_nodes=n_nodes,
-                         batch_size=batch_size,
-                         drain_batches=drain_batches, timeout=timeout,
-                         churn=True, chaos_seed=seed, log=log)
-
-
-def run_explain_ab(n_pods: int = 2000, n_nodes: int = 1000,
-                   batch_size: int = 512, drain_batches: int = 2,
-                   timeout: float = 300.0, min_ratio: float = 0.95,
-                   log=lambda *a: None) -> dict:
-    """ExplainAB: the ConnectedChurn workload with the decision-provenance
-    explainer + flight recorder ON vs OFF. The observability layer's whole
-    contract is "off the hot path": the on-leg must sustain at least
-    ``min_ratio`` of the off-leg's throughput (default 95% — the <=5% cost
-    budget), gated HARD like PR 8's sloGates (a missing number fails)."""
-    import os
-    legs = {}
-    # a leaked KTPU_EXPLAIN would override BOTH legs' explainer_enabled
-    # config (scheduler construction reads it last), silently turning the
-    # A/B into on-vs-on or off-vs-off — the gate would then price nothing
-    env_explain = os.environ.pop("KTPU_EXPLAIN", None)
-    try:
-        for name, on in (("off", False), ("on", True)):
-            log(f"  explain A/B leg: {name} ...")
-            legs[name] = run_connected(
-                n_pods=n_pods, n_nodes=n_nodes, batch_size=batch_size,
-                drain_batches=drain_batches, timeout=timeout, churn=True,
-                explain=on, trace_tag=f"ExplainAB.{name}", log=log)
-    finally:
-        if env_explain is not None:
-            os.environ["KTPU_EXPLAIN"] = env_explain
-    on_t = legs["on"].get("SchedulingThroughput")
-    off_t = legs["off"].get("SchedulingThroughput")
-    ratio = (round(on_t / off_t, 3)
-             if isinstance(on_t, (int, float))
-             and isinstance(off_t, (int, float)) and off_t else None)
-    failures = []
-    if ratio is None:
-        failures.append(
-            f"throughput ratio unavailable (on={on_t!r}, off={off_t!r}) — "
-            "the <=5% overhead gate cannot pass silently")
-    elif ratio < min_ratio:
-        failures.append(
-            f"explainer+flight overhead too high: on/off throughput "
-            f"ratio {ratio} below the {min_ratio} floor")
-    # the A/B must actually have measured on-vs-off: the on leg carries
-    # the layer it is pricing, the off leg provably does not
-    ex = (legs["on"].get("explain") or {})
-    if legs["on"].get("explain") is None:
-        failures.append("on-leg ran without the explainer constructed")
-    if legs["off"].get("explain") is not None:
-        failures.append("off-leg ran WITH the explainer (A/B invalid)")
-    if not (legs["on"].get("flight") or {}).get("enabled"):
-        failures.append("on-leg ran with the flight recorder disabled")
-    out = {
-        "case": "ExplainAB",
-        "workload": f"{n_pods}x{n_nodes}churn",
-        "throughput_on": on_t, "throughput_off": off_t,
-        "throughput_ratio": ratio, "min_ratio": min_ratio,
-        "explain_on": ex,
-        "unschedulable_reasons": legs["on"].get("unschedulable_reasons"),
-        "e2e_on": legs["on"].get("e2e"),
-        "slo_failures": failures,
-        "invariant_violations": sum(
-            int(leg.get("invariant_violations") or 0)
-            for leg in legs.values()),
-        "legs": {name: {k: leg.get(k) for k in
-                        ("SchedulingThroughput", "bound", "measure_s",
-                         "p99_attempt_latency_s")}
-                 for name, leg in legs.items()},
-    }
-    return out
-
-
 def drain_parity_check(mesh_shape: tuple[int, int], n_nodes: int = 1024,
                        P: int = 128, B: int = 2, seed: int = 0) -> dict:
     """Deterministic mesh acceptance gate: the FULL fused drain over the
     bench workload, sharded vs unsharded, must produce bit-identical
     placements and fold arithmetic (same check as __graft_entry__'s
-    multichip dry-run, at the live path's shapes). bench.py exits non-zero
-    when this reports ok=False."""
+    multichip dry-run, at the live path's shapes). chip_smoke.py exits
+    non-zero when this reports ok=False."""
     import jax
     import numpy as np
     from benchmarks.workloads import mixed_heterogeneous
@@ -920,278 +797,6 @@ def drain_parity_check(mesh_shape: tuple[int, int], n_nodes: int = 1024,
             "mismatches": mism, "placed": int(fill_u),
             "pods": n_pods, "nodes": n_nodes,
             "mesh": f"{mesh_shape[0]}x{mesh_shape[1]}"}
-
-
-def _run_mesh_leg(mesh_shape, n_pods: int, n_nodes: int, batch_size: int,
-                  drain_batches: int, timeout: float, log) -> dict:
-    """One live leg of the ConnectedMesh case: separate-process apiserver,
-    a HOLLOW-KUBELET node fleet (kubemark nodes registering + syncing pods
-    over HTTP), and the connected scheduler — mesh on or off per
-    ``mesh_shape``. Measured window matches run_connected: pod creation to
-    last binding visible."""
-    from kubernetes_tpu.client.clientset import HTTPClient
-    from kubernetes_tpu.config.types import SchedulerConfiguration
-    from kubernetes_tpu.kubelet.kubemark import HollowCluster
-    from kubernetes_tpu.sched.runner import SchedulerRunner
-    from kubernetes_tpu.testing.wrappers import make_pod
-
-    ctx = mp.get_context("spawn")
-    parent, child = ctx.Pipe()
-    server = ctx.Process(target=_serve, args=(child,), daemon=True)
-    server.start()
-    port = parent.recv()
-    url = f"http://127.0.0.1:{port}"
-    cluster = None
-    runner = None
-    try:
-        seed_client = HTTPClient(url, timeout=120.0)
-        t0 = time.time()
-        cluster = HollowCluster(HTTPClient(url, timeout=60.0), n_nodes,
-                                heartbeat_period=30.0).start()
-        log(f"  {n_nodes} hollow kubelets up in {time.time()-t0:.1f}s")
-        pods = [make_pod(f"mp{i:05d}", "default")
-                .req({"cpu": "500m", "memory": "256Mi"}).obj()
-                for i in range(n_pods)]
-        runner = SchedulerRunner(
-            HTTPClient(url),
-            SchedulerConfiguration(batch_size=batch_size,
-                                   max_drain_batches=drain_batches,
-                                   mesh_shape=mesh_shape))
-        # churn legs run under fail-fast audit too: a sharded program that
-        # silently corrupts placements must fail THIS leg, not surface as
-        # a throughput anomaly three rounds later
-        runner.auditor = _bench_auditor(runner, HTTPClient(url))
-        runner.start(wait_sync=30.0, start_loop=False)
-        _warm_jit(runner, pods, batch_size, n_pods, log)
-        mesh = runner.scheduler._mesh
-
-        _, rv0 = seed_client.pods("default").list_rv()
-        count = ctx.Value("i", 0)
-        all_bound, watch_dead, ready = ctx.Event(), ctx.Event(), ctx.Event()
-        watcher = ctx.Process(target=_watch_bound,
-                              args=(url, "default", rv0, n_pods,
-                                    count, all_bound, watch_dead, ready),
-                              daemon=True)
-        watcher.start()
-        ready.wait(30.0)
-
-        _trace_window()
-        from kubernetes_tpu.metrics.registry import ATTEMPT_DURATION
-        ATTEMPT_DURATION.reset()
-        t_start = time.time()
-        objs = [p.to_dict() for p in pods]
-        CHUNK = 2500
-        for i in range(0, len(objs), CHUNK):
-            seed_client.pods("default").create_many(objs[i:i + CHUNK])
-        runner.start_loop()
-        deadline = t_start + timeout
-        completed = False
-        while time.time() < deadline:
-            if all_bound.wait(timeout=0.05):
-                completed = True
-                break
-            if watch_dead.is_set():
-                n = sum(1 for p in seed_client.pods("default").list()
-                        if p["spec"].get("nodeName"))
-                count.value = n
-                if n >= n_pods:
-                    completed = True
-                    break
-                time.sleep(0.2)
-        dt = time.time() - t_start
-        bound = count.value
-        if not completed:
-            bound = sum(1 for p in seed_client.pods("default").list()
-                        if p["spec"].get("nodeName"))
-        p99 = ATTEMPT_DURATION.percentile(0.99, {"result": "scheduled"})
-        span_ms = _span_totals()
-        encode_cache = runner.cache.encode_cache_stats()
-        staging = runner.cache.staging_stats()
-        from kubernetes_tpu.metrics.registry import RESOLVE_BYTES
-        audit_block = _audit_close(runner)
-        log(f"  mesh={mesh_shape}: {bound}/{n_pods} bound at +{dt:.1f}s")
-        return {
-            "mesh": (f"{mesh_shape[0]}x{mesh_shape[1]}"
-                     if mesh_shape else "off"),
-            "mesh_active": mesh is not None,
-            "SchedulingThroughput": round(bound / dt, 1) if dt > 0 else 0.0,
-            "bound": bound, "pods": n_pods, "hollow_nodes": n_nodes,
-            "measure_s": round(dt, 2),
-            "p99_attempt_latency_s": p99,
-            "span_ms": span_ms,
-            # zero-copy attribution (the r06 lesson: a transfer hiding in
-            # a dispatch span cost two rounds): staging spans broken out,
-            # the h2d swap/fallback split, and the winners-fetch bytes
-            "stage_batch_ms": span_ms.get("scheduler/stage_batch", 0.0),
-            "stage_swap_ms": span_ms.get("scheduler/stage_swap", 0.0),
-            "staging": staging,
-            "resolve_bytes": RESOLVE_BYTES.get(),
-            "encode_cache": encode_cache,
-            **audit_block,
-        }
-    finally:
-        try:
-            if runner is not None:
-                runner.stop()
-        except Exception:
-            pass
-        try:
-            if cluster is not None:
-                cluster.stop()
-        except Exception:
-            pass
-        try:
-            parent.send("stop")
-        except Exception:
-            pass
-        server.join(timeout=5.0)
-        if server.is_alive():
-            server.terminate()
-
-
-def run_connected_mesh(mesh_shapes=((1, 2),),
-                       n_pods: int = 1024, n_nodes: int = 96,
-                       batch_size: int = 128, drain_batches: int = 2,
-                       timeout: float = 300.0, slo_gates: dict | None = None,
-                       min_ratio: float = 1.0,
-                       log=lambda *a: None, mesh_shape=None) -> dict:
-    """ConnectedMesh case: a WIDTH SWEEP. One unsharded live leg (the
-    baseline), then per mesh width: the deterministic sharded-vs-unsharded
-    drain parity gate and a sharded live leg, with per-leg
-    stage_batch/stage_swap spans, resolve_bytes, and staging-arena health.
-
-    HARD gate per width: sharded throughput >= ``min_ratio`` x unsharded
-    (SLO-style — a MISSING ratio fails exactly like a regressed one; the
-    zero-copy steady state exists to make the sharded leg strictly
-    dominate). On VIRTUAL CPU devices a width whose parity check or leg
-    crashes is environmental (forced-multi-device CPU GSPMD has
-    miscompiled some widths): recorded, excluded from the ratio gate and
-    from the parity verdict. On real chips there is no such excuse: the
-    crash propagates and the sweep fails.
-
-    Needs a backend with >= max(pods*nodes) devices: the four-chip host,
-    or a forced multi-device CPU host platform (where only the parity
-    verdict means anything — every rate there is a CPU number).
-    ``mesh_shape`` (single tuple) is accepted for back-compat callers."""
-    import jax
-    virtual = jax.devices()[0].platform == "cpu"
-    if mesh_shape is not None:
-        mesh_shapes = (mesh_shape,)
-    mesh_shapes = [tuple(s) for s in mesh_shapes]
-    out = {"case": "ConnectedMesh",
-           "workload": f"{n_pods}x{n_nodes}hollow",
-           "widths": {}}
-    if slo_gates is None:
-        slo_gates = {"SchedulingThroughput": 60,
-                     "p99AttemptLatencySeconds": 10}
-    out["slo_gates"] = dict(slo_gates, shardedVsUnshardedRatio=min_ratio)
-    runnable = [s for s in mesh_shapes
-                if s[0] * s[1] <= jax.device_count()]
-    for s in mesh_shapes:
-        if s not in runnable:
-            out["widths"][f"{s[0]}x{s[1]}"] = {
-                "skipped": True,
-                "reason": (f"needs {s[0] * s[1]} devices, have "
-                           f"{jax.device_count()}")}
-    if not runnable:
-        out.update(skipped=True, invariant_violations=0,
-                   reason="no runnable mesh width on this backend")
-        return out
-
-    slo_failures: list[str] = []
-    log("  live leg: unsharded baseline ...")
-    try:
-        unsharded = _run_mesh_leg(None, n_pods, n_nodes, batch_size,
-                                  drain_batches, timeout, log)
-    except Exception as e:
-        unsharded = {"error": f"{type(e).__name__}: {e}"[:300],
-                     "mesh": "off"}
-        log(f"  unsharded leg crashed: {type(e).__name__}")
-    out["unsharded"] = unsharded
-    un_tput = unsharded.get("SchedulingThroughput")
-    if "error" in unsharded:
-        # the baseline is SINGLE-DEVICE — no GSPMD environmental excuse
-        # applies, and without it every width's ratio gate is blind:
-        # that is a bench failure, not a skip (missing number = failure)
-        slo_failures.append(
-            "unsharded baseline leg crashed "
-            f"({unsharded['error']}); ratio gates cannot run")
-    else:
-        slo_failures += [f"unsharded: {m}"
-                         for m in check_slo_gates(unsharded, slo_gates)]
-
-    parity_verdicts: dict[str, bool] = {}
-    for shape in runnable:
-        name = f"{shape[0]}x{shape[1]}"
-        w: dict = {}
-        out["widths"][name] = w
-        log(f"  parity gate (drain sharded {name} vs unsharded) ...")
-        try:
-            w["parity"] = drain_parity_check(shape, P=batch_size,
-                                             B=drain_batches)
-            parity_verdicts[name] = bool(w["parity"]["ok"])
-            log("  parity: " + str(w["parity"]))
-        except Exception as e:
-            if not virtual:
-                raise
-            # the sharded program CRASHED at this width — the PR-5
-            # environmental-miscompile contract: record, skip the leg,
-            # no parity verdict (only a real divergence may fail)
-            w["parity"] = {"ok": None,
-                           "error": f"{type(e).__name__}: {e}"[:300]}
-            log(f"  parity check crashed at {name}: {type(e).__name__}")
-            continue
-        if not w["parity"]["ok"]:
-            continue  # live leg would measure a miscompiling backend
-        log(f"  live leg: sharded {name} ...")
-        try:
-            leg = _run_mesh_leg(shape, n_pods, n_nodes, batch_size,
-                                drain_batches, timeout, log)
-        except Exception as e:
-            if not virtual:
-                raise
-            w["sharded"] = {"error": f"{type(e).__name__}: {e}"[:300],
-                            "mesh": name}
-            log(f"  sharded leg {name} crashed: {type(e).__name__}")
-            continue
-        w["sharded"] = leg
-        sh_tput = leg.get("SchedulingThroughput")
-        ratio = (round(sh_tput / un_tput, 3)
-                 if un_tput and sh_tput else None)
-        w["throughput_ratio"] = ratio
-        w["all_bound"] = (unsharded.get("bound") == n_pods
-                          and leg.get("bound") == n_pods)
-        slo_failures += [f"sharded {name}: {m}"
-                         for m in check_slo_gates(leg, slo_gates)]
-        # the zero-copy gate: sharded must dominate at EVERY width that
-        # ran; a missing ratio (either leg lost its number) fails too
-        if "error" not in unsharded and (ratio is None
-                                         or ratio < min_ratio):
-            slo_failures.append(
-                f"{name}: sharded/unsharded throughput ratio "
-                f"{ratio} < {min_ratio} (missing = failure)")
-
-    # aggregate parity verdict over widths that produced one (bench.py
-    # exits non-zero on ok=False: divergence is never perf variance)
-    out["parity"] = {"ok": (all(parity_verdicts.values())
-                            if parity_verdicts else None),
-                    "widths": parity_verdicts}
-    # back-compat convenience: first width's figures at the top level
-    first = next((out["widths"][f"{s[0]}x{s[1]}"] for s in runnable
-                  if "sharded" in out["widths"][f"{s[0]}x{s[1]}"]), None)
-    if first is not None:
-        out["sharded"] = first["sharded"]
-        out["throughput_ratio"] = first.get("throughput_ratio")
-        out["all_bound"] = first.get("all_bound")
-    out["slo_failures"] = slo_failures
-    # summary-level audit figure: a MULTICHIP JSON without it is refused
-    # by bench.py (the loud-failure lesson — a missing field must never
-    # read as "zero violations")
-    out["invariant_violations"] = (
-        int(unsharded.get("invariant_violations") or 0)
-        + sum(int((w.get("sharded") or {}).get("invariant_violations")
-                  or 0) for w in out["widths"].values()))
-    return out
 
 
 def run_connected_preemption(n_nodes: int = 5000, n_high: int = 128,
@@ -1370,50 +975,6 @@ if __name__ == "__main__":
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     from kubernetes_tpu.parallel.aot import place_compile_cache
     place_compile_cache()
-    if len(sys.argv) > 1 and sys.argv[1] == "mesh":
-        # ConnectedMesh entry, for a backend with devices to span: the
-        # four-chip host, or JAX_PLATFORMS=cpu with
-        # --xla_force_host_platform_device_count (parity verdict only).
-        # Each leg pins its own mesh via cfg.mesh_shape; a leaked KTPU_MESH
-        # would override BOTH legs and corrupt the A/B
-        os.environ.pop("KTPU_MESH", None)
-        from kubernetes_tpu.parallel.mesh import parse_mesh_shape
-        shapes_env = os.environ.get(
-            "BENCH_MESH_SHAPES",
-            os.environ.get("BENCH_MESH_SHAPE", "1x2"))
-        # "off"/"none" tokens DISABLE (parse -> None, filtered) — same
-        # no-silent-default rule as bench.py's parent-side parse
-        shapes = [s for s in (parse_mesh_shape(tok) for tok in
-                              shapes_env.replace(";", " ").split())
-                  if s is not None]
-        if not shapes:
-            print(json.dumps({"case": "ConnectedMesh", "skipped": True,
-                              "reason": f"no mesh widths in "
-                                        f"{shapes_env!r}"}))
-            sys.exit(0)
-        res = run_connected_mesh(
-            mesh_shapes=shapes,
-            n_pods=int(os.environ.get("BENCH_MESH_PODS", "1024")),
-            n_nodes=int(os.environ.get("BENCH_MESH_NODES", "96")),
-            batch_size=int(os.environ.get("BENCH_MESH_BATCH", "128")),
-            slo_gates={
-                "SchedulingThroughput":
-                    float(os.environ.get("BENCH_MESH_SLO_TPUT", "60")),
-                "p99AttemptLatencySeconds":
-                    float(os.environ.get("BENCH_MESH_SLO_P99", "10")),
-            },
-            # sharded >= unsharded is the GOAL gate (ROADMAP S7; export
-            # BENCH_MESH_MIN_RATIO=1.0 on real multi-chip hardware). On
-            # virtual CPU devices the sharded program does strictly more
-            # work on the same cores, so the default only guards a gross
-            # staging regression (one measured ~0.5).
-            min_ratio=float(os.environ.get("BENCH_MESH_MIN_RATIO", "0.7")),
-            log=lambda *a: print(*a, file=sys.stderr))
-        print(json.dumps(res))
-        # exit gate: a divergence verdict (ok=False) fails. On virtual CPU
-        # devices a sweep whose every width crashed carries ok=None and
-        # passes; on real chips that crash already raised above
-        sys.exit(1 if res.get("parity", {}).get("ok") is False else 0)
     _pipe = os.environ.get("BENCH_CONNECTED_PIPELINE")
     res = run_connected(
         n_pods=int(os.environ.get("BENCH_CONNECTED_PODS", "2000")),

@@ -312,7 +312,7 @@ def _run_churn_workload(case: dict, workload: dict, params: dict,
                       if k == "SchedulingThroughput"))
     # HARD SLO gates (distinct from the advisory thresholds above): a
     # missing or regressed p99/throughput figure must fail the bench run,
-    # not read as fine — bench.py exits non-zero on slo_failures.
+    # not read as fine — the result carries them as slo_failures.
     # Throughput floors scale with the workload like the advisory
     # thresholds do; latency ceilings stay absolute (a scaled-down run is
     # only ever faster).

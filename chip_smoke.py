@@ -63,7 +63,7 @@ OUT_DIR = os.path.join(HERE, "chiprun_out", "chip_smoke")
 FULL = {"nodes": 5000, "pods": 10000, "batch": 512, "preemptors": 128}
 REDUCED: list = []
 TINY = {"nodes": 48, "pods": 192, "batch": 32, "preemptors": 8}  # rehearsal
-DRAIN_BATCHES = 2  # as bench.py drives the connected run
+DRAIN_BATCHES = 2  # maxDrainBatches of both listed cells (yardstick/configs)
 
 EXIT_FAILED = 1
 EXIT_NO_PROGRAM = 4     # chip_smoke.py alone, without the repo

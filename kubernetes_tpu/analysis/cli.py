@@ -2,7 +2,7 @@
 
 Exit codes: 0 = no NEW findings (baseline-covered ones are reported as
 context, not failures), 1 = new findings, 2 = usage error. ``--json``
-prints a machine-readable summary (the bench.py convention) as the last
+prints a machine-readable summary (one JSON object) as the last
 line so CI wrappers can parse without scraping human output.
 """
 

@@ -857,15 +857,12 @@ def cmd_status(client: HTTPClient, args, out) -> int:
               + "\n")
     ctx = st.get("ctx")
     if ctx is not None:
-        fused = st.get("fusedFold")
         reasons = ", ".join(f"{k}={v}" for k, v in
                             sorted((ctx.get("reasons") or {}).items()))
         out.write(f"Resident ctx:  folds {ctx.get('folds', 0)}, "
                   f"patches {ctx.get('patches', 0)}, "
                   f"rebuilds {ctx.get('rebuilds', 0)}"
                   + (f" ({reasons})" if reasons else "")
-                  + (f" — fused fold {'on' if fused else 'off'}"
-                     if fused is not None else "")
                   + "\n")
     staging = st.get("staging")
     if staging is not None:

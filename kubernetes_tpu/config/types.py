@@ -115,7 +115,7 @@ class SchedulerConfiguration:
     batch_size: int = 256          # pods per gang step (pop_batch max)
     # Deep-backlog drain: when one pop yields more than batch_size pods the
     # loop fuses up to this many batches into ONE device program (lax.scan,
-    # models/gang.py gang_drain) — one dispatch + one readback for the whole
+    # models/gang.py drain_step) — one dispatch + one readback for the whole
     # backlog instead of a ~100ms round trip per batch on remote TPUs.
     max_drain_batches: int = 8
     # Dispatch-pipeline depth: how many fused drains may be in flight on the
@@ -125,19 +125,6 @@ class SchedulerConfiguration:
     # host-side apply/bind work behind device execution. jax dispatch is
     # asynchronous, so deeper pipelines cost HBM for queued programs only.
     pipeline_depth: int = 2
-    # Fused fold: churn patches ride the drain dispatch as a third input of
-    # the resident device program (models/gang.py drain_step) instead of a
-    # separate blocking apply_ctx_patch dispatch — and fold-SAFE churn
-    # (encode/patch.py entries_fold_safe) no longer drains the dispatch
-    # pipeline first. False restores the PR3-era patch-then-dispatch path
-    # (the parity tests diff the two). KTPU_FUSED_FOLD=0 overrides.
-    fused_fold: bool = True
-    # Pre-sharded double-buffered batch staging (sched/staging.py): batch
-    # K+1's pod stack uploads to pre-sharded device buffers on a background
-    # thread while batch K runs; dispatch swaps buffers instead of paying a
-    # device_put. False restores the inline staging path (the A/B the
-    # staging parity tests diff). KTPU_STAGE_ARENA=0 overrides.
-    staging_arena: bool = True
     # Device-mesh shape (pods_axis, nodes_axis) for the live scheduling
     # path: cluster tensors shard over "nodes", pod batches over "pods",
     # and the drain/preemption programs run under GSPMD with ICI
@@ -225,8 +212,6 @@ class SchedulerConfiguration:
             ("batchSize", "batch_size"), ("maxGangRounds", "max_gang_rounds"),
             ("maxDrainBatches", "max_drain_batches"),
             ("pipelineDepth", "pipeline_depth"),
-            ("fusedFold", "fused_fold"),
-            ("stagingArena", "staging_arena"),
             ("seed", "seed"), ("backoffInitialSeconds", "backoff_initial_s"),
             ("backoffMaxSeconds", "backoff_max_s"), ("assumeTTLSeconds", "assume_ttl_s"),
             ("clientQPS", "client_qps"), ("parallelism", "parallelism"),

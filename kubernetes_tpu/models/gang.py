@@ -739,7 +739,7 @@ def _apply_patch(ct_all: ClusterTensors, patch: dict) -> ClusterTensors:
     """Traceable body of the churn-patch scatter: pod slot rewrites/clears,
     node row rewrites/retires, nominee reservation diffs, and the dense
     requested[N,R] delta. Shared by the standalone ``apply_ctx_patch``
-    dispatch (rebuild-time nominee staging, fusedFold=off) and the fused
+    dispatch (rebuild-time nominee staging) and the fused
     drain (``drain_step``'s third input), so the two paths can never drift.
 
     Reference shape: the incremental half of ``Cache.UpdateSnapshot``
@@ -818,8 +818,8 @@ def _apply_patch(ct_all: ClusterTensors, patch: dict) -> ClusterTensors:
 @partial(jax.jit, donate_argnums=(0,), static_argnames=("mesh",))
 def apply_ctx_patch(ct_all: ClusterTensors, patch: dict, mesh=None
                     ) -> ClusterTensors:
-    """Standalone churn-patch dispatch (rebuild-time nominee staging,
-    fusedFold=off). ``mesh``: same output-sharding pin as ``drain_step`` —
+    """Standalone churn-patch dispatch (rebuild-time nominee staging).
+    ``mesh``: same output-sharding pin as ``drain_step`` —
     the patched encoding must leave this program carrying exactly the
     shardings the next drain dispatch expects, so donation aliases in
     place instead of resharding the resident arrays."""

@@ -84,7 +84,7 @@ def _count_pn(ct: ClusterTensors, sel, pod_ns, ns_explicit=None, ns_mask=None):
     SLOWER than this path on v5e (16k epods x 1k pods x 4 terms x 5k nodes:
     14.7s vs 122ms/eval — tiny per-grid-step dots starved the MXU, and
     MXU-sized tiles spilled ~74MiB of Mosaic VMEM stack) and was deleted in
-    round 4; benchmarks/pallas_bench.py records the comparison."""
+    round 4 (the figures above are that comparison's record)."""
     N = ct.node_valid.shape[0]
     match_ept = _term_match_epods(ct, sel, pod_ns, ns_explicit, ns_mask)
     onehot = (ct.epod_node[:, None] == jnp.arange(N)[None, :]).astype(jnp.float32)

@@ -24,8 +24,8 @@ process entry points and the benchmarks build on:
 3. ``place_compile_cache`` — where jax's persistent compilation cache
    goes. ``JAX_COMPILATION_CACHE_DIR`` (placed from OUTSIDE the program)
    always wins and is never re-pointed or cleaned by this code; without
-   it, process entry points (chip_smoke.py, bench.py, the benchmarks'
-   mains, ``ktpu-up``, the chaos scheduler child) use ONE fixed,
+   it, process entry points (chip_smoke.py, the benchmarks' mains,
+   ``ktpu-up``, the chaos scheduler child) use ONE fixed,
    git-ignored directory inside the checkout — the path is part of the
    cache key, so a directory that moves never hits. Library construction
    of a ``SchedulerRunner`` places nothing (tier-1 stays cache-free).

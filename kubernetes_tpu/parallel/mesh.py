@@ -143,56 +143,6 @@ def shard_cluster(mesh: Mesh, ct: ClusterTensors) -> ClusterTensors:
     return jax.device_put(ct, cluster_shardings(mesh, ct))
 
 
-def presplit_stack(mesh: Mesh, pb_stack: PodBatch) -> PodBatch:
-    """Pre-partitioned device staging of a STACKED drain batch [B,P,...]:
-    every leaf is sliced host-side to match stack_shardings, ALL shards of
-    ALL leaves ship in one batched ``device_put`` (single runtime call —
-    a PodBatch has ~100 leaves and a per-shard put would pay ~100us of
-    Python dispatch each), and the global arrays assemble from the
-    single-device shards — zero re-layout work in the runtime (the
-    SNIPPETS [1]/[3] prescription: "ensuring that the inputs are already
-    correctly pre-partitioned can increase performance"). Bit- and
-    sharding-identical to ``device_put(pb_stack, stack_shardings(...))``
-    — the staging arena's parity test pins that."""
-    shardings = stack_shardings(mesh, pb_stack)
-    leaves, treedef = jax.tree_util.tree_flatten(pb_stack)
-    shard_leaves = jax.tree_util.tree_leaves(shardings)
-    pieces: list = []     # host arrays/slices, flat across leaves
-    targets: list = []    # matching Device (split shard) or Sharding
-    plans = []            # per leaf: (shape, sharding, n) | None (whole)
-    for leaf, sh in zip(leaves, shard_leaves):
-        x = np.asarray(leaf)
-        idx_map = sh.addressable_devices_indices_map(x.shape)
-        distinct = {tuple((s.start, s.stop) for s in idx)
-                    for idx in idx_map.values()}
-        if len(distinct) > 1:
-            # genuinely partitioned (a >1 "pods" axis): ship each shard
-            # straight to its device, assemble without runtime re-layout
-            for d, idx in idx_map.items():
-                pieces.append(np.ascontiguousarray(x[idx]))
-                targets.append(d)
-            plans.append((x.shape, sh, len(idx_map)))
-        else:
-            # replicated (incl. the trivial 1-wide pods axis): slicing
-            # would only copy the whole array per device host-side —
-            # let the batched put replicate it
-            pieces.append(x)
-            targets.append(sh)
-            plans.append(None)
-    staged = jax.device_put(pieces, targets)
-    out, pos = [], 0
-    for plan in plans:
-        if plan is None:
-            out.append(staged[pos])
-            pos += 1
-        else:
-            shape, sh, n = plan
-            out.append(jax.make_array_from_single_device_arrays(
-                shape, sh, staged[pos:pos + n]))
-            pos += n
-    return jax.tree_util.tree_unflatten(treedef, out)
-
-
 def shard_batch(mesh: Mesh, pb: PodBatch) -> PodBatch:
     return jax.device_put(pb, batch_shardings(mesh, pb))
 

@@ -296,7 +296,7 @@ def autoscaler_thrash(params: dict | None = None, seed: int = 0) -> Trace:
 
 def smoke(params: dict | None = None, seed: int = 0) -> Trace:
     """The committed golden fixture: a small diurnal-burst trace sized
-    for tests and ``BENCH_SCENARIO=builtin:smoke``."""
+    for tests and ``ktpu scenario replay builtin:smoke``."""
     p = {"pods": 24, "nodes": 8, "cycles": 2, "period_s": 2.0,
          "bursts": 1, "burst_pods": 8, **(params or {})}
     t = diurnal_burst(p, seed=seed)
@@ -316,8 +316,8 @@ BUILTINS = {
 
 def builtin_trace(name: str, seed: int = 0,
                   params: dict | None = None) -> Trace:
-    """Resolve a builtin by name — the ``builtin:<name>`` half of
-    ``BENCH_SCENARIO`` and the ``ktpu scenario generate`` catalog."""
+    """Resolve a builtin by name — the ``builtin:<name>`` a trace path
+    may be, and the ``ktpu scenario generate`` catalog."""
     fn = BUILTINS.get(name)
     if fn is None:
         raise KeyError(f"unknown builtin scenario {name!r} "

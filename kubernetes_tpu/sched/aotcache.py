@@ -456,6 +456,5 @@ def cache_knobs(cfg) -> dict:
     wholesale. jax's own entry keys already cover the HLO and compile
     options, so this list is the coarse outer guard, not the dedup key."""
     return {"meshShape": list(cfg.mesh_shape) if cfg.mesh_shape else None,
-            "fusedFold": bool(cfg.fused_fold),
             "batchSize": int(cfg.batch_size),
             "maxDrainBatches": int(cfg.max_drain_batches)}

@@ -79,14 +79,11 @@ class SchedulerCache:
         # under GSPMD instead of on one chip.
         self._mesh = None
         # pre-sharded double-buffered batch staging (sched/staging.py):
-        # batch K+1 uploads on the background stager thread while batch K
-        # runs; dispatch redeems a buffer swap. KTPU_STAGE_ARENA=0 (or
-        # SchedulerConfiguration.staging_arena via configure_staging)
-        # restores the legacy inline device_put path everywhere.
+        # under a mesh, batch K+1 uploads on the background stager thread
+        # while batch K runs and dispatch redeems a buffer swap; on one
+        # device (no mesh) stage_drain_batch puts the batch inline.
         from kubernetes_tpu.sched.staging import StagingArena
         self._arena = StagingArena()
-        self._staging_enabled = os.environ.get(
-            "KTPU_STAGE_ARENA", "1") != "0"
 
     # ---- device mesh -----------------------------------------------------
 
@@ -99,22 +96,14 @@ class SchedulerCache:
     def mesh(self):
         return self._mesh
 
-    def configure_staging(self, enabled: bool) -> None:
-        """Config-level arena switch (the KTPU_STAGE_ARENA env read at
-        construction still overrides OFF for bench A/Bs)."""
-        import os as _os
-        if _os.environ.get("KTPU_STAGE_ARENA") == "0":
-            enabled = False
-        self._staging_enabled = bool(enabled)
-
     def stage_submit(self, pb_stack):
         """Hand the final stacked drain batch to the staging arena: the
         background thread uploads it PRE-SHARDED while the scheduling
         thread finishes the cycle's host work (patch compile, sentinel
         capture) and the previous drain still executes. Returns a ticket
-        for stage_redeem, or None (arena off / single-device / buffer
-        full) — the dispatch then stages inline as before."""
-        if not self._staging_enabled or self._mesh is None:
+        for stage_redeem, or None (single-device / buffer full) — the
+        dispatch then stages inline."""
+        if self._mesh is None:
             return None
         return self._arena.submit(pb_stack, self._mesh)
 
@@ -166,7 +155,7 @@ class SchedulerCache:
 
     def staging_stats(self) -> dict:
         """Arena health for ktpu status / bench legs."""
-        return dict(self._arena.stats(), enabled=self._staging_enabled)
+        return dict(self._arena.stats(), enabled=self._mesh is not None)
 
     def request_vector(self, pod: Pod, resources: list) -> "np.ndarray":
         """One pod's scaled request vector on ``resources`` (the resident

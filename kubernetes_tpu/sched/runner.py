@@ -81,17 +81,6 @@ class SchedulerRunner:
         self.trace_name = trace_name
         if hasattr(client, "default_user_agent"):
             client.default_user_agent("kube-scheduler")
-        # GIL tuning for the connected deployment shape: informer bursts
-        # (thousands of JSON decodes) and the device fetches share one
-        # interpreter; a finer switch interval caps how long either side
-        # can starve the other between checks. Opt-in via env so library
-        # embedders keep the interpreter default.
-        import os
-        import sys
-        si = os.environ.get("KTPU_SWITCH_INTERVAL")
-        if si:
-            sys.setswitchinterval(float(si))
-
         self.cfg = cfg or SchedulerConfiguration()
         # durable AOT executable cache: armed BEFORE the Scheduler exists so
         # every jit this process ever compiles — warm ladder, staging
@@ -704,14 +693,13 @@ class SchedulerRunner:
             "maxDrainBatches": self.cfg.max_drain_batches,
             "pipelineDepth": self.cfg.pipeline_depth,
             # live pipeline depth + resident-context lifecycle counters:
-            # degraded fusion (patches climbing instead of folds, rebuild
-            # reasons piling up) is visible from ktpu status without a
-            # bench run. Momentarily stale is fine for a status surface;
-            # the reasons dict is the one piece that GROWS on the
+            # a degrading context (rebuild reasons piling up against
+            # folds) is visible from ktpu status without a bench run.
+            # Momentarily stale is fine for a status surface; the reasons
+            # dict is the one piece that GROWS on the
             # scheduling thread (new reason keys), so its copy retries —
             # dict() over a concurrently-resizing dict raises RuntimeError.
             "pipelineInflight": len(self.scheduler._pending),
-            "fusedFold": self.scheduler._fused_fold,
             # zero-copy staging health: swaps tracking dispatches 1:1 with
             # fallbacks ~0 means the dispatch path pays buffer swaps, not
             # device_puts (sched/staging.py)

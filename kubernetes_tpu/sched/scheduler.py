@@ -181,24 +181,11 @@ class Scheduler:
                 _LOG.warning("mesh shape %s unavailable; running "
                              "single-device", mesh_shape, exc_info=True)
         MESH_DEVICES.set(self._mesh.devices.size if self._mesh else 1)
-        # Fused fold: churn patches ride the drain dispatch as the resident
-        # program's third input instead of a separate apply_ctx_patch
-        # dispatch (and fold-safe churn skips the pipeline drain). The env
-        # knob exists so a bench A/B can flip modes without config surgery.
-        self._fused_fold = cfg.fused_fold
-        env_fused = _os.environ.get("KTPU_FUSED_FOLD")
-        if env_fused is not None:
-            self._fused_fold = env_fused != "0"
-        # Pre-sharded double-buffered batch staging (sched/staging.py):
-        # dispatch-time stage_drain_batch becomes a buffer swap. The cache
-        # owns the arena (it owns the mesh staging helpers); the env knob
-        # KTPU_STAGE_ARENA=0 wins over config for bench A/Bs.
-        self.cache.configure_staging(cfg.staging_arena)
         # context lifecycle counters (benchmarks report these: a healthy
-        # churn run shows folds/patches >> rebuilds; "folds" are churn
-        # deltas fused into a drain dispatch, "patches" are separate
-        # apply_ctx_patch dispatches — steady-state fused churn keeps
-        # patches at 0)
+        # churn run shows folds >> rebuilds). "folds" are churn deltas that
+        # rode a drain dispatch as drain_step's third input; "patches"
+        # counts separate apply_ctx_patch dispatches on the drain path,
+        # of which there are none — the key stays 0 for its readers
         self.ctx_stats = {"patches": 0, "folds": 0, "rebuilds": 0,
                           "unfit": 0, "reasons": {}}
         # Multi-deep software pipeline: in-flight drains awaiting resolution,
@@ -217,8 +204,6 @@ class Scheduler:
         self._resolver_swap_lock = threading.Lock()
         self._resolver_q: Optional["queue_mod.Queue"] = None  # guarded by: self._resolver_swap_lock
         self._resolver_thread: Optional[threading.Thread] = None  # guarded by: self._resolver_swap_lock
-        self._use_resolver = _os.environ.get(
-            "KTPU_RESOLVER_THREAD", "1") != "0"
         # Fleet mode (sched/fleet.py FleetRunner sets this): pops are split
         # into TENANT-HOMOGENEOUS drain chunks so every tenant's pods sit at
         # batch positions 0..n of their own chunk — the structural property
@@ -442,10 +427,7 @@ class Scheduler:
     def _submit_resolve(self, pend: dict) -> None:
         """Hand the drain's device handles to the resolver thread: it blocks
         in device_get (GIL released in the runtime) and publishes numpy
-        results + sets ``pend['done']``. KTPU_RESOLVER_THREAD=0 disables the
-        thread; _resolve_one then fetches inline as before."""
-        if not self._use_resolver:
-            return
+        results + sets ``pend['done']``."""
         pend["done"] = threading.Event()
         self._ensure_resolver().put(pend)
 
@@ -525,7 +507,7 @@ class Scheduler:
 
         A pop can yield up to ``batch_size * max_drain_batches`` pods; a deep
         backlog takes the fused drain path (one device program for many
-        batches, models/gang.py gang_drain) while shallow pops run the
+        batches, models/gang.py drain_step) while shallow pops run the
         single-batch program."""
         from kubernetes_tpu.utils.tracing import TRACER
         self._fold_staged_nominations()
@@ -1191,7 +1173,6 @@ class Scheduler:
         -> (ctx, use_ctx, fused_patch, pods bound by drains it had to
         resolve first, delta-log entries looked at). ``use_ctx`` False
         means the caller rebuilds from a host snapshot."""
-        from kubernetes_tpu.models.gang import apply_ctx_patch
         from kubernetes_tpu.utils.tracing import TRACER
         ctx = self._drain_ctx
         use_ctx = False
@@ -1238,22 +1219,19 @@ class Scheduler:
                         ctx["seq"] = entries[-1][0] + 1
                     use_ctx = True
                 else:
-                    # Foreign churn / nominee change. Fused-fold mode
-                    # compiles the patch against the LIVE patch state and
-                    # ships it as the drain dispatch's third input — the
-                    # pipeline drains first only when a delta actually
-                    # depends on an in-flight drain's unmirrored folds
-                    # (encode/patch.py entries_fold_safe: a pod an
-                    # in-flight drain is scheduling, or a node delete
-                    # whose retire accounting can't see in-flight folds).
-                    # Legacy mode (fusedFold off) resolves everything and
-                    # dispatches a separate apply_ctx_patch, as before.
+                    # Foreign churn / nominee change: the patch compiles
+                    # against the LIVE patch state and ships as the drain
+                    # dispatch's third input — the pipeline drains first
+                    # only when a delta actually depends on an in-flight
+                    # drain's unmirrored folds (encode/patch.py
+                    # entries_fold_safe: a pod an in-flight drain is
+                    # scheduling, or a node delete whose retire accounting
+                    # can't see in-flight folds).
                     from kubernetes_tpu.encode.patch import entries_fold_safe
-                    if self._pending and not (
-                            self._fused_fold and entries_fold_safe(
-                                cs, entries,
-                                {p.key for pend in self._pending
-                                 for c in pend["chunks"] for p, _ in c})):
+                    if self._pending and not entries_fold_safe(
+                            cs, entries,
+                            {p.key for pend in self._pending
+                             for c in pend["chunks"] for p, _ in c}):
                         n_prev += self._resolve_pending()
                         entries = self.cache.deltas_since(ctx["seq"])
                         n_deltas = len(entries or ())
@@ -1294,27 +1272,11 @@ class Scheduler:
                                     lambda p: self.cache.request_vector(
                                         p, cs.resources))
                                 shadow.apply_patch(patch)
-                            if self._fused_fold:
-                                # the scatter rides THIS dispatch as
-                                # drain_step's third input — zero separate
-                                # device round trips for churn
-                                fused_patch = patch
-                                self.ctx_stats["folds"] += 1
-                            else:
-                                with TRACER.span("scheduler/ctx_patch_apply"), \
-                                        self._mesh_scope():
-                                    # sharded context: the scatter program
-                                    # runs under the mesh — the tiny patch
-                                    # arrays ship via one explicit
-                                    # replicated put, the donated sharded
-                                    # buffers keep their layout
-                                    # (epoch-checked above, out-shardings
-                                    # pinned inside the program)
-                                    ctx["ct"] = apply_ctx_patch(
-                                        ctx["ct"],
-                                        self.cache.stage_patch(patch),
-                                        mesh=self._mesh)
-                                self.ctx_stats["patches"] += 1
+                            # the scatter rides THIS dispatch as
+                            # drain_step's third input — zero separate
+                            # device round trips for churn
+                            fused_patch = patch
+                            self.ctx_stats["folds"] += 1
                             ctx["seq"] = new_seq
                             use_ctx = True
                         elif patch is None:
@@ -1629,7 +1591,7 @@ class Scheduler:
                             else f"silent for {RESOLVE_WAIT_S:.0f}s")
                         break
                 res = pend.pop("resolved", None)
-            if res is None:  # resolver off/stalled or its fetch failed
+            if res is None:  # resolver dead/stalled or its fetch failed
                 try:
                     chaos_point("resolve")
                     res = jax.device_get(
@@ -1846,7 +1808,7 @@ class Scheduler:
                   winners_sharding=self._winners_sharding,
                   mesh=self._mesh)
         # the SAME staging path (and spans) the live dispatch uses — warms
-        # the stager thread + pre-split layouts, and keeps this call site
+        # the stager thread + pre-sharded layouts, and keeps this call site
         # inside the scheduler/stage_batch attribution
         pb_staged = self._stage_batch(
             pb_stack, self.cache.stage_submit(pb_stack), len(sample_pods))
@@ -1866,11 +1828,10 @@ class Scheduler:
             # rehearse the real churn alternation at the standard patch
             # write buckets so every steady-state program compiles here,
             # at each other's output layouts (a layout mismatch recompiles
-            # drain_step for seconds inside the measured window). Fused
-            # mode alternates drain(patch=None) with drain(patch=...);
-            # the standalone apply_ctx_patch still stages rebuild-time
-            # nominee reservations (and is THE churn program with
-            # fusedFold off), so it warms in both modes.
+            # drain_step for seconds inside the measured window). The
+            # served path alternates drain(patch=None) with
+            # drain(patch=...); the standalone apply_ctx_patch stages
+            # rebuild-time nominee reservations, so it warms too.
             try:
                 from kubernetes_tpu.models.gang import apply_ctx_patch
                 cs_warm = self.cache.patch_state_fork()
@@ -1879,7 +1840,7 @@ class Scheduler:
                         self.cache.compile_ctx_patch(
                             fork_meta(meta), cs_warm, [], {},
                             DRAIN_NOM_BUCKET))
-                    if warm_patch is not None and self._fused_fold:
+                    if warm_patch is not None:
                         _, _, ct_dev4, fill4 = drain_step(
                             ct_dev3, pb_staged, fill3, warm_patch, **kw)
                         # plain drain over the fused variant's output
@@ -1888,10 +1849,6 @@ class Scheduler:
                                                       fill4, None, **kw)
                         apply_ctx_patch(ct_dev5, warm_patch,
                                         mesh=self._mesh)
-                    elif warm_patch is not None:
-                        ct_dev4 = apply_ctx_patch(ct_dev3, warm_patch,
-                                                  mesh=self._mesh)
-                        drain_step(ct_dev4, pb_staged, fill3, None, **kw)
             except Exception:
                 LOOP_ERRORS.inc({"site": "warm_patch"})
                 _LOG.exception("patch-program warmup failed (non-fatal)")

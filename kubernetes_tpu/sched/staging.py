@@ -12,18 +12,15 @@ Two pieces live here:
 
 ``StagingArena``
     A background "batch-stager" thread that uploads batch K+1's host stack
-    into PRE-SHARDED device buffers while batch K's drain still runs —
-    one batched sharded put by default, or host-side per-shard slices +
-    ``make_array_from_single_device_arrays`` assembly with KTPU_PRESPLIT=1
-    (parallel/mesh.py ``presplit_stack``; zero runtime re-layout, for
-    runtimes where ``device_put`` against a NamedSharding re-lays-out).
+    into PRE-SHARDED device buffers while batch K's drain still runs:
+    one batched sharded put, off the dispatch thread.
     Double-buffered: at most ``depth`` uploads in flight (the buffer being
     dispatched + the one uploading). At dispatch time
     ``Scheduler._stage_batch`` REDEEMS the ticket — a buffer swap, not a
     ``device_put``. Invalidation discipline mirrors the resident drain
     context: a mesh install/reshape (``SchedulerCache.set_mesh``) bumps the
     arena epoch and every in-flight ticket redeems to None — the caller
-    falls back to the legacy inline ``device_put`` path with bit-identical
+    falls back to the inline ``device_put`` path with bit-identical
     placements (the staged copy is a faithful snapshot of the submitted
     host stack, so a DECLINED swap never loses data, only the overlap).
 
@@ -102,17 +99,7 @@ class StagingArena:
             t.start()
 
     def _loop(self) -> None:
-        import os
-        from kubernetes_tpu.parallel.mesh import (presplit_stack,
-                                                  stack_shardings)
-        # KTPU_PRESPLIT=1: slice every partitioned leaf host-side and
-        # assemble from per-device shards (SNIPPETS [1]/[3] — wins on
-        # runtimes whose device_put re-lays-out against a NamedSharding,
-        # e.g. remote-attached TPU). Default: ONE batched sharded put —
-        # on backends with layout-free transfers (CPU sim) the slicing
-        # overhead exceeds the savings, and the arena's real win is that
-        # either variant runs HERE, off the dispatch thread.
-        presplit = os.environ.get("KTPU_PRESPLIT", "0") == "1"
+        from kubernetes_tpu.parallel.mesh import stack_shardings
         while True:
             item = self._q.get()
             if item is None:  # poison pill from close()
@@ -120,11 +107,8 @@ class StagingArena:
             ticket, pb_stack = item
             try:
                 import jax
-                if presplit:
-                    staged = presplit_stack(ticket.mesh, pb_stack)
-                else:
-                    staged = jax.device_put(
-                        pb_stack, stack_shardings(ticket.mesh, pb_stack))
+                staged = jax.device_put(
+                    pb_stack, stack_shardings(ticket.mesh, pb_stack))
                 jax.block_until_ready(staged)
                 ticket.nbytes = _tree_nbytes(pb_stack)
                 ticket.staged = staged

@@ -22,10 +22,10 @@ wrap-around torus origin x EVERY axis-order rotation at once:
     "fewest-evictions-to-free-a-slice" plane — defrag-toward-contiguity
     and slice preemption read it without a second program.
 
-Expressed as large XLA contractions on purpose: the in-repo
-``pallas_bench`` measured a hand kernel 120x slower than the fused XLA
-form of exactly this kind of pass (see benchmarks/), so there is no
-Pallas here.
+Expressed as large XLA contractions on purpose: a hand Pallas kernel
+measured 120x slower on the v5e than the fused XLA form of exactly this
+kind of pass (ops/topology._domain_counts carries the figures), so there
+is no Pallas here.
 
 Host-side selection is deliberately tiny (argmax/argmin over the readback
 grids) and shared, ORDER AND ALL, with the numpy twin ``numpy_grids`` —

@@ -176,11 +176,10 @@ def test_cache_knobs_cover_lowering_config():
     from kubernetes_tpu.config.types import SchedulerConfiguration
     cfg = SchedulerConfiguration()
     knobs = cache_knobs(cfg)
-    assert set(knobs) == {"meshShape", "fusedFold", "batchSize",
-                          "maxDrainBatches"}
+    assert set(knobs) == {"meshShape", "batchSize", "maxDrainBatches"}
     # any knob change must change the fingerprint (wholesale distrust)
     from kubernetes_tpu.parallel.aot import lowering_fingerprint
-    flipped = dict(knobs, fusedFold=not knobs["fusedFold"])
+    flipped = dict(knobs, batchSize=knobs["batchSize"] * 2)
     assert lowering_fingerprint(knobs) != lowering_fingerprint(flipped)
 
 

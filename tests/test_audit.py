@@ -11,9 +11,9 @@ Contracts pinned here:
    the circuit breaker with reason "parity"; a device program that RAISES
    trips as "device". After a parity trip the scheduler converges via the
    oracle fallback without losing pods.
-3. HYGIENE — stale nominations are garbage-collected by the runner sweep,
-   the bench refuses a summary without the invariant_violations field,
-   and non-daemon thread leaks are detectable.
+3. HYGIENE — stale nominations are garbage-collected by the runner sweep
+   and non-daemon thread leaks are detectable (a result without its
+   invariant_violations field is refused in tests/test_chip_smoke.py).
 """
 
 import io
@@ -537,7 +537,7 @@ def test_verify_wave_results_unit():
         nodes, [victim, small], [pre], [weak]))
 
 
-# ---- 4. surfaces: CLI, config, bench gate, thread-leak detector ----------
+# ---- 4. surfaces: CLI, config, thread-leak detector ----------------------
 
 def test_ktpu_audit_status():
     from kubernetes_tpu.cli.ktpu import cmd_audit
@@ -584,15 +584,6 @@ def test_audit_config_knobs():
         assert sched.sentinel is None
     finally:
         sched.close()
-
-
-def test_bench_summary_refuses_missing_invariant_field():
-    import bench
-    with pytest.raises(SystemExit):
-        bench._require_invariant_field({"metric": "x"}, "test summary")
-    bench._require_invariant_field({"invariant_violations": 0}, "ok")
-    assert bench._sum_violations(None, {"invariant_violations": 2},
-                                 {"invariant_violations": 1}, {}) == 3
 
 
 def test_thread_leak_detector_helper():

@@ -9,8 +9,9 @@ masks on the resident encoding in place, per-node totals read back from it,
 victim request vectors from its fold ledger — instead of re-encoding
 ``nodes``/``bound_pods`` per wave.
 
-The parity tests are the contract: fused-vs-legacy placements must be
-IDENTICAL on the same delta log, and the resident wave must return exactly
+The parity tests are the contract: a context that folds churn must place
+exactly as one rebuilt from the host snapshot before every drain on the
+same delta log, and the resident wave must return exactly
 what the snapshot-path wave returns (PDB budgets, victim sets, dedup
 included) — the fusion is an optimization, never a semantics fork.
 """
@@ -20,7 +21,7 @@ import threading
 import numpy as np
 import pytest
 
-from kubernetes_tpu.config.types import SchedulerConfiguration
+from kubernetes_tpu.config.types import SchedulerConfiguration, validate
 from kubernetes_tpu.sched.cache import SchedulerCache
 from kubernetes_tpu.sched.queue import SchedulingQueue
 from kubernetes_tpu.sched.scheduler import Scheduler
@@ -33,18 +34,17 @@ def _nodes(n, cpu="4", prefix="n"):
             .obj() for i in range(n)]
 
 
-def _sched(nodes, batch_size=4, drain_batches=2, fused=True,
-           pipeline_depth=2, parity_every=0):
+def _sched(nodes, batch_size=4, drain_batches=2,
+           pipeline_depth=2, parity_every=0, cfg=None):
     cache = SchedulerCache()
     for n in nodes:
         cache.add_node(n)
     queue = SchedulingQueue(backoff_initial=0.05)
     log = []
-    cfg = SchedulerConfiguration(batch_size=batch_size,
-                                 max_drain_batches=drain_batches,
-                                 pipeline_depth=pipeline_depth,
-                                 fused_fold=fused,
-                                 parity_sample_every=parity_every)
+    cfg = cfg or SchedulerConfiguration(batch_size=batch_size,
+                                        max_drain_batches=drain_batches,
+                                        pipeline_depth=pipeline_depth,
+                                        parity_sample_every=parity_every)
     sched = Scheduler(cfg, cache, queue,
                       lambda pod, node: log.append(
                           (pod.metadata.name, node)) or True)
@@ -71,7 +71,7 @@ def _drain(sched, queue, pods, rounds=8):
     return bound
 
 
-# ---- tentpole (a): fused fold vs apply-then-dispatch parity ---------------
+# ---- tentpole (a): fused fold vs rebuild-from-snapshot parity -------------
 
 def _churn_script(rng, cache, i):
     """One randomized churn op against the cache (the delta-log feed)."""
@@ -93,18 +93,19 @@ def _churn_script(rng, cache, i):
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_fused_fold_matches_apply_then_dispatch(seed):
-    """Randomized parity: the SAME delta log driven through a fused-fold
-    scheduler and a legacy (separate apply_ctx_patch) scheduler must bind
-    the same pods to the same nodes — the third drain input is the same
-    scatter the standalone dispatch applied, so placements cannot drift."""
+    """Randomized parity: the SAME delta log driven through a scheduler
+    that folds churn into its resident context and through one whose
+    context is discarded before every drain (so each drain rebuilds from
+    the host snapshot: the independent answer a fold must equal) must bind
+    the same pods to the same nodes."""
     rng = np.random.default_rng(seed)
     script = []  # (kind, payload) replayed identically against both
     for i in range(6):
         script.append(("churn", int(rng.integers(0, 4)), i))
         script.append(("drain", i))
 
-    def run(fused):
-        sched, cache, queue, log = _sched(_nodes(3), fused=fused)
+    def run(resident):
+        sched, cache, queue, log = _sched(_nodes(3))
         _arm(sched)
         for step in script:
             if step[0] == "churn":
@@ -112,22 +113,55 @@ def test_fused_fold_matches_apply_then_dispatch(seed):
                               cache, step[2])
             else:
                 i = step[1]
+                if not resident:
+                    # _drain resolved everything: nothing in flight reads it
+                    sched._drain_ctx = None
+                # more than one batch: a pop this deep takes the drain path
+                # with or without a context to ride
                 got = _drain(sched, queue,
                              [make_pod(f"m{i}-{j}").req({"cpu": "200m"}).obj()
-                              for j in range(4)])
-                assert got == 4, f"step {i} lost pods (fused={fused})"
+                              for j in range(6)])
+                assert got == 6, f"step {i} lost pods (resident={resident})"
         stats = dict(sched.ctx_stats)
         sched.close()
         return sorted(log), stats  # bind workers race log order, not content
 
     log_fused, stats_fused = run(True)
-    log_legacy, stats_legacy = run(False)
-    assert log_fused == log_legacy, (log_fused, log_legacy)
-    # both runs took their respective churn path at least once
+    log_rebuilt, stats_rebuilt = run(False)
+    assert log_fused == log_rebuilt, (log_fused, log_rebuilt)
+    # each run took its own way through the churn at least once
     assert stats_fused["patches"] == 0
     assert stats_fused["folds"] >= 1
-    assert stats_legacy["folds"] == 0
-    assert stats_legacy["patches"] >= 1
+    assert stats_rebuilt["folds"] == 0
+    assert stats_rebuilt["rebuilds"] >= 1
+
+
+def test_removed_switch_keys_load_as_unknown_keys_and_the_drain_folds(
+        tmp_path):
+    """``fusedFold`` and ``stagingArena`` were fields once. A file that
+    still carries them loads and validates like one with any unknown key,
+    and the scheduler built from it folds churn into the dispatch."""
+    path = tmp_path / "scheduler.yaml"
+    path.write_text("batchSize: 4\nmaxDrainBatches: 2\n"
+                    "fusedFold: false\nstagingArena: false\n")
+    cfg = SchedulerConfiguration.from_yaml(str(path))
+    validate(cfg)
+    assert cfg.batch_size == 4 and cfg.max_drain_batches == 2
+    assert vars(cfg) == vars(SchedulerConfiguration.from_dict(
+        {"batchSize": 4, "maxDrainBatches": 2}))
+    sched, cache, queue, log = _sched(_nodes(3), cfg=cfg)
+    ctx = _arm(sched)
+    cache.add_pod(make_pod("foreign").req({"cpu": "300m"}).node("n1").obj())
+    assert _drain(sched, queue,
+                  [make_pod(f"m{j}").req({"cpu": "200m"}).obj()
+                   for j in range(4)]) == 4
+    assert sched._drain_ctx is ctx
+    assert sched.ctx_stats["folds"] >= 1 and sched.ctx_stats["patches"] == 0
+    # every key stays for the readers that print each of them
+    assert set(sched.ctx_stats) == {"patches", "folds", "rebuilds", "unfit",
+                                    "reasons"}
+    assert cache.staging_stats()["enabled"] is False  # no mesh, no arena
+    sched.close()
 
 
 def test_fold_safe_churn_does_not_drain_the_pipeline():

@@ -140,13 +140,13 @@ def test_mesh_reshape_forces_ctx_rebuild():
 # ---- donation audit (satellite): steady-state drain/patch aliasing -------
 
 def test_drain_patch_steady_state_no_copy_on_donate_warnings():
-    """The resident ctx is donated through drain_step (both its plain and
-    fused-fold variants) AND apply_ctx_patch; steady-state cycles must
-    alias buffers in place. A 'donated buffers were not usable' warning
-    means a layout mismatch re-copies the multi-MB encoding every drain —
-    the exact regression the warmup double-execute exists to prevent. Runs
-    the THREE-input drain (churn patch fused into the dispatch) and the
-    legacy separate-apply path back to back."""
+    """The resident ctx is donated through drain_step, in both its plain
+    and fused-fold variants; steady-state cycles must alias buffers in
+    place. A 'donated buffers were not usable' warning means a layout
+    mismatch re-copies the multi-MB encoding every drain — the exact
+    regression the warmup double-execute exists to prevent. Runs plain
+    drains and the THREE-input drain (churn patch fused into the
+    dispatch) back to back, twice."""
     sched, cache, queue, log = _scheduler()
     with warnings.catch_warnings(record=True) as caught:
         warnings.simplefilter("always")
@@ -157,18 +157,16 @@ def test_drain_patch_steady_state_no_copy_on_donate_warnings():
             .capacity({"cpu": "8", "memory": "16Gi", "pods": "20"})
             .label("kubernetes.io/hostname", "late-node").obj())
         bound += _run_to_empty(sched, queue, _pods(16, prefix="late"))
-        # legacy mode: churn -> separate apply_ctx_patch dispatch -> drain
-        sched._fused_fold = False
+        # and again: a fold over the layouts a fold left behind
         cache.add_node(
             make_node("late-node-2")
             .capacity({"cpu": "8", "memory": "16Gi", "pods": "20"})
             .label("kubernetes.io/hostname", "late-node-2").obj())
         bound += _run_to_empty(sched, queue, _pods(16, prefix="later"))
     assert bound == 56
-    assert sched.ctx_stats["folds"] >= 1, \
+    assert sched.ctx_stats["folds"] >= 2, \
         "churn did not take the fused-fold path"
-    assert sched.ctx_stats["patches"] >= 1, \
-        "churn did not take the legacy patch path"
+    assert sched.ctx_stats["patches"] == 0
     donate_warnings = [str(w.message) for w in caught
                        if "donated" in str(w.message).lower()]
     assert not donate_warnings, donate_warnings
@@ -284,10 +282,11 @@ def test_publish_status_and_ktpu_status():
         assert "Mesh:" in text and "single-device" in text
         assert "default-scheduler" in text
         # resident-ctx fusion health is part of the status surface
-        assert "Resident ctx:" in text and "fused fold on" in text
+        assert "Resident ctx:" in text and "folds 0, patches 0" in text
         assert "in flight" in text
-        # zero-copy staging health (sched/staging.py arena)
-        assert "Staging:" in text and "arena on" in text
+        # zero-copy staging health (sched/staging.py arena): the mesh
+        # chooses it, and this runner has none
+        assert "Staging:" in text and "arena off" in text
         # no aotCacheDir configured -> the cache reports itself off
         assert "Compile cache: off" in text
         out = io.StringIO()
@@ -298,8 +297,8 @@ def test_publish_status_and_ktpu_status():
         st = json.loads(out.getvalue())
         assert st["mesh"] is None and st["batchSize"] == 256
         assert st["ctx"]["patches"] == 0 and st["ctx"]["folds"] == 0
-        assert st["pipelineInflight"] == 0 and st["fusedFold"] is True
-        assert st["staging"]["enabled"] is True
+        assert st["pipelineInflight"] == 0
+        assert st["staging"]["enabled"] is False
         assert st["staging"]["fallbacks"] == 0
         assert st["aotCache"] == {"enabled": False}
         runner.scheduler.close()

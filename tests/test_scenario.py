@@ -4,9 +4,9 @@ Format tests pin the canonical-bytes contract (save -> load -> save is
 bit-equal, generators are pure in (params, seed), the committed golden
 fixture never drifts); recorder tests turn a real WAL and a synthetic
 audit bundle into traces; the e2e tests replay small traces through a
-REAL in-process apiserver + connected scheduler and hold the same gates
-the ScenarioReplay bench case holds (all resident pods bound, per-phase
-p99 present, dispatch order == plan, status ConfigMap published).
+REAL in-process apiserver + connected scheduler and hold the replay's
+gates (all resident pods bound, per-phase p99 present, dispatch order ==
+plan, status ConfigMap published).
 """
 
 import io
@@ -98,8 +98,8 @@ def test_generator_determinism_across_seeds(name):
 
 def test_golden_fixture_pinned():
     # the committed fixture IS smoke(seed=0): tests and
-    # BENCH_SCENARIO=builtin:smoke replay the same bytes, and toolchain
-    # drift in the generators gets caught here, not in a bench round
+    # `ktpu scenario replay builtin:smoke` replay the same bytes, and
+    # toolchain drift in the generators gets caught here
     assert open(FIXTURE).read().splitlines() == smoke(seed=0).to_lines()
 
 

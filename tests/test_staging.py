@@ -2,17 +2,20 @@
 resident-totals host shadow.
 
 The arena's contract: a redeemed swap is bit- and sharding-identical to the
-legacy inline ``device_put``, and every invalidation path (mesh reshape,
+inline ``device_put``, and every invalidation path (mesh reshape,
 upload failure, dead stager thread, buffer-full submit) DECLINES into the
 inline fallback — placements never depend on which path staged the batch.
 The invalidation matrix runs the live scheduler through mesh reshape,
 catalog-epoch bumps, sticky row-width growth, ctx taint, and mid-stream
-churn with the arena on vs off and diffs placements bit-for-bit.
+churn, once redeeming tickets and once with every ticket declined, and
+diffs placements bit-for-bit.
 
 Mesh-executing tests carry the ``multichip`` marker and gate on the
 test_mesh GSPMD canary, like test_mesh_live.
 """
 
+import sys
+import threading
 import time
 
 import numpy as np
@@ -39,11 +42,9 @@ def _pods(n=24, prefix="p", cpu="500m"):
             .label("app", f"g{i % 3}").obj() for i in range(n)]
 
 
-def _scheduler(mesh_shape=None, nodes=None, batch_size=16, warm=True,
-               staging=True):
+def _scheduler(mesh_shape=None, nodes=None, batch_size=16, warm=True):
     cfg = SchedulerConfiguration(batch_size=batch_size, max_drain_batches=2,
-                                 mesh_shape=mesh_shape,
-                                 staging_arena=staging)
+                                 mesh_shape=mesh_shape)
     validate(cfg)
     cache = SchedulerCache()
     for n in (nodes or _nodes()):
@@ -54,10 +55,14 @@ def _scheduler(mesh_shape=None, nodes=None, batch_size=16, warm=True,
                       lambda pod, node: log.append(
                           (pod.metadata.name, node)) or True)
     if warm:
-        warm_pods = [make_pod(f"__warm{i}").req({"cpu": "100m"}).obj()
-                     for i in range(batch_size)]
-        assert sched.warm_drain(warm_pods, slot_headroom=256)
+        _warm(sched)
     return sched, cache, queue, log
+
+
+def _warm(sched):
+    warm_pods = [make_pod(f"__warm{i}").req({"cpu": "100m"}).obj()
+                 for i in range(sched.cfg.batch_size)]
+    assert sched.warm_drain(warm_pods, slot_headroom=256)
 
 
 def _run_to_empty(sched, queue, pods, rounds=30):
@@ -92,22 +97,6 @@ def _stack(P=8, R=3):
     return {"requests": rng.integers(0, 100, (2, P, R)).astype(np.int32),
             "pod_valid": np.ones((2, P), bool),
             "labels": rng.integers(-1, 9, (2, P, 4)).astype(np.int32)}
-
-
-# ---- presplit parity -----------------------------------------------------
-
-@pytest.mark.multichip
-def test_presplit_matches_device_put():
-    mesh = _mesh_or_skip()
-    import jax
-    from kubernetes_tpu.parallel.mesh import presplit_stack, stack_shardings
-    stack = _stack(P=8)
-    a = presplit_stack(mesh, stack)
-    b = jax.device_put(stack, stack_shardings(mesh, stack))
-    for x, y in zip(jax.tree_util.tree_leaves(a),
-                    jax.tree_util.tree_leaves(b)):
-        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
-        assert x.sharding == y.sharding
 
 
 # ---- arena unit contract -------------------------------------------------
@@ -207,19 +196,67 @@ def test_single_device_submit_is_none_and_inline_counts_bytes():
     assert STAGE_BYTES.get({"path": "inline"}) > before
 
 
-def test_config_and_env_disable_staging(monkeypatch):
-    cfg = SchedulerConfiguration.from_dict({"stagingArena": False})
-    assert cfg.staging_arena is False
-    cache = SchedulerCache()
-    cache.configure_staging(False)
-    assert cache.staging_stats()["enabled"] is False
-    monkeypatch.setenv("KTPU_STAGE_ARENA", "0")
-    cache2 = SchedulerCache()
-    cache2.configure_staging(True)  # env wins OFF for bench A/Bs
-    assert cache2.staging_stats()["enabled"] is False
+# ---- the switches that went: their variables change nothing -------------
+
+@pytest.mark.parametrize("var,value,mesh_shape", [
+    ("KTPU_FUSED_FOLD", "0", None),
+    ("KTPU_RESOLVER_THREAD", "0", None),
+    ("KTPU_SWITCH_INTERVAL", "0.0005", None),
+    pytest.param("KTPU_STAGE_ARENA", "0", (1, 2),
+                 marks=pytest.mark.multichip),
+    pytest.param("KTPU_PRESPLIT", "1", (1, 2),
+                 marks=pytest.mark.multichip),
+])
+def test_a_removed_switch_variable_changes_nothing(monkeypatch, var, value,
+                                                   mesh_shape):
+    """Each variable once selected another way through the drain path (or,
+    for the switch interval, retuned the interpreter). Set to the value
+    that did, one churned drain still folds its deltas into the dispatch,
+    fetches on the resolver thread and leaves the interpreter alone; under
+    a mesh it also stages through the arena, with one sharded put of the
+    whole stack on the stager thread."""
+    import jax
+    from kubernetes_tpu.client.clientset import DirectClient
+    from kubernetes_tpu.sched.runner import SchedulerRunner
+    from kubernetes_tpu.store.store import ObjectStore
+    if mesh_shape is not None:
+        _mesh_or_skip()
+    monkeypatch.setenv(var, value)
+    interval = sys.getswitchinterval()
+    # a runner's construction was where the interpreter was retuned
+    SchedulerRunner(DirectClient(ObjectStore())).scheduler.close()
+    stager_puts = []
+    real_put = jax.device_put
+
+    def spy_put(x, *a, **kw):
+        if threading.current_thread().name == "batch-stager":
+            stager_puts.append(type(x).__name__)
+        return real_put(x, *a, **kw)
+    monkeypatch.setattr(jax, "device_put", spy_put)
+    sched, cache, queue, log = _scheduler(mesh_shape=mesh_shape)
+    if mesh_shape is not None and sched._mesh is None:
+        pytest.skip("mesh unavailable on this backend")
+    try:
+        cache.add_pod(make_pod("foreign").req({"cpu": "300m"})
+                      .node("n001").obj())
+        assert _run_to_empty(sched, queue, _pods(8)) == 8
+        assert sched.ctx_stats["folds"] >= 1, sched.ctx_stats
+        assert sched.ctx_stats["patches"] == 0
+        resolver = sched._resolver_thread
+        assert resolver is not None and resolver.is_alive()
+        assert resolver.name == "drain-resolver"
+        assert sys.getswitchinterval() == interval
+        st = cache.staging_stats()
+        assert st["enabled"] is (mesh_shape is not None)
+        if mesh_shape is not None:
+            assert st["swaps"] >= 1 and st["fallbacks"] == 0, st
+            assert stager_puts and set(stager_puts) == {"PodBatch"}
+    finally:
+        sched.close()
+        sys.setswitchinterval(interval)
 
 
-# ---- live invalidation matrix: arena on == arena off, bit-identical ------
+# ---- live invalidation matrix: redeemed == declined, bit-identical --------
 
 def _matrix_scenario(sched, cache, queue, scenario):
     """One churny workload with a mid-run invalidation event; returns the
@@ -256,22 +293,27 @@ def _matrix_scenario(sched, cache, queue, scenario):
                                       "row_width_growth", "ctx_taint",
                                       "churn_mid_stage"])
 def test_invalidation_matrix_parity_vs_legacy_staging(scenario):
-    """Every invalidation event must fall back to the legacy device_put
-    path with bit-identical placements (arena on vs stagingArena off)."""
+    """Every invalidation event must fall back to the inline device_put
+    path with bit-identical placements: the reference side keeps the mesh
+    and has every ticket declined at submit (what a full buffer does), so
+    each of its batches stages inline."""
     _mesh_or_skip()
     placements = {}
     for staging in (True, False):
-        sched, cache, queue, log = _scheduler(mesh_shape=(1, 2),
-                                              staging=staging)
+        sched, cache, queue, log = _scheduler(mesh_shape=(1, 2), warm=False)
         if sched._mesh is None:
             pytest.skip("mesh unavailable on this backend")
+        if not staging:
+            cache.stage_submit = lambda pb_stack: None
+        _warm(sched)
         bound = _matrix_scenario(sched, cache, queue, scenario)
         expected = 48 + (4 if scenario == "row_width_growth" else 0)
         assert bound == expected, f"{scenario} staging={staging}: {bound}"
         placements[staging] = dict(log)
-        if staging:
-            st = cache.staging_stats()
-            assert st["enabled"] and st["submits"] >= 1
+        st = cache.staging_stats()
+        # "enabled" says whether a mesh is installed NOW
+        assert st["enabled"] is (scenario != "mesh_reshape")
+        assert (st["submits"] >= 1) is staging, st
         sched.close()
     assert placements[True] == placements[False], scenario
 

@@ -6,7 +6,7 @@
 #      so this pass is what turns a syntax error into a hard failure);
 #   2. ktpu-lint over the package with the committed baseline, failing on
 #      any NEW finding and printing a machine-readable [ktpu-lint] JSON
-#      summary line (the bench.py convention) for CI wrappers to parse.
+#      summary line (one JSON object, last) for CI wrappers to parse.
 #
 # Exit: 0 clean, non-zero on syntax errors or new findings.
 set -o pipefail
