@@ -302,6 +302,15 @@ BIND_RESULTS = REGISTRY.counter(
 GANG_ROUNDS = REGISTRY.histogram(
     "scheduler_gang_rounds", "Conflict-resolution rounds per gang batch",
     buckets=(1, 2, 3, 4, 6, 8, 12, 16, 24, 32, 48, 64))
+GANG_ROUNDS_EXHAUSTED = REGISTRY.counter(
+    "scheduler_gang_rounds_exhausted_total",
+    "Gang batches that held pods, ran all maxGangRounds rounds and still "
+    "returned a pod unplaced: the batch ran out of rounds, which is not the "
+    "same as the pod running out of nodes")
+# exposed from import, so that a window in which neither moved reads 0 and
+# not nothing
+GANG_ROUNDS_EXHAUSTED.inc(by=0)
+SCHEDULE_ATTEMPTS.inc({"result": "unschedulable"}, by=0)
 
 # How long a pod stood in the scheduling queue: pop time less the stamp the
 # queue put on it at add (sched/queue.py pop_batch, one pass a pop).

@@ -20,7 +20,9 @@ Design: iterative propose/commit rounds, all tensor-side:
      accepted THIS round conflicts (anti-affinity either direction, shared
      hard-spread domain, or required-affinity forcing co-location). The veto
      is conservative — rejected pods simply re-propose next round against the
-     updated state, so committed state is always sequentially valid.
+     updated state, so committed state is always sequentially valid. "Shared
+     hard-spread domain" is pairwise: of the pods one DoNotSchedule selector
+     matches, a round commits ONE a domain, whatever room ``maxSkew`` leaves.
   5. fold acceptances into requested[N,R] + extension slots; repeat.
 
 ``serial=True`` caps acceptance at one pod per round (highest rank), which
@@ -153,7 +155,10 @@ def _relational_veto(ct: ClusterTensors, pb: PodBatch, choice, accept, rank,
                      topo_keys: tuple[int, ...]):
     """Reject accepted pods conflicting with a higher-rank pod accepted this
     round (anti-affinity both directions, shared hard-spread domain, required
-    affinity forcing co-location). Conservative; rejects re-propose next round."""
+    affinity forcing co-location). Conservative; rejects re-propose next round.
+    The hard-spread arm does not look at ``maxSkew``: two pods of one
+    DoNotSchedule selector that chose the same domain conflict, so a round
+    commits one such pod a domain (three zones: three pods a round)."""
     from kubernetes_tpu.ops.exprs import eval_selector_set
     from kubernetes_tpu.ops.topology import _gather_ns
     P = pb.pod_valid.shape[0]

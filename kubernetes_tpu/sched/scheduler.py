@@ -31,6 +31,7 @@ from kubernetes_tpu.metrics.registry import (
     BATCH_DURATION,
     DRAIN_SHARD_MS,
     GANG_ROUNDS,
+    GANG_ROUNDS_EXHAUSTED,
     LOOP_ERRORS,
     MESH_DEVICES,
     PIPELINE_DEPTH,
@@ -1640,6 +1641,13 @@ class Scheduler:
         # batches converge in one dead round and are not a gang batch)
         GANG_ROUNDS.observe_many(
             int(r) for chunk, r in zip(pend["chunks"], rounds) if chunk)
+        # of those, the batches that ran every round they may and still
+        # hold a pod they did not place: out of rounds, whatever the nodes
+        GANG_ROUNDS_EXHAUSTED.inc(by=sum(
+            1 for chunk, r, assignment
+            in zip(pend["chunks"], rounds, assignments)
+            if chunk and int(r) >= self.cfg.max_gang_rounds
+            and (np.asarray(assignment)[:len(chunk)] < 0).any()))
         # nominations that arrived while this drain was on the device (the
         # descheduler writes them right before evicting): the dispatched
         # program could not reserve them, so winners re-check here — same

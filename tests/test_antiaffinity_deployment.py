@@ -40,9 +40,10 @@ def wait_for(pred, timeout=120.0, interval=0.05):
 
 class Served:
     """An in-process apiserver holding ``nodes`` and a running scheduler at
-    batchSize 16 x 2 drain batches."""
+    batchSize 16 x 2 drain batches; ``scheduler_cfg`` adds to its
+    configuration."""
 
-    def __init__(self, nodes: list, namespaces):
+    def __init__(self, nodes: list, namespaces, **scheduler_cfg):
         self.server = APIServer().start()
         self.client = HTTPClient(self.server.url)
         spaces = self.client.resource("namespaces", None)
@@ -55,7 +56,7 @@ class Served:
             HTTPClient(self.server.url),
             SchedulerConfiguration(batch_size=16, max_drain_batches=2,
                                    backoff_initial_s=0.05,
-                                   backoff_max_s=0.2))
+                                   backoff_max_s=0.2, **scheduler_cfg))
         self.runner.start()
 
     def __enter__(self):
