@@ -17,12 +17,17 @@ Design: iterative propose/commit rounds, all tensor-side:
      rank = (-priority, batch index); segmented exclusive prefix-sums of
      requests accept the prefix that fits (sort + cumsum, no scatter loops).
   4. relational veto: an accepted pod is rejected if a higher-rank pod
-     accepted THIS round conflicts (anti-affinity either direction, shared
-     hard-spread domain, or required-affinity forcing co-location). The veto
-     is conservative — rejected pods simply re-propose next round against the
-     updated state, so committed state is always sequentially valid. "Shared
-     hard-spread domain" is pairwise: of the pods one DoNotSchedule selector
-     matches, a round commits ONE a domain, whatever room ``maxSkew`` leaves.
+     accepted THIS round conflicts (anti-affinity either direction, or
+     required-affinity forcing co-location), or if the higher-rank pods of
+     this round leave its hard-spread constraint no room. The veto is
+     conservative — rejected pods simply re-propose next round against the
+     updated state, so committed state is always sequentially valid. The
+     hard-spread arm is a quota, not a pairwise conflict: of the pods a
+     DoNotSchedule selector matches, a round commits into a domain as many
+     as ``maxSkew`` leaves room for against the minimum at the round's
+     opening (maxSkew 5 over balanced domains: five a domain a round;
+     maxSkew 1: one). Commits only raise counts, so that minimum is never
+     above the true one when a commit is replayed in rank order.
   5. fold acceptances into requested[N,R] + extension slots; repeat.
 
 ``serial=True`` caps acceptance at one pod per round (highest rank), which
@@ -152,19 +157,30 @@ def _segmented_capacity_accept(choice, want, rank, requests, free_at_choice,
 
 
 def _relational_veto(ct: ClusterTensors, pb: PodBatch, choice, accept, rank,
-                     topo_keys: tuple[int, ...]):
+                     topo_keys: tuple[int, ...], spread_room=None):
     """Reject accepted pods conflicting with a higher-rank pod accepted this
-    round (anti-affinity both directions, shared hard-spread domain, required
-    affinity forcing co-location). Conservative; rejects re-propose next round.
-    The hard-spread arm does not look at ``maxSkew``: two pods of one
-    DoNotSchedule selector that chose the same domain conflict, so a round
-    commits one such pod a domain (three zones: three pods a round)."""
+    round (anti-affinity both directions, required affinity forcing
+    co-location) or whose hard-spread constraint has no room left for them.
+    Conservative; rejects re-propose next round.
+
+    The hard-spread arm counts against ``spread_room`` [P,S] — ``maxSkew``
+    less the skew the filter computed at the pod's chosen node
+    (``StepResult.spread_room``; the minimum is the one at the round's
+    opening, before any of this round's commits). Pod q is rejected when
+    more higher-rank accepted pods than that, matched by q's selector in q's
+    namespace, chose q's domain. Replayed in rank order every commit then
+    passes upstream's filter: commits only raise counts, so the true minimum
+    is never below the one used, and a counted pod that another arm rejects
+    only over-counts. ``spread_room`` None (no constraint in the batch, or a
+    profile without the PodTopologySpread filter: nothing to keep valid)
+    skips the arm."""
     from kubernetes_tpu.ops.exprs import eval_selector_set
     from kubernetes_tpu.ops.topology import _gather_ns
     P = pb.pod_valid.shape[0]
     K = ct.node_labels.shape[1]
     higher = (rank[None, :] < rank[:, None]) & accept[None, :] & accept[:, None]  # [q,p]
     conflict = jnp.zeros((P, P), bool)
+    no_room = jnp.zeros(P, bool)
     ns_eq = pb.pod_ns[:, None] == pb.pod_ns[None, :]                # [q,p]
 
     def _term_ns_ok(explicit, mask):
@@ -188,11 +204,14 @@ def _relational_veto(ct: ClusterTensors, pb: PodBatch, choice, accept, rank,
             conflict |= q_hits_p & same
             # symmetry: p's anti term matches q -> q (lower rank) rejected
             conflict |= q_hits_p.T & same
-        if pb.sc_valid.shape[1] > 0:
+        if spread_room is not None:
             m = eval_selector_set(pb.sc_sel, pb.pod_labels)         # [p_t, q, SC]
-            qt = (pb.sc_topo == k) & pb.sc_valid & pb.sc_hard
-            q_hits_p = jnp.any(m & qt[None], axis=-1).T
-            conflict |= q_hits_p & same & ns_eq  # spread: own namespace only
+            qt = (pb.sc_topo == k) & pb.sc_valid & pb.sc_hard       # [q,SC]
+            ahead = same & ns_eq & higher   # [q,p]; spread: own namespace only
+            before = jnp.sum(m & ahead.T[..., None], axis=0,
+                             dtype=jnp.int32)                       # [q,SC]
+            no_room |= jnp.any(qt & (before.astype(jnp.float32) > spread_room),
+                               axis=-1)
         if pb.aff_valid.shape[1] > 0:
             m = eval_selector_set(pb.aff_sel, pb.pod_labels)        # [p_t, q, AT]
             qt = (pb.aff_topo == k) & pb.aff_valid
@@ -201,7 +220,7 @@ def _relational_veto(ct: ClusterTensors, pb: PodBatch, choice, accept, rank,
                                & ns_ok, axis=1)                     # [q,p]
             # required affinity: must be in SAME domain as matching member
             conflict |= q_hits_p & ~same
-    veto = jnp.any(conflict & higher, axis=1)
+    veto = jnp.any(conflict & higher, axis=1) | no_room
     return accept & ~veto
 
 
@@ -267,7 +286,7 @@ def _gang_round_impl(ct_ext: ClusterTensors, pb: PodBatch, state: GangState,
                                             free_at_choice, per_node_cap=cap)
     with jax.named_scope("gang/veto"):
         accept = _relational_veto(ct_round, pb, res.choice, accept, rank,
-                                  topo_keys)
+                                  topo_keys, res.spread_room)
     with jax.named_scope("gang/commit"):
         onehot = ((res.choice[:, None] == jnp.arange(N)[None, :])
                   & accept[:, None])

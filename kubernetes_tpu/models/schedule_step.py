@@ -33,6 +33,11 @@ class StepResult(struct.PyTreeNode):
     assigned: jnp.ndarray   # [P] bool
     feasible: jnp.ndarray   # [P,N] bool
     scores: jnp.ndarray     # [P,N] float32 (-inf infeasible)
+    # [P,S] float32: at the chosen node, how many more matching pods each
+    # spread constraint's domain takes before maxSkew refuses this pod
+    # (topology.spread_mask_and_room); None when the batch has no spread
+    # constraint or the profile runs without the PodTopologySpread filter
+    spread_room: jnp.ndarray | None = None
 
 
 def evaluate(ct: ClusterTensors, pb: PodBatch, seed: int = 0,
@@ -57,8 +62,10 @@ def evaluate(ct: ClusterTensors, pb: PodBatch, seed: int = 0,
         return enabled_filters is None or name in enabled_filters
 
     feasible = run_filters(ct, pb, enabled=enabled_filters)
+    room = None
     if _on("PodTopologySpread"):
-        feasible &= topology.spread_mask(ct, pb, topo_keys)
+        mask, room = topology.spread_mask_and_room(ct, pb, topo_keys)
+        feasible &= mask
     if _on("InterPodAffinity"):
         feasible &= topology.interpod_required_mask(ct, pb, topo_keys)
         feasible &= topology.interpod_symmetry_mask(ct, pb, topo_keys)
@@ -97,9 +104,12 @@ def evaluate(ct: ClusterTensors, pb: PodBatch, seed: int = 0,
     from kubernetes_tpu.ops.filters import tenant_local_rank
     choice, has = select_host(scores, seed=seed,
                               node_rank=tenant_local_rank(ct))
-    return StepResult(choice=choice.astype(jnp.int32),
+    choice = choice.astype(jnp.int32)
+    if room is not None:
+        room = jnp.take_along_axis(room, choice[:, None, None], axis=2)[..., 0]
+    return StepResult(choice=choice,
                       assigned=has & jnp.any(feasible, axis=-1),
-                      feasible=feasible, scores=scores)
+                      feasible=feasible, scores=scores, spread_room=room)
 
 
 @partial(jax.jit, static_argnames=("seed", "fit_strategy", "topo_keys"))

@@ -159,11 +159,10 @@ def _spread_policy_elig(ct: ClusterTensors, pb: PodBatch):
     return ok & ct.node_valid[None, None, :]
 
 
-def spread_mask(ct: ClusterTensors, pb: PodBatch, topo_keys: tuple[int, ...] = ()):
-    """DoNotSchedule constraints: count(domain) + self - min(domain counts)
-    must not exceed maxSkew; nodes lacking the topology key are infeasible."""
-    if pb.sc_valid.shape[1] == 0:
-        return jnp.ones(pb.pod_valid.shape + ct.node_valid.shape, bool)
+def _spread_skew(ct: ClusterTensors, pb: PodBatch, topo_keys: tuple[int, ...]):
+    """-> (skew [P,S,N] f32, has_key [P,S,N] bool): per constraint and node,
+    count(node's domain) + self - min(eligible domain counts), exactly what
+    filtering.go compares with maxSkew."""
     pol = _spread_policy_elig(ct, pb)                         # [P,S,N]
     cnt_pn = _count_pn(ct, pb.sc_sel, pb.pod_ns)              # [P,S,N]
     cnt, has_key, num_dom = _domain_counts(
@@ -181,10 +180,31 @@ def spread_mask(ct: ClusterTensors, pb: PodBatch, topo_keys: tuple[int, ...] = (
     min_unmet = (pb.sc_min_domains > 0) & \
         (num_dom < pb.sc_min_domains.astype(jnp.float32))     # [P,S]
     min_cnt = jnp.where(min_unmet[..., None], 0.0, min_cnt)
-    skew = cnt + self_match[..., None].astype(jnp.float32) - min_cnt
-    ok = has_key & (skew <= pb.sc_maxskew[..., None].astype(jnp.float32))
+    return cnt + self_match[..., None].astype(jnp.float32) - min_cnt, has_key
+
+
+def spread_mask_and_room(ct: ClusterTensors, pb: PodBatch,
+                         topo_keys: tuple[int, ...] = ()):
+    """DoNotSchedule constraints: count(domain) + self - min(domain counts)
+    must not exceed maxSkew; nodes lacking the topology key are infeasible.
+    -> (mask [P,N] bool, room [P,S,N] f32 | None). ``room`` = maxSkew - skew:
+    how many MORE matching pods the node's domain takes before this pod's
+    constraint refuses it, with the minimum as it stands now (>= 0 wherever
+    the mask admits the node). The gang veto's hard-spread arm spends it
+    (models/gang._relational_veto), so one round counts once. None when the
+    batch carries no constraint."""
+    if pb.sc_valid.shape[1] == 0:
+        return jnp.ones(pb.pod_valid.shape + ct.node_valid.shape, bool), None
+    skew, has_key = _spread_skew(ct, pb, topo_keys)
+    room = pb.sc_maxskew[..., None].astype(jnp.float32) - skew
+    ok = has_key & (room >= 0.0)
     active = (pb.sc_valid & pb.sc_hard)[..., None]            # soft/pad -> neutral
-    return jnp.all(ok | ~active, axis=1)                      # [P,N]
+    return jnp.all(ok | ~active, axis=1), room                # [P,N], [P,S,N]
+
+
+def spread_mask(ct: ClusterTensors, pb: PodBatch, topo_keys: tuple[int, ...] = ()):
+    """The filter alone (see ``spread_mask_and_room``). -> [P,N] bool."""
+    return spread_mask_and_room(ct, pb, topo_keys)[0]
 
 
 def spread_score_raw(ct: ClusterTensors, pb: PodBatch, topo_keys: tuple[int, ...] = ()):

@@ -130,12 +130,17 @@ def test_rounds_required_affinity_colocates():
     check_validity(nodes, [], pods, assignment)
 
 
-def test_rounds_hard_spread():
+@pytest.mark.parametrize("max_skew", [1, 5])
+def test_rounds_hard_spread(max_skew):
     nodes = [make_node(f"n{i}").capacity({"cpu": "8", "pods": "50"})
              .label("zone", f"z{i % 3}").obj() for i in range(6)]
-    pods = [make_pod(f"p{i}").label("app", "api")
-            .spread(1, "zone", "DoNotSchedule", {"app": "api"}).obj()
-            for i in range(9)]
+
+    def spread_pods(skew):
+        return [make_pod(f"p{i}").label("app", "api")
+                .spread(skew, "zone", "DoNotSchedule", {"app": "api"}).obj()
+                for i in range(9)]
+
+    pods = spread_pods(max_skew)
     ct, pb, meta = encode(nodes, pods)
     assignment, rounds = gang_schedule(ct, pb, topo_keys=meta.topo_keys)
     a = assignment[:9]
@@ -144,8 +149,14 @@ def test_rounds_hard_spread():
     for i in a:
         z = nodes[i].metadata.labels["zone"]
         zone_counts[z] = zone_counts.get(z, 0) + 1
-    assert max(zone_counts.values()) - min(zone_counts.values()) <= 1, zone_counts
+    assert max(zone_counts.values()) - min(zone_counts.values()) <= max_skew, zone_counts
     check_validity(nodes, [], pods, assignment)
+    if max_skew > 1:
+        # the veto's hard-spread arm commits what maxSkew leaves room for:
+        # the same pods at maxSkew 1 go one a zone a round
+        ct1, pb1, meta1 = encode(nodes, spread_pods(1))
+        _, rounds_1 = gang_schedule(ct1, pb1, topo_keys=meta1.topo_keys)
+        assert rounds < rounds_1, (rounds, rounds_1)
 
 
 def test_priority_order_respected_under_scarcity():

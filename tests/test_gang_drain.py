@@ -7,6 +7,7 @@ valid epods for later batches' spread/affinity/anti-affinity terms).
 """
 
 import numpy as np
+import pytest
 
 from kubernetes_tpu.encode.snapshot import SnapshotEncoder
 from kubernetes_tpu.models.gang import gang_drain, gang_schedule
@@ -67,21 +68,32 @@ def test_cross_batch_capacity_carry():
     assert counts.max() <= 2, counts
 
 
-def test_cross_batch_hard_spread():
-    """Hard zone spread (maxSkew=1) over 4 zones, 2 batches of 4: every zone
-    must end with exactly 2 — requires batch 2 to count batch 1's pods."""
-    nodes = _zone_nodes(8, per_zone=2)
+def _hard_spread_drain(nodes, max_skew, n=8, batch=4):
     pods = [make_pod(f"p{i}").label("app", "a").req({"cpu": "250m"})
-            .spread(1, "topology.kubernetes.io/zone", "DoNotSchedule",
-                    {"app": "a"}).obj() for i in range(8)]
-    ct, pbs, batches, meta = _encode(nodes, pods, batch=4)
-    a, _, _ = gang_drain(ct, pbs, topo_keys=meta.topo_keys)
+            .spread(max_skew, "topology.kubernetes.io/zone", "DoNotSchedule",
+                    {"app": "a"}).obj() for i in range(n)]
+    ct, pbs, batches, meta = _encode(nodes, pods, batch=batch)
+    a, rounds, _ = gang_drain(ct, pbs, topo_keys=meta.topo_keys)
     placed = [int(a[b][i]) for b in range(len(batches))
               for i in range(len(batches[b]))]
+    return placed, int(np.sum(rounds))
+
+
+@pytest.mark.parametrize("max_skew", [1, 5])
+def test_cross_batch_hard_spread(max_skew):
+    """Hard zone spread over 4 zones, 2 batches of 4. At maxSkew 1 every
+    zone must end with exactly 2 — requires batch 2 to count batch 1's pods.
+    At maxSkew 5 the zones stay within 5 of each other and the same pods
+    take fewer rounds: a round commits what maxSkew leaves room for."""
+    nodes = _zone_nodes(8, per_zone=2)
+    placed, rounds = _hard_spread_drain(nodes, max_skew)
     assert all(x >= 0 for x in placed)
     zones = [placed[i] // 2 for i in range(8)]
     counts = np.bincount(zones, minlength=4)
-    assert counts.max() - counts.min() <= 1, counts
+    assert counts.max() - counts.min() <= max_skew, counts
+    if max_skew > 1:
+        _, rounds_1 = _hard_spread_drain(nodes, 1)
+        assert rounds < rounds_1, (rounds, rounds_1)
 
 
 def test_drain_validity_vs_oracle():
