@@ -8,12 +8,17 @@ must stay within ``maxSkew: 5`` of each other over the zones
 upstream's pod-default.yaml, the same requests and no ``color`` label, so
 no constraint counts them.
 
-The harness generates one list and slices it measure | init | warmup, and
-tells a generator the total only. The two kinds of pod differ here, so
-``generate`` finds the measured count in the configuration that asked: the
-one file of ``configs/`` that names this generator with that many nodes and
-pods. ``build`` takes the counts themselves. Nothing is random: ``seed`` is
-accepted and unused, as the upstream workload has no random part."""
+The two kinds of pod differ, so the harness tells this generator the count
+of every phase (``generate_phases``): the measured pods carry the
+constraint, the initial and warm-up pods nothing. ``build`` takes the counts
+themselves. Nothing is random: ``seed`` is accepted and unused, as the
+upstream workload has no random part.
+
+``generate`` and ``measured`` (the total only, and a scan of ``configs/`` for
+the one file with these sizes) are what the harness called before PR 36. It
+no longer does; three cases of tests/test_topologyspread_deployment.py
+still do, and a benchmark PR may not edit a file under tests/: they go with
+those cases (PERF.md section 7)."""
 
 import json
 import os
@@ -42,6 +47,13 @@ def build(nodes: int, spreading: int, default: int) -> tuple[list, list]:
           for i in range(spreading)]
     ps += [pod(f"pod-{i}", REQUESTS) for i in range(default)]
     return ns, ps
+
+
+def generate_phases(seed: int, nodes: int, counts: dict) -> tuple[list, dict]:
+    m, i = counts["measure"], counts["init"]
+    ns, ps = build(nodes, m, i + counts["warmup"])
+    return ns, {"measure": ps[:m], "init": ps[m:m + i],
+                "warmup": ps[m + i:], "pending": []}
 
 
 def measured(nodes: int, pods: int) -> int:

@@ -15,7 +15,6 @@ import multiprocessing as mp
 import os
 import shutil
 import sys
-import threading
 import time
 import traceback
 
@@ -79,38 +78,53 @@ def layer_metrics(kind: str) -> list:
     return out
 
 
+PHASES = ("measure", "init", "warmup", "pending")
+
+
 def build(config: dict, driver, params: dict, seed: int,
           seconds: float) -> dict:
     """Everything the run will create, known before it starts: nodes, the
-    pods of each phase with their namespaces, and the window's groups."""
+    pods of each phase with their namespaces, and the window's groups. A
+    generator with ``generate_phases`` is told every phase's count; one
+    with ``generate`` alone gets the total and is sliced measure | init |
+    warmup."""
     gen = importlib.import_module(
         f"yardstick.generators.{config['generator']}")
     plan = driver.plan(params, config, seed, seconds)
-    n_measure = sum(n for _, n in plan["groups"])
-    n_init, n_warm = int(config["initPods"]), int(config["warmupPods"])
-    nodes, pods = gen.generate(seed, int(config["nodes"]),
-                               n_measure + n_init + n_warm)
-    phases = {"measure": pods[:n_measure],
-              "init": pods[n_measure:n_measure + n_init],
-              "warmup": pods[n_measure + n_init:]}
+    counts = {"measure": sum(n for _, n in plan["groups"]),
+              "init": int(config["initPods"]),
+              "warmup": int(config["warmupPods"]),
+              "pending": int(config.get("pendingPods", 0))}
+    leavers = tuple(config.get("leavers", ()))
+    if not set(leavers) <= {"init", "warmup"}:
+        raise SystemExit(f"leavers {list(leavers)}: only pods bound in "
+                         "set-up (init, warmup) may leave; a measured pod "
+                         "that is gone is failed")
+    if hasattr(gen, "generate_phases"):
+        nodes, phases = gen.generate_phases(seed, int(config["nodes"]),
+                                            dict(counts))
+        got = {phase: len(group) for phase, group in phases.items()}
+        if got != counts:
+            raise SystemExit(f"generator {config['generator']} gave {got} "
+                             f"pods where {counts} were asked for")
+    elif counts["pending"]:
+        raise SystemExit(
+            f"pendingPods {counts['pending']} needs a generator with "
+            f"generate_phases(seed, nodes, counts); {config['generator']} "
+            "has generate(seed, nodes, pods) only, which is sliced measure "
+            "| init | warmup")
+    else:
+        n_measure, n_init = counts["measure"], counts["init"]
+        nodes, pods = gen.generate(seed, int(config["nodes"]),
+                                   n_measure + n_init + counts["warmup"])
+        phases = {"measure": pods[:n_measure],
+                  "init": pods[n_measure:n_measure + n_init],
+                  "warmup": pods[n_measure + n_init:], "pending": []}
     for phase, group in phases.items():
         for p in group:
             p["metadata"]["namespace"] = config["namespaces"][phase]
     return {"nodes": nodes, "phases": phases, "plan": plan,
-            "constraints": tuple(gen.CONSTRAINTS)}
-
-
-class Sampler(threading.Thread):
-    """Pending pods at 10 Hz, in traced runs only."""
-
-    def __init__(self, runner):
-        super().__init__(daemon=True)
-        self.runner, self.samples = runner, []
-        self.halt = threading.Event()
-
-    def run(self) -> None:
-        while not self.halt.wait(0.1):
-            self.samples.append(program.pending(self.runner))
+            "leavers": leavers, "constraints": tuple(gen.CONSTRAINTS)}
 
 
 class Deployment:
@@ -125,6 +139,9 @@ class Deployment:
         measure = world["phases"]["measure"]
         # bound during set-up, before the window
         self.before = world["phases"]["init"] + world["phases"]["warmup"]
+        # created in set-up and not awaited: the configuration states that
+        # they fit nowhere
+        self.pending = world["phases"]["pending"]
         self.groups, at = [], 0
         for due, n in world["plan"]["groups"]:
             self.groups.append((due, config["namespaces"]["measure"],
@@ -153,8 +170,10 @@ class Deployment:
             # the existing-pod bucket is sized here for every pod the cell
             # will create, so no run outgrows it inside the window
             self.runner = program.start_scheduler(
-                url, config["scheduler"], measure + self.before,
-                headroom=len(measure) + len(self.before))
+                url, config["scheduler"],
+                measure + self.before + self.pending,
+                headroom=len(measure) + len(self.before)
+                + len(self.pending))
             if not self.watch_conn.poll(60.0) or (
                     self.watch_conn.recv() != "ready"):
                 raise RuntimeError("the watcher did not come up")
@@ -200,38 +219,57 @@ class Deployment:
         path, loop running — the configuration's initial pods, its warm-up
         pods — created, bound and seen bound before the window. So a
         cache-booted process's canary sample, lazy initialisation and the
-        first staging swap are set-up in cold and warm runs alike."""
-        by_ns: dict = {}
-        for p in self.before:
-            by_ns.setdefault(p["metadata"]["namespace"], []).append(p)
-        for ns, objs in by_ns.items():
-            self.client.pods(ns).create_many(objs)
+        first staging swap are set-up in cold and warm runs alike. Then the
+        pending phase (upstream's ``skipWaitToCompletion``): created, and
+        waited for only until the scheduler has called as many attempts
+        unschedulable as there are such pods, so that every window opens
+        on the pool parked after one attempt."""
+        self._create(self.before)
         if not program.wait_until(
                 lambda: self.bound_count() >= len(self.before), 240.0):
             raise RuntimeError(f"warm-up: {self.count.value} of "
                                f"{len(self.before)} pods seen bound")
+        if self.pending:
+            def judged() -> float:
+                return program.counters(self.runner).get(
+                    program.UNSCHEDULABLE, 0.0)
+
+            floor = judged() + len(self.pending)
+            self._create(self.pending)
+            if not program.wait_until(lambda: judged() >= floor, 240.0,
+                                      every=0.1):
+                raise RuntimeError(
+                    f"warm-up: {judged() - floor + len(self.pending):g} "
+                    f"unschedulable attempts for {len(self.pending)} "
+                    "pending pods")
+
+    def _create(self, pods: list) -> None:
+        """One bulk create a namespace, in the pods' order."""
+        by_ns: dict = {}
+        for p in pods:
+            by_ns.setdefault(p["metadata"]["namespace"], []).append(p)
+        for ns, objs in by_ns.items():
+            self.client.pods(ns).create_many(objs)
 
     def window(self, seconds: float, trace_dir) -> dict:
         """Open the window, let the sender go, wait until every pod is
         seen bound or the plan's deadline, close the window; then collect
         what the watcher and the sender hold. ``trace_dir``: wrap the
-        window in a profiler trace there, and sample the queue."""
+        window in a profiler trace there. The pending phase is not
+        expected: it never binds."""
         plan, runner = self.world["plan"], self.runner
-        sampler = None
         if trace_dir:
             import jax
             shutil.rmtree(trace_dir, ignore_errors=True)
             options = jax.profiler.ProfileOptions()
             options.python_tracer_level = 0
             jax.profiler.start_trace(trace_dir, profiler_options=options)
-            sampler = Sampler(runner)
         program.open_window()
         c_open = program.counters(runner)
         if trace_dir:
             with jax.profiler.TraceAnnotation(xplane.MARK_START,
                                               t=repr(time.time())):
                 pass
-            sampler.start()
         self.go.set()
         _tag, t0 = self.send_conn.recv()
         expected = len(self.before) + sum(n for _, n in plan["groups"])
@@ -245,7 +283,6 @@ class Deployment:
             time.sleep(0.02)
         t_end = time.monotonic()
         if trace_dir:
-            sampler.halt.set()
             with jax.profiler.TraceAnnotation(xplane.MARK_END,
                                               t=repr(time.time())):
                 pass
@@ -264,17 +301,24 @@ class Deployment:
         return {"t0": t0, "t_end": t_end, "spans": spans,
                 "counters": program.delta(c_open, c_close),
                 "pending_at": pending_at, "binds": seen["binds"],
-                "watch_restarts": seen["restarts"], "sent": sent,
-                "queue_depth": sampler.samples if sampler else []}
+                "gone": seen["gone"], "watch_restarts": seen["restarts"],
+                "sent": sent}
 
-    def judge(self, platform: str, binds: dict) -> tuple:
+    def judge(self, platform: str, binds: dict, gone: dict) -> tuple:
         """After the window, outside every timed interval. -> (verdicts,
-        keys of binds the store does not confirm)."""
+        keys of binds the store does not confirm). ``gone``: the pods the
+        watcher saw deleted, {key: [t, last object]}."""
         pods = self.client.resource("pods", None).list()
-        back, unconfirmed = verdicts.read_back(binds, pods)
+        may_leave = {key(p) for phase in self.world["leavers"]
+                     for p in self.world["phases"][phase]}
+        back, unconfirmed = verdicts.read_back(binds, pods, gone, may_leave)
         results = [back]
-        results += verdicts.end_state(self.world["constraints"],
-                                      self.client.nodes().list(), pods)
+        if self.pending:
+            results.append(verdicts.left_pending(
+                [key(p) for p in self.pending], pods, binds))
+        results += verdicts.end_state(
+            self.world["constraints"], self.client.nodes().list(), pods,
+            [obj for _t, obj in gone.values()])
         results += verdicts.own_judges(program.settle(self.runner))
         results.append(verdicts.device_answers(
             platform, program.residency(self.runner),
@@ -315,8 +359,10 @@ def per_layer(cell: dict, kind: str, facts: dict) -> dict:
     return out
 
 
-def pace_and_regime(win: dict, spans: dict, setup_s: float) -> dict:
-    """What is reported beside the result and never judged."""
+def pace_and_regime(win: dict, spans: dict, setup_s: float,
+                    pending: list) -> dict:
+    """What is reported beside the result and never judged. ``pending``:
+    keys of the pods created in set-up and not awaited."""
     window = win["counters"]
     create_errors = [s[2] for s in win["sent"] if s[2]]
     return {
@@ -332,6 +378,9 @@ def pace_and_regime(win: dict, spans: dict, setup_s: float) -> dict:
         "loop_errors": {k: v for k, v in window.items()
                         if k.startswith("scheduler_loop_errors_total") and v},
         "watch_restarts": win["watch_restarts"],
+        "pending_pods": len(pending),
+        "pending_bound": sum(k in win["binds"] for k in pending),
+        "left": len(win["gone"]),
         "create_errors": len(create_errors),
         "first_create_errors": create_errors[:3],
         "span_ms": {k: round(v["ms"], 1) for k, v in spans.items()},
@@ -364,7 +413,7 @@ def run(args, t_start: float) -> int:
             dep.warm_up()
             win = dep.window(args.seconds, trace_dir)
             results, unconfirmed = dep.judge(device["platform"],
-                                             win["binds"])
+                                             win["binds"], win["gone"])
             device["memory_peak_bytes"] = program.memory_peak_bytes()
             groups = dep.groups
     except Exception:
@@ -389,7 +438,7 @@ def run(args, t_start: float) -> int:
                  "groups": [[g[0], len(g[2]), *s]
                             for g, s in zip(groups, win["sent"])],
                  "spans": spans, "counters": win["counters"],
-                 "queue_depth": win["queue_depth"], "trace": None}
+                 "trace": None}
         try:
             facts["trace"] = trace = xplane.reduce(
                 xplane.newest_trace(trace_dir), win["spans"])
@@ -413,23 +462,32 @@ def run(args, t_start: float) -> int:
                    for name, value in found.items()
                    if wanted is None or name in wanted}
 
-    beside = pace_and_regime(win, spans, setup_s)
+    beside = pace_and_regime(win, spans, setup_s,
+                             [key(p) for p in world["phases"]["pending"]])
     say("counters: " + json.dumps(beside))
-    for name, ok, detail in results:
+    for name, ok, detail, _n in results:
         if not ok:
             say(f"VERDICT FAILED {name}: {detail}")
-    line = {"correct": all(ok for _, ok, _ in results),
+    line = {"correct": all(ok for _, ok, _, _ in results),
             "attempted": len(due), "failed": len(due) - n_bound,
             "metrics": metrics, "device": device}
     if breakdown is not None:
         line["breakdown"] = breakdown
+    # every number ``correct`` compared, beside its limit: last in the
+    # line, and the last lines of standard error
+    line["compared"] = {name: {"value": n, "limit": 0}
+                        for name, _, _, n in results}
     full = dict(line, workload=cell["name"], seed=args.seed,
                 seconds=args.seconds, trace=args.trace, counters=beside,
                 verdicts=[{"name": n, "ok": ok, "detail": d}
-                          for n, ok, d in results])
+                          for n, ok, d, _ in results])
     os.makedirs(OUT, exist_ok=True)
     with open(os.path.join(OUT, f"{cell['name']}.{args.seed}.json"),
               "w") as f:
         json.dump(full, f, indent=1)
+    for name, number in line["compared"].items():
+        print(f"compared {name}: {number['value']} limit "
+              f"{number['limit']}", file=sys.stderr)
+    sys.stderr.flush()
     say(json.dumps(line))
     return 0

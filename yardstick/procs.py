@@ -1,8 +1,8 @@
 """The benchmark's own processes: the apiserver, the watcher that stamps
-binds and the sender that creates pods. Each is a spawned interpreter of
-its own, so none shares the scheduler's GIL, and none imports jax. All
-stamps are ``time.monotonic()``, which on Linux is one clock for every
-process of the machine."""
+binds and deletes, and the sender that creates pods. Each is a spawned
+interpreter of its own, so none shares the scheduler's GIL, and none
+imports jax. All stamps are ``time.monotonic()``, which on Linux is one
+clock for every process of the machine."""
 
 from __future__ import annotations
 
@@ -23,12 +23,15 @@ def serve(conn) -> None:
 
 def watch(url: str, rv0: int, count, stop, conn) -> None:
     """Stamp each pod's first watch event that carries ``spec.nodeName``,
-    in every namespace. ``count`` follows the number stamped. On ``stop``
-    sends {"binds": {"ns/name": [t, node]}, "restarts": n}; a stream that
-    closes is opened again from the last resourceVersion seen."""
+    in every namespace, and each pod's ``DELETED`` event with the object
+    as the event carried it. ``count`` follows the number of binds
+    stamped. On ``stop`` sends {"binds": {"ns/name": [t, node]}, "gone":
+    {"ns/name": [t, last object]}, "restarts": n}; a stream that closes is
+    opened again from the last resourceVersion seen."""
     from kubernetes_tpu.client.clientset import HTTPClient
     pods = HTTPClient(url, timeout=30.0).resource("pods", None)
     binds: dict = {}
+    gone: dict = {}
     restarts, rv = 0, rv0
     w = pods.watch(since_rv=rv)
     conn.send("ready")
@@ -41,12 +44,15 @@ def watch(url: str, rv0: int, count, stop, conn) -> None:
             continue
         rv = max(rv, ev.resource_version)
         obj = ev.object or {}
+        if ev.type == "DELETED":
+            gone[key(obj)] = [time.monotonic(), obj]
+            continue
         node = obj.get("spec", {}).get("nodeName")
         if node and key(obj) not in binds:
             binds[key(obj)] = [time.monotonic(), node]
             count.value = len(binds)
     w.stop()
-    conn.send({"binds": binds, "restarts": restarts})
+    conn.send({"binds": binds, "gone": gone, "restarts": restarts})
 
 
 def send(url: str, groups: list, threads: int, go, conn) -> None:

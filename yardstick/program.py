@@ -16,6 +16,10 @@ DEVICE_ERROR_SITES = ("device_drain", "device_gang", "device_preempt",
                       "drain_resolve", "resolver", "resolver_wait",
                       "drain_ready", "warm_patch")
 
+# attempts the scheduler called unschedulable (set-up waits on it for a
+# configuration's pending pods)
+UNSCHEDULABLE = 'scheduler_schedule_attempts_total{result="unschedulable"}'
+
 _SERIES = re.compile(r"^([^#\s]+)\s+(\S+)$")
 
 

@@ -12,7 +12,6 @@ inside the window;
 program's Prometheus exposition by its exposed name, ``ctx.<key>`` of the
 resident context and ``compile.real`` / ``compile.backend`` /
 ``compile.hits`` of the compile meter (program.counters);
-``queue_depth``: pending pods sampled at 10 Hz;
 ``trace``: what xplane.reduce made of the profiler trace, or None.
 """
 

@@ -1,9 +1,13 @@
 """The plain reference: validity of an end state, one file a constraint
 kind. Each kind file has ``check(nodes, pods) -> list[str]`` over the node
 and pod objects as the apiserver lists them (plain dicts); every bound pod
-is checked, not a sample. Nothing here imports the program."""
+is checked, not a sample. A kind that has to know who left (a preemption's
+victims) declares ``check(nodes, pods, gone)`` and is also given the last
+objects of the pods deleted since the watch began. Nothing here imports
+the program."""
 
 import importlib
+import inspect
 
 
 def load(kinds) -> dict:
@@ -20,6 +24,11 @@ def load(kinds) -> dict:
                 f"{kind}.py: add the file with the generator") from e
         checks[kind] = mod.check
     return checks
+
+
+def takes_gone(check) -> bool:
+    """Whether a kind's ``check`` declares the third parameter."""
+    return len(inspect.signature(check).parameters) >= 3
 
 
 def bound_by_node(pods) -> dict:

@@ -53,9 +53,19 @@ def test_the_new_metric_files_come_in_pairs():
         assert specs["arrivals"]["moves"] == "bind_p99_s"
         for k in ("layer", "unit", "better", "source", "reader", "args"):
             assert specs["burst"][k] == specs["arrivals"][k], (name, k)
+    # and every file, paired or not, is named for the one kind it reports
+    # and moves that kind's end-to-end metric
+    moves = {"burst": "bound_rate", "arrivals": "bind_p99_s"}
     every = glob.glob(os.path.join(ROOT, "yardstick", "layer_metrics",
                                    "*.json"))
-    assert len(every) == 21 + 2 * len(NEW)
+    assert len(every) >= 21 + 2 * len(NEW)
+    for path in every:
+        with open(path) as f:
+            spec = json.load(f)
+        base, _, kind = spec["name"].rpartition(".")
+        assert os.path.basename(path) == spec["name"] + ".json"
+        assert base and spec["kinds"] == [kind], spec["name"]
+        assert spec["moves"] == moves[kind], spec["name"]
 
 
 def test_traced_burst_reports_the_hosts_own_account():

@@ -1,9 +1,12 @@
 """The topologyspread-5000n deployment's rehearsal twin through run.py on
-the CPU: ``maxGangRounds`` 2 at batches of 16 keeps the full-size regime
-(one pod a zone a round, 6 of 16 placed, the rest unschedulable, explained,
-backed off and retried), so the three metrics the cell brought find
-something to read. The reference, the generator and the counter are held
-by tests/test_topologyspread_deployment.py."""
+the CPU. Since PR 35 a gang round commits what ``maxSkew`` leaves room for
+in each zone, and the full-size cell places every pod on its first attempt;
+here ``maxGangRounds`` 2 at batches of 16 still runs about one batch in four
+out of rounds (its proposals bunch in one zone and want a third round), so a
+few of the 36 pods are called unschedulable, explained, backed off and
+retried, and the three metrics the cell brought find something to read. The
+reference, the generator and the counter are held by
+tests/test_topologyspread_deployment.py."""
 
 from conftest import KEYS, run_cell
 
