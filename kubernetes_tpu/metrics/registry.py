@@ -294,8 +294,25 @@ EXPLAIN_SAMPLES = REGISTRY.counter(
     "scheduler_explainer_pods_total",
     "Pods explained by the decision-provenance explainer, by mode "
     "(tensor = batched per-filter-output program, oracle = numpy fallback)")
+# The explainer's three answers to a capture (sched/explainer.py submit /
+# submit_direct), so that its fallback to the generic event is a series and
+# not a Python attribute.
+EXPLAIN_CAPTURES = REGISTRY.counter(
+    "scheduler_explain_captures_total",
+    "Captures of a cycle's unschedulable pods handed to the explainer, by "
+    "result (accepted = queued for a verdict, throttled = every pod was "
+    "explained inside the re-explain interval, skipped = backlog full, the "
+    "generic FailedScheduling event is the fallback)")
 QUEUE_DEPTH = REGISTRY.gauge(
     "scheduler_pending_pods", "Pending pods by queue (active|backoff|unschedulable)")
+# pkg/scheduler/metrics queue_incoming_pods_total: pods added to a queue of
+# the scheduling queue, by the queue and by the event that put them there
+# (sched/queue.py; one inc by a count a call, outside the heap loops).
+QUEUE_INCOMING = REGISTRY.counter(
+    "scheduler_queue_incoming_pods_total",
+    "Pods added to scheduling queues by queue (active|backoff|"
+    "unschedulable) and event (PodAdd, ScheduleAttemptFailure, "
+    "BackoffComplete, UnschedulableTimeout, or the cluster event's name)")
 BIND_RESULTS = REGISTRY.counter(
     "scheduler_bind_failures_total",
     "Bind RPC failures by class (conflict|error|connection)")
@@ -311,6 +328,13 @@ GANG_ROUNDS_EXHAUSTED = REGISTRY.counter(
 # not nothing
 GANG_ROUNDS_EXHAUSTED.inc(by=0)
 SCHEDULE_ATTEMPTS.inc({"result": "unschedulable"}, by=0)
+for _result in ("accepted", "throttled", "skipped"):
+    EXPLAIN_CAPTURES.inc({"result": _result}, by=0)
+for _queue, _event in (("active", "PodAdd"), ("active", "BackoffComplete"),
+                       ("active", "UnschedulableTimeout"),
+                       ("backoff", "ScheduleAttemptFailure"),
+                       ("unschedulable", "ScheduleAttemptFailure")):
+    QUEUE_INCOMING.inc({"queue": _queue, "event": _event}, by=0)
 
 # How long a pod stood in the scheduling queue: pop time less the stamp the
 # queue put on it at add (sched/queue.py pop_batch, one pass a pop).

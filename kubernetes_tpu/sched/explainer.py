@@ -36,10 +36,12 @@ from collections import OrderedDict
 from typing import Callable, Optional
 
 from kubernetes_tpu.metrics.registry import (
+    EXPLAIN_CAPTURES,
     EXPLAIN_SAMPLES,
     LOOP_ERRORS,
     UNSCHEDULABLE_REASONS,
 )
+from kubernetes_tpu.utils.tracing import TRACER
 
 _LOG = logging.getLogger(__name__)
 
@@ -100,9 +102,11 @@ class SchedulingExplainer:
             # every pod was explained moments ago; its event/ConfigMap
             # entry is still fresh — recording another identical generic
             # event would only be noise
+            EXPLAIN_CAPTURES.inc({"result": "throttled"})
             return True
         if self._q.qsize() >= self._max_backlog:
             self.skipped += 1
+            EXPLAIN_CAPTURES.inc({"result": "skipped"})
             return False
         for p in fresh:
             self._last_explained[p.key] = now
@@ -112,12 +116,16 @@ class SchedulingExplainer:
                 k: t for k, t in self._last_explained.items() if t > cutoff}
         self.samples += 1
         self._ensure_thread()
-        self._q.put({"ts": now, "level": level,
-                     "profile": profile.scheduler_name if profile else "",
-                     "pods": list(fresh),
-                     "nodes": cache.list_nodes(),
-                     "bound": cache.bound_pods(include_assumed=True),
-                     "ns_labels": cache.namespace_labels()})
+        with TRACER.span("explain/capture", pods=len(fresh)) as sp:
+            nodes = cache.list_nodes()
+            bound = cache.bound_pods(include_assumed=True)
+            if sp is not None:
+                sp.attributes.update(nodes=len(nodes), bound=len(bound))
+            self._q.put({"ts": now, "level": level,
+                         "profile": profile.scheduler_name if profile else "",
+                         "pods": list(fresh), "nodes": nodes, "bound": bound,
+                         "ns_labels": cache.namespace_labels()})
+        EXPLAIN_CAPTURES.inc({"result": "accepted"})
         return True
 
     def submit_direct(self, pod, message: str, filters: dict,
@@ -131,9 +139,11 @@ class SchedulingExplainer:
         emitted the same message)."""
         now = time.time()  # ktpu-lint: disable=KTL003 -- same wall-clock re-explain throttle as submit() above (baselined); entries carry wall ts for ktpu why
         if now - self._last_explained.get(pod.key, 0.0) < REEXPLAIN_INTERVAL_S:
+            EXPLAIN_CAPTURES.inc({"result": "throttled"})
             return True
         if self._q.qsize() >= self._max_backlog:
             self.skipped += 1
+            EXPLAIN_CAPTURES.inc({"result": "skipped"})
             return False
         self._last_explained[pod.key] = now
         self.samples += 1
@@ -144,6 +154,7 @@ class SchedulingExplainer:
                                "nodes": n_nodes, "feasibleNow": 0,
                                "unjudged": 0, "mode": "carve", "ts": now,
                                "profile": profile}})
+        EXPLAIN_CAPTURES.inc({"result": "accepted"})
         return True
 
     # ---- results surface -------------------------------------------------
@@ -239,7 +250,6 @@ class SchedulingExplainer:
 
     def _explain(self, item: dict) -> None:
         from kubernetes_tpu.models.explain import failed_scheduling_message
-        from kubernetes_tpu.utils.tracing import TRACER
         pods, nodes = item["pods"], item["nodes"]
         profile = self._profile(item["profile"])
         views = (profile.apply_added_affinity(pods)
@@ -321,7 +331,6 @@ class SchedulingExplainer:
         from kubernetes_tpu.encode.snapshot import SnapshotEncoder
         from kubernetes_tpu.models.explain import (explain_step, first_fail,
                                                    reject_histogram)
-        from kubernetes_tpu.utils.tracing import TRACER
         if self._encoder is None:
             self._encoder = SnapshotEncoder()
         enc = self._encoder

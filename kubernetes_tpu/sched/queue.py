@@ -21,7 +21,7 @@ from dataclasses import dataclass, field
 from typing import Optional
 
 from kubernetes_tpu.api.types import Pod
-from kubernetes_tpu.metrics.registry import QUEUE_WAIT
+from kubernetes_tpu.metrics.registry import QUEUE_INCOMING, QUEUE_WAIT
 from kubernetes_tpu.utils.tracing import FLIGHT
 
 # Cluster events that can make unschedulable pods schedulable again
@@ -31,6 +31,16 @@ EVENT_NODE_UPDATE = "NodeUpdate"
 EVENT_POD_DELETE = "PodDelete"
 EVENT_POD_UPDATE = "PodUpdate"
 EVENT_UNSCHEDULABLE_TIMEOUT = "UnschedulableTimeout"
+# scheduler_queue_incoming_pods_total's other events (upstream's names)
+EVENT_POD_ADD = "PodAdd"
+EVENT_ATTEMPT_FAILURE = "ScheduleAttemptFailure"
+EVENT_BACKOFF_COMPLETE = "BackoffComplete"
+
+
+def _incoming(queue: str, event: str, n: int = 1) -> None:
+    """``n`` pods entered ``queue`` on ``event``: one inc a call."""
+    if n:
+        QUEUE_INCOMING.inc({"queue": queue, "event": event}, by=n)
 
 
 @dataclass(order=True)
@@ -88,9 +98,11 @@ class SchedulingQueue:
             if pod.spec.scheduling_gates:
                 # SchedulingGates PreEnqueue: hold until gates cleared.
                 self._unschedulable[k] = item
+                _incoming("unschedulable", EVENT_POD_ADD)
                 return
             heapq.heappush(self._active, item)
             self._lock.notify_all()
+        _incoming("active", EVENT_POD_ADD)
         FLIGHT.record(k, "queue_add")
 
     def add_unschedulable(self, pod: Pod, attempts: int):
@@ -109,6 +121,7 @@ class SchedulingQueue:
             heapq.heappush(self._backoff, (time.time() + delay, item))
             self._keys_queued.add(k)
             self._lock.notify_all()
+        _incoming("backoff", EVENT_ATTEMPT_FAILURE)
         FLIGHT.record(k, "requeue", attempts=attempts)
 
     def park_unschedulable(self, pod: Pod, attempts: int):
@@ -120,6 +133,7 @@ class SchedulingQueue:
             self._entries[k] = item
             self._unschedulable[k] = item
             self._keys_queued.add(k)
+        _incoming("unschedulable", EVENT_ATTEMPT_FAILURE)
         FLIGHT.record(k, "park", attempts=attempts)
 
     def delete(self, pod: Pod):
@@ -139,6 +153,7 @@ class SchedulingQueue:
     def move_all_to_active_or_backoff(self, event: str):
         """Cluster event: unschedulable pods get another chance
         (MoveAllToActiveOrBackoffQueue)."""
+        moved = 0
         with self._lock:
             for k, item in list(self._unschedulable.items()):
                 if item.pod.spec.scheduling_gates:
@@ -146,7 +161,9 @@ class SchedulingQueue:
                 del self._unschedulable[k]
                 if self._current_locked(item):
                     heapq.heappush(self._active, item)
+                    moved += 1
             self._lock.notify_all()
+        _incoming("active", event, moved)
 
     def activate_gated(self, pod: Pod):
         """Gates removed (pod update): move from unschedulable to activeQ."""
@@ -158,17 +175,18 @@ class SchedulingQueue:
                 item.pod = pod
                 heapq.heappush(self._active, item)
                 self._lock.notify_all()
+                _incoming("active", EVENT_POD_UPDATE)
 
     # ---- consumer --------------------------------------------------------
 
     def _flush_backoff_locked(self):
         now = time.time()
-        moved = False
+        backed_off = timed_out = 0
         while self._backoff and self._backoff[0][0] <= now:
             _, item = heapq.heappop(self._backoff)
             if self._current_locked(item):
                 heapq.heappush(self._active, item)
-                moved = True
+                backed_off += 1
         # unschedulable timeout sweep
         for k, item in list(self._unschedulable.items()):
             if (not item.pod.spec.scheduling_gates
@@ -176,8 +194,10 @@ class SchedulingQueue:
                 del self._unschedulable[k]
                 if self._current_locked(item):
                     heapq.heappush(self._active, item)
-                    moved = True
-        return moved
+                    timed_out += 1
+        _incoming("active", EVENT_BACKOFF_COMPLETE, backed_off)
+        _incoming("active", EVENT_UNSCHEDULABLE_TIMEOUT, timed_out)
+        return bool(backed_off or timed_out)
 
     def _active_has_current_locked(self) -> bool:
         # drop stale heap heads so waiters don't wake for deleted pods

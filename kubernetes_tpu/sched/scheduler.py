@@ -1988,44 +1988,49 @@ class Scheduler:
         resolved as ONE wave (sequential-commit device program,
         sched/preemption.py preempt_wave) instead of one full dry-run per
         pod — a preemption storm was 0.67s/pod of host re-encoding before.
-        (Metrics for the unschedulable result are batched by the caller.)"""
-        preemptable: list[tuple[Pod, int]] = []
-        preempt_on = self.features.enabled("PreemptionSimulation")
-        unschedulable: list[Pod] = []
-        slice_gangs: dict[str, list[tuple[Pod, int]]] = {}
-        for pod, attempts in failures:
-            if self.cache.is_bound(pod.key):
-                # Bound by another party while in-flight (its own bound copy
-                # may even be why the gang step couldn't place it).
-                # Requeueing would cycle it through backoffQ forever — no
-                # future event clears it. No FailedScheduling event either:
-                # the pod IS scheduled.
-                continue
-            unschedulable.append(pod)
-            g = self._carve_gang_of(pod)
-            if g is not None:
-                # failed-carve slice members: the whole gang preempts as
-                # one contiguous victim set (below), never as per-pod
-                # wave entries chasing unrelated nodes
-                slice_gangs.setdefault(g, []).append((pod, attempts))
-            elif pod.spec.priority > 0 and preempt_on:
-                preemptable.append((pod, attempts))
-            else:
-                self._after_preempt(pod, attempts, None)
-        self._emit_failed_scheduling(unschedulable)
-        for g, gang_members in sorted(slice_gangs.items()):
-            self._slice_preempt_gang(g, gang_members, preempt_on)
-        if not preemptable:
+        (Metrics for the unschedulable result are batched by the caller.)
+        A drain in which every pod was placed opens no span."""
+        if not failures:
             return
-        if self._custom_preemptor or len(preemptable) == 1:
-            # injected preemptors keep the one-pod contract
-            for pod, attempts in preemptable:
-                self._after_preempt(pod, attempts, self.preemptor(pod))
-        else:
-            nominations = self._default_preempt_wave(
-                [p for p, _ in preemptable])
-            for (pod, attempts), node in zip(preemptable, nominations):
-                self._after_preempt(pod, attempts, node)
+        from kubernetes_tpu.utils.tracing import TRACER
+        with TRACER.span("scheduler/handle_failures", pods=len(failures)):
+            preemptable: list[tuple[Pod, int]] = []
+            preempt_on = self.features.enabled("PreemptionSimulation")
+            unschedulable: list[Pod] = []
+            slice_gangs: dict[str, list[tuple[Pod, int]]] = {}
+            for pod, attempts in failures:
+                if self.cache.is_bound(pod.key):
+                    # Bound by another party while in-flight (its own bound
+                    # copy may even be why the gang step couldn't place it).
+                    # Requeueing would cycle it through backoffQ forever — no
+                    # future event clears it. No FailedScheduling event
+                    # either: the pod IS scheduled.
+                    continue
+                unschedulable.append(pod)
+                g = self._carve_gang_of(pod)
+                if g is not None:
+                    # failed-carve slice members: the whole gang preempts as
+                    # one contiguous victim set (below), never as per-pod
+                    # wave entries chasing unrelated nodes
+                    slice_gangs.setdefault(g, []).append((pod, attempts))
+                elif pod.spec.priority > 0 and preempt_on:
+                    preemptable.append((pod, attempts))
+                else:
+                    self._after_preempt(pod, attempts, None)
+            self._emit_failed_scheduling(unschedulable)
+            for g, gang_members in sorted(slice_gangs.items()):
+                self._slice_preempt_gang(g, gang_members, preempt_on)
+            if not preemptable:
+                return
+            if self._custom_preemptor or len(preemptable) == 1:
+                # injected preemptors keep the one-pod contract
+                for pod, attempts in preemptable:
+                    self._after_preempt(pod, attempts, self.preemptor(pod))
+            else:
+                nominations = self._default_preempt_wave(
+                    [p for p, _ in preemptable])
+                for (pod, attempts), node in zip(preemptable, nominations):
+                    self._after_preempt(pod, attempts, node)
 
     def _emit_failed_scheduling(self, pods: list[Pod]) -> None:
         """FailedScheduling events for one cycle's unschedulable pods. The

@@ -39,9 +39,16 @@ SPAN_THREADS = {
     "scheduler/resolver_fetch": "drain-resolver",
     "scheduler/bind_bulk": "binder-",
     "scheduler/bind_call": "binder-",
+    # the failure path (PR 37): only a drain that left a pod unplaced
+    "scheduler/handle_failures": "scheduler-loop",
+    "explain/capture": "scheduler-loop",
 }
 
 _SERIES = re.compile(r"^([^#\s]+)\s+(\S+)$")
+# pods of the served run that fit nowhere: more than a batch, so that they
+# are part of a drain whatever the pop that takes them
+STUCK = 10
+UNSCHEDULABLE = 'scheduler_schedule_attempts_total{result="unschedulable"}'
 
 
 def series() -> dict:
@@ -154,7 +161,9 @@ def test_queue_wait_counts_one_observation_a_popped_pod(queue_cls):
 @pytest.fixture(scope="module")
 def served():
     """A small served run through the drain path with every drain sampled
-    by the sentinel: 4 nodes, 40 pods in two bulk creates."""
+    by the sentinel: 4 nodes, 40 pods in two bulk creates, the second with
+    ten more that fit nowhere (100 CPU on nodes of 16): the failure path
+    runs once, and the pods' back-off outlasts the test."""
     server = APIServer().start()
     client = HTTPClient(server.url)
     for i in range(4):
@@ -163,7 +172,8 @@ def served():
                 {"cpu": "16", "memory": "32Gi", "pods": "64"})
             .label("kubernetes.io/hostname", f"n{i}").obj().to_dict())
     runner = SchedulerRunner(HTTPClient(server.url), SchedulerConfiguration(
-        batch_size=8, max_drain_batches=2, parity_sample_every=1))
+        batch_size=8, max_drain_batches=2, parity_sample_every=1,
+        backoff_initial_s=600.0, backoff_max_s=600.0))
     # the informer and encoder collectors sum over weakly held objects: one
     # that an earlier test of this process left unreachable must drop out
     # BEFORE the first scrape, not between the two
@@ -177,11 +187,16 @@ def served():
         for wave in range(2):
             pods.create_many([
                 make_pod(f"w{wave}-p{i}").req({"cpu": "100m"})
-                .obj().to_dict() for i in range(20)])
+                .obj().to_dict() for i in range(20)] + [
+                make_pod(f"stuck-p{i}").req({"cpu": "100"})
+                .obj().to_dict() for i in range(STUCK * wave)])
             assert wait_for(lambda: sum(
                 1 for p in pods.list() if p["spec"].get("nodeName"))
                 == 20 * (wave + 1)), "pods never bound"
+        assert wait_for(lambda: series().get(UNSCHEDULABLE, 0.0)
+                        - before.get(UNSCHEDULABLE, 0.0) == STUCK)
         runner.scheduler.wait_for_bindings(10.0)
+        runner.scheduler.explainer.drain(60.0)
         yield {"runner": runner, "before": before, "after": series(),
                "spans": TRACER.spans(),
                "threads": {t.name for t in threading.enumerate()}}
@@ -256,7 +271,7 @@ def test_span_and_informer_series_cover_the_served_run(served):
     assert events >= 40
     assert grew('scheduler_informer_handler_seconds_total{resource="pods"}') \
         > 0.0
-    assert grew("scheduler_queue_wait_seconds_count") == 40
+    assert grew("scheduler_queue_wait_seconds_count") == 40 + STUCK
     # the e2e SLI saw every pod once, from a first stamp that was there
     assert grew("scheduler_e2e_scheduling_duration_seconds_count") == 40
     assert grew("scheduler_e2e_scheduling_duration_seconds_sum") > 0.0
