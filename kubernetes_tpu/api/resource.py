@@ -12,6 +12,7 @@ float of the *base* value; callers scale cpu by 1000 via ``to_milli``.
 
 from __future__ import annotations
 
+import functools
 import re
 from decimal import Decimal
 
@@ -77,8 +78,19 @@ def to_bytes(value) -> int:
 MILLI_RESOURCES = frozenset({"cpu"})
 
 
+@functools.lru_cache(maxsize=4096)
+def _canonical_text(milli: bool, text: str) -> int:
+    return to_milli(text) if milli else to_bytes(text)
+
+
 def canonical(resource: str, value) -> int:
-    """Canonical integer amount for ``resource`` (milli for cpu, base otherwise)."""
+    """Canonical integer amount for ``resource`` (milli for cpu, base otherwise).
+
+    A quantity that comes as text is parsed once a spelling: a workload's
+    replicas ask for the same few quantities ("250m", "512Mi"), and the
+    exact Decimal parse is the larger part of ``Pod.resource_requests``."""
+    if type(value) is str:
+        return _canonical_text(resource in MILLI_RESOURCES, value)
     if resource in MILLI_RESOURCES:
         return to_milli(value)
     return to_bytes(value)

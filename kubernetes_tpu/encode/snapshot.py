@@ -139,6 +139,10 @@ _ROW_GROUPS = {
         "maxskew", "hard", "min_domains", "honor_affinity", "honor_taints")),
 }
 
+# entries the template store holds before it drops its oldest: an entry is
+# one compiled record and one row pack (two to twenty small arrays)
+_TEMPLATE_CAP = 4096
+
 
 class TermSet(struct.PyTreeNode):
     """Compiled node-selector terms: OR over terms, AND over exprs within a term.
@@ -382,16 +386,27 @@ _ENCODERS: "weakref.WeakSet[SnapshotEncoder]" = weakref.WeakSet()
 
 
 @REGISTRY.collector
-def _row_group_lines() -> list[str]:
+def _encoder_counter_lines() -> list[str]:
     """Constraint groups built into row packs against those left to the
-    batch arrays' defaults — the two plain integers every encoder keeps."""
+    batch arrays' defaults, and what the template store did with the pods
+    offered to it — plain integers every encoder keeps."""
     encoders = list(_ENCODERS)
     return series_lines(
         "scheduler_encode_row_groups_total", "counter",
         "Constraint groups of pod row packs: built as arrays because the "
         "pod populates them, or left to the batch default", "kind",
         {"built": sum(e.row_groups_built for e in encoders),
-         "default": sum(e.row_groups_default for e in encoders)})
+         "default": sum(e.row_groups_default for e in encoders)}) + series_lines(
+        "scheduler_encode_pod_template_total", "counter",
+        "Pods offered to the encoder's template store: compiled record and "
+        "row pack reused from an earlier pod equal under the template key, "
+        "built for a key or signature seen first, or not offered to it "
+        "(volumes, cache_rows=False, the encode lock busy at event time)",
+        "result",
+        {"hit": sum(e.pod_template_hits for e in encoders),
+         "miss": sum(e.pod_template_misses for e in encoders),
+         "bypass": sum(e.pod_template_bypass + e.pod_template_lock_busy
+                       for e in encoders)})
 
 
 class SnapshotEncoder:
@@ -426,9 +441,10 @@ class SnapshotEncoder:
         self.value_headroom = 0
         self.ns_headroom = 0
         # informer-event-time pod compile cache (precompile_pod): key ->
-        # [pod object, epoch, compiled record, row sig, row pack]. Hits are
-        # validated by OBJECT IDENTITY (informers build a fresh Pod per
-        # event, so a new version never aliases a cached one) and by the
+        # [pod object, epoch, compiled record, row sig, row pack, template
+        # entry]. Hits are validated by OBJECT IDENTITY (informers build a
+        # fresh Pod per event, so a new version never aliases a cached one)
+        # and by the
         # catalog epoch below — any volume/namespace/DRA catalog change
         # invalidates every record. The row pack is the pod's PRE-FILLED
         # numpy rows at the current bucket signature: encode_pods then
@@ -459,13 +475,36 @@ class SnapshotEncoder:
         self._row_widths: dict[str, int] = {}
         self._row_sig: Optional[tuple] = None
         self._row_env: Optional[tuple] = None  # (resources, K, NSB, widths)
-        self.pod_rows_stacked = 0  # rows bulk-assembled from prebuilt packs
-        self.pod_rows_filled = 0   # rows built by the per-pod fill loop
-        # constraint groups of _ROW_GROUPS built into row packs / left to
-        # the batch arrays' defaults, bumped once a pack (read at exposition
-        # by _row_group_lines; both writers hold the cache's encode lock)
+        self.pod_rows_stacked = 0  # rows whose pack the pod's record held
+        # rows whose pack encode_pods made: built (_build_rows), or copied
+        # from the pod's template
+        self.pod_rows_filled = 0
+        # constraint groups of _ROW_GROUPS held by row packs / left to
+        # the batch arrays' defaults, bumped once a pack made, built or
+        # copied from a template (read at exposition by _encoder_counter_lines;
+        # both writers hold the cache's encode lock)
         self.row_groups_built = 0
         self.row_groups_default = 0
+        # Template store: a workload's replicas differ in name, uid and
+        # perhaps requests, none of which the compile or the pack (but for
+        # its ``requests`` vector) reads. template key (``_template_key``)
+        # -> [compiled record without ``pod``, row sig, row pack without
+        # ``requests``, constraint groups in that pack]; insertion-ordered:
+        # a hit moves its entry to the young end and a full store drops the
+        # oldest. The catalog epoch is part of the key, so records of an
+        # older epoch are never found again and age out. Read and written
+        # under the cache's encode lock like ``_pod_cache``'s writers.
+        self._templates: dict[tuple, list] = {}
+        # pods offered (``precompile_pod``, ``encode_pods``' miss path): hit
+        # = neither ``_compile_pod`` nor ``_build_rows`` ran for the pod
+        self.pod_template_hits = 0
+        self.pod_template_misses = 0
+        self.pod_template_bypass = 0  # volumes, cache_rows=False
+        # the informer found the encode lock busy: written by
+        # ``SchedulerCache.precompile_pod`` WITHOUT that lock (under a small
+        # one of its own), so it is an integer of its own and not a second
+        # writer of the one above
+        self.pod_template_lock_busy = 0
         _ENCODERS.add(self)
 
     def set_volumes(self, catalog) -> None:
@@ -1000,8 +1039,10 @@ class SnapshotEncoder:
             reqs.update(self._dra.pod_demands(p))
         return reqs
 
-    def _request_vector(self, p: Pod, resources: list[str]) -> np.ndarray:
-        reqs = self._effective_requests(p)
+    def _request_vector(self, p: Pod, resources: list[str],
+                        reqs: Optional[dict] = None) -> np.ndarray:
+        if reqs is None:
+            reqs = self._effective_requests(p)
         vec = np.zeros(len(resources), np.int32)
         for r_idx, r in enumerate(resources):
             if r in reqs:
@@ -1182,11 +1223,18 @@ class SnapshotEncoder:
 
     def _compile_pod(self, p: Pod) -> dict:
         """Host-side compile of ONE pod: selectors/affinity terms to int-set
-        tables, tolerations/ports/images interned. One of the two per-pod
-        halves of ``encode_pods`` (the other is the row pack,
+        tables, tolerations/ports/images interned. One of the two halves of
+        ``encode_pods``' per-pod work (the other is the row pack,
         ``_build_rows``); it only reads the intern tables (append-only) and
-        the volume/namespace/DRA catalogs, so it can run at informer-event
-        time (``precompile_pod``) instead of on the drain hot path."""
+        the volume/namespace catalogs, so it can run at informer-event
+        time (``precompile_pod``) instead of on the drain hot path. It runs
+        once a TEMPLATE, not once a pod: of the pod it reads what
+        ``_template_key`` holds and nothing else (a volume pod's compile
+        also reads the volume catalog, so such a pod has no template), and
+        every later pod equal under that key takes a shallow copy of the
+        record (``_template_record``). A field read here that the key does
+        not hold would hand one pod's answer to another:
+        tests/test_pod_template_reuse.py mutates each in turn."""
         aff = p.spec.affinity
         na = aff.node_affinity if aff else None
         req_pairs = [(t, 1.0) for t in (na.required if na else [])]
@@ -1263,49 +1311,158 @@ class SnapshotEncoder:
                                                  (0, 0, 0)):
                 vol_terms.append((g_idx, exprs))
         vol_rwo = [self.pv_names.intern(n) for n in vinfo.rwo_pv_names]
+        # what this pod asks of each batch-derived bucket dim, in _ROW_DIMS
+        # order: part of the record, so a batch's widths are a maximum over
+        # its distinct records and not a pass over every pod a dim
+        def widest(expr_lists):
+            """(most expressions a term has, most values an expression)"""
+            x = vv = 0
+            for exprs in expr_lists:
+                x = max(x, len(exprs))
+                for (_, _, v, _) in exprs:
+                    vv = max(vv, len(v))
+            return x, vv
+
+        X, VV = widest([e for _, e in req_terms + pref_terms + vol_terms])
+        AX, AV = widest([t[2] for t in aff_req + anti_req + paff + spreads])
+        widths = (
+            len(req_terms), len(pref_terms), len(vol_terms),
+            len(vinfo.groups), len(vol_rwo), X, VV,
+            len(sel), len(tols), len(ports), len(images), len(aff_req),
+            len(anti_req), len(paff), len(spreads), AX, AV)
         return dict(
             pod=p, req_terms=req_terms, pref_terms=pref_terms, sel=sel,
             tols=tols, ports=ports, images=images, labels=labels, ns=own_ns,
             aff_req=aff_req, anti_req=anti_req, paff=paff, spreads=spreads,
             vol_terms=vol_terms, vol_groups=len(vinfo.groups),
-            vol_rwo=vol_rwo, attach_req=vinfo.attach_count,
+            vol_rwo=vol_rwo, attach_req=vinfo.attach_count, widths=widths,
         )
 
+    def _template_key(self, p: Pod, epoch: tuple) -> tuple:
+        """Everything ``_compile_pod`` and ``_build_rows`` read from a
+        volume-less pod, but for its requests: namespace, labels, priority,
+        the containers' images and ports, and — only where one is set —
+        affinity, tolerations, spread constraints, nodeSelector and
+        resource claims, as the ``repr`` of their dataclasses (every field
+        of every nested term, whatever the compile reads of it). Not the
+        name, uid or resourceVersion, not ``spec.nodeName`` (``encode_pods``
+        reads it live), not the requests (the pack's ``requests`` vector is
+        each pod's own), and never an owner reference or
+        ``pod-template-hash``: a webhook may have mutated one replica. With
+        the pod's catalog ``epoch``, since namespace resolution of a term
+        is frozen in the record. Equal keys mean equal compile inputs; two
+        equal pods whose labels come in another order only miss. ~2 us
+        for a pod without constraints, ~6 with an affinity term, against
+        the 30-36 a hit saves (my CPU count, PR 38)."""
+        md, spec = p.metadata, p.spec
+        cs = spec.containers
+        if len(cs) == 1 and not cs[0].ports:
+            containers = cs[0].image
+        else:
+            containers = repr([(c.image, c.ports) for c in cs])
+        rest = None
+        if (spec.affinity is not None or spec.tolerations
+                or spec.topology_spread_constraints or spec.node_selector
+                or spec.resource_claims):
+            rest = repr((spec.affinity, spec.tolerations,
+                         spec.topology_spread_constraints,
+                         spec.node_selector, spec.resource_claims))
+        return (epoch, md.namespace, tuple(md.labels.items()),
+                spec.priority, containers, rest)
+
+    def _template_record(self, p: Pod, epoch: tuple) -> tuple:
+        """-> (template entry, the pod's compiled record, compiled here?).
+        The record of a pod whose key the store holds is a shallow copy of
+        the template's with ``pod=p``: the compiled tables are shared by
+        reference and only read from here on."""
+        key = self._template_key(p, epoch)
+        tmpl = self._templates.pop(key, None)
+        if tmpl is None:
+            c = self._compile_pod(p)
+            if len(self._templates) >= _TEMPLATE_CAP:
+                del self._templates[next(iter(self._templates))]
+            rec = dict(c)
+            del rec["pod"]
+            tmpl = [rec, None, None, 0]
+            self._templates[key] = tmpl
+            return tmpl, c, True
+        self._templates[key] = tmpl  # youngest again
+        return tmpl, {**tmpl[0], "pod": p}, False
+
+    def _template_pack(self, tmpl: list, c: dict, sig: tuple,
+                       resources: list[str], K: int, NSB: int, w: dict,
+                       reqs: Optional[dict] = None) -> tuple:
+        """-> (the row pack of ``c``'s pod at signature ``sig``, built
+        here?). The template's pack at that signature, copied shallowly,
+        with this pod's ``requests`` vector (from ``reqs``, its effective
+        requests, where the caller has them); its arrays are shared by
+        reference and read-only (``encode_pods`` only stacks them). The
+        first pod of a template, and the first after a promotion of the
+        signature, builds the pack (``_build_rows``, IndexError and all)
+        and leaves it to the rest."""
+        if tmpl[1] == sig:
+            pk = dict(tmpl[2])
+            pk["requests"] = self._request_vector(c["pod"], resources, reqs)
+            # a pack handed out holds its groups whoever built them:
+            # encode_pods copies them a pod all the same
+            self.row_groups_built += tmpl[3]
+            self.row_groups_default += len(_ROW_GROUPS) - tmpl[3]
+            return pk, False
+        groups = self.row_groups_built
+        pk = self._build_rows(c, resources, K, NSB, w)
+        shared = dict(pk)
+        del shared["requests"]
+        for v in shared.values():
+            if isinstance(v, np.ndarray):
+                v.flags.writeable = False
+        tmpl[1:] = sig, shared, self.row_groups_built - groups
+        return pk, True
+
     def precompile_pod(self, p: Pod) -> bool:
-        """Compile a pod's encode record AND its row pack AHEAD of
+        """Give a pod its encode record AND its row pack AHEAD of
         batch-encode time — the informer layer calls this per watch event,
         so when the drain pops the pod, ``encode_pods`` stacks the pack's
         always-present fields and copies the few constraint groups it holds
         (see sched/cache.py precompile_pod for the locking discipline).
-        This MOVES the compile and the pack's allocations to the informer's
-        thread; it does not make them free. Both threads run on one
-        interpreter, so a pod costs the sum of the two halves wherever they
-        run — which is why a pack holds only what the pod populates
-        (``_build_rows``).
+        Both threads run on one interpreter, so a pod costs the sum of what
+        the two do for it wherever that runs — which is why a pack holds
+        only what the pod populates (``_build_rows``) and why the compile
+        and the pack are made once a TEMPLATE: the first pod of a key
+        (``_template_key``) at a row signature pays ``_compile_pod`` and
+        ``_build_rows``; every later one pays the key, two shallow copies
+        and its own ``requests`` vector, and is counted a ``hit`` of
+        ``scheduler_encode_pod_template_total``.
 
-        Volume-carrying pods are skipped: their compile reads catalog state
-        (``_rwop_in_use``) that every cluster encode rewrites. Returns True
-        when the record was cached."""
+        Volume-carrying pods are skipped (``bypass``): their compile reads
+        catalog state (``_rwop_in_use``) that every cluster encode rewrites.
+        Returns True when the record was cached."""
         if p.spec.volumes:
+            self.pod_template_bypass += 1
             return False
         if len(self._pod_cache) >= self._pod_cache_max:
             self._pod_cache.clear()  # backstop; steady state evicts per key
         epoch = self._epoch_for(p)
-        c = self._compile_pod(p)
+        tmpl, c, built = self._template_record(p, epoch)
         sig = pack = None
         if self._row_sig is not None:
             resources, K, NSB, w = self._row_env
-            res_index = {r: i for i, r in enumerate(resources)}
-            if all(r in res_index for r in self._effective_requests(p)):
+            reqs = self._effective_requests(p)
+            if all(r in resources for r in reqs):
                 try:
-                    pack = self._build_rows(c, resources, K, NSB, w)
+                    pack, built_pack = self._template_pack(
+                        tmpl, c, self._row_sig, resources, K, NSB, w, reqs)
                     sig = self._row_sig
+                    built = built or built_pack
                 except IndexError:
                     # the pod outgrows the current buckets (wider terms, a
                     # key past K, ...): encode_pods promotes the signature
                     # when this pod actually pops, and fills its rows then
-                    pack = None
-        self._pod_cache[p.key] = [p, epoch, c, sig, pack]
+                    pack, built = None, True
+        if built:
+            self.pod_template_misses += 1
+        else:
+            self.pod_template_hits += 1
+        self._pod_cache[p.key] = [p, epoch, c, sig, pack, tmpl]
         return True
 
     def pod_cache_discard(self, key: str) -> None:
@@ -1337,6 +1494,11 @@ class SnapshotEncoder:
         # object or any catalog change (volumes/namespaces/DRA) misses.
         compiled = []
         entries: list[Optional[list]] = []  # live cache record per pod
+        # positions of the pods _compile_pod ran for in this call (the
+        # first of a template among cold pods and pods the informer skipped
+        # because the encode lock was busy): each is counted a miss of the
+        # template store once its pack is settled, in the second pass
+        fresh: set[int] = set()
         for p in pods:
             ent = self._pod_cache.get(p.key)
             if (ent is not None and ent[0] is p
@@ -1349,8 +1511,6 @@ class SnapshotEncoder:
             # the compile (informer threads bump the epoch without the
             # encode lock) must invalidate this record, not get tagged on it
             epoch = self._epoch_for(p)
-            c = self._compile_pod(p)
-            compiled.append(c)
             self.pod_cache_misses += 1
             ent = None
             if cache_rows and not p.spec.volumes:
@@ -1358,44 +1518,22 @@ class SnapshotEncoder:
                 # here — cache so the retry encode is stack-only too
                 if len(self._pod_cache) >= self._pod_cache_max:
                     self._pod_cache.clear()
-                ent = [p, epoch, c, None, None]
+                tmpl, c, compiled_here = self._template_record(p, epoch)
+                if compiled_here:
+                    fresh.add(len(compiled))
+                ent = [p, epoch, c, None, None, tmpl]
                 self._pod_cache[p.key] = ent
+            else:
+                c = self._compile_pod(p)
+                self.pod_template_bypass += 1
+            compiled.append(c)
             entries.append(ent)
 
         K = next_bucket(len(self.keys), minimum=1)
-
-        def _bucket(fn, minimum=0):
-            return next_bucket(max((fn(c) for c in compiled), default=0), minimum=minimum)
-
-        w = {}
-        w["TREQ"] = _bucket(lambda c: len(c["req_terms"]))
-        w["TPREF"] = _bucket(lambda c: len(c["pref_terms"]))
-        w["VT"] = _bucket(lambda c: len(c["vol_terms"]))
-        w["VG"] = _bucket(lambda c: c["vol_groups"])
-        w["VB"] = _bucket(lambda c: len(c["vol_rwo"]))
-        w["X"] = _bucket(lambda c: max((len(e) for _, e in c["req_terms"] + c["pref_terms"]
-                                        + c["vol_terms"]), default=0))
-        w["VV"] = _bucket(lambda c: max((len(v) for _, ex in c["req_terms"] + c["pref_terms"]
-                                         + c["vol_terms"]
-                                         for (_, _, v, _) in ex), default=0))
-        w["S"] = _bucket(lambda c: len(c["sel"]))
-        w["TOL"] = _bucket(lambda c: len(c["tols"]))
-        w["PP"] = _bucket(lambda c: len(c["ports"]))
-        w["CI"] = _bucket(lambda c: len(c["images"]))
-        w["AT"] = _bucket(lambda c: len(c["aff_req"]))
-        w["BT"] = _bucket(lambda c: len(c["anti_req"]))
-        w["CT"] = _bucket(lambda c: len(c["paff"]))
-        w["SC"] = _bucket(lambda c: len(c["spreads"]))
-        AX = _bucket(lambda c: max((len(e) for (_, _, e, _) in c["aff_req"] + c["anti_req"]), default=0))
-        AX = max(AX, _bucket(lambda c: max((len(e) for (_, _, e, _, _) in c["paff"]), default=0)))
-        AX = max(AX, _bucket(lambda c: max((len(t[2]) for t in c["spreads"]), default=0)))
-        AV = _bucket(lambda c: max((len(v) for (_, _, e, _) in c["aff_req"] + c["anti_req"]
-                                    for (_, _, v, _) in e), default=0))
-        AV = max(AV, _bucket(lambda c: max((len(v) for (_, _, e, _, _) in c["paff"]
-                                            for (_, _, v, _) in e), default=0)))
-        AV = max(AV, _bucket(lambda c: max((len(v) for t in c["spreads"]
-                                            for (_, _, v, _) in t[2]), default=0)))
-        w["AX"], w["AV"] = AX, AV
+        # a template's replicas share one ``widths`` tuple: the set is small
+        asked = {c["widths"] for c in compiled}
+        w = {k: next_bucket(max((a[i] for a in asked), default=0))
+             for i, k in enumerate(_ROW_DIMS)}
         # sticky promotion: widths never shrink across encodes, so a pod's
         # prebuilt row pack stays valid batch to batch (padding is inert
         # behind validity flags; stable widths also mean stable compiled
@@ -1413,20 +1551,29 @@ class SnapshotEncoder:
 
         # Second pass: one row pack per pod — PREBUILT at informer-event
         # time when the signature matches (the steady state: this thread
-        # then only assembles), built here otherwise and cached back so
+        # then only assembles), made here otherwise — from the pod's
+        # template where it has one, so a promotion of the signature
+        # rebuilds once a template and not once a pod — and cached back so
         # failure re-pops stack too.
         packs = []
         forced = []
         image_bytes_v = []
-        for (c, ent) in zip(compiled, entries):
+        for i, (c, ent) in enumerate(zip(compiled, entries)):
             if ent is not None and ent[3] == sig and ent[4] is not None:
                 packs.append(ent[4])
                 self.pod_rows_stacked += 1
             else:
-                pk = self._build_rows(c, meta.resources, K, NSB, w)
                 self.pod_rows_filled += 1
-                if ent is not None:
+                if ent is None:  # counted a bypass in the first pass
+                    pk = self._build_rows(c, meta.resources, K, NSB, w)
+                else:
+                    pk, built = self._template_pack(
+                        ent[5], c, sig, meta.resources, K, NSB, w)
                     ent[3], ent[4] = sig, pk
+                    if built or i in fresh:
+                        self.pod_template_misses += 1
+                    else:
+                        self.pod_template_hits += 1
                 packs.append(pk)
             p: Pod = c["pod"]
             # scalars a cached pack must not freeze: node pinning reads the

@@ -363,8 +363,9 @@ ENCODE_POD_CACHE_MISSES = REGISTRY.gauge(
     "scheduler_encode_pod_cache_misses",
     "Pod rows compiled on the batch-encode hot path")
 # Row-pack batch assembly (encode/snapshot.py encode_pods): a stacked row's
-# pack arrived prebuilt (informer-time); a filled row's pack was built by
-# the loop's thread on the hot path. A healthy connected run shows stacked
+# pack arrived prebuilt (informer-time); a filled row's pack was made by
+# the loop's thread on the hot path, built or copied from the pod's template
+# (scheduler_encode_pod_template_total). A healthy connected run shows stacked
 # >> filled — which says WHERE the pack was built, not that it was free:
 # the two threads share one interpreter. What a pack costs is the groups it
 # holds, counted by scheduler_encode_row_groups_total{kind} (a collector in

@@ -28,6 +28,9 @@ class SchedulerCache:
         # snapshot() callers (scheduling loop + binder workers' volume path)
         # must not interleave delta pops/encodes on the shared encoder.
         self._encode_lock = threading.Lock()
+        # informer threads that found the encode lock busy count that on
+        # the encoder without it (a fleet runs one informer a tenant)
+        self._lock_busy_count = threading.Lock()
         self._nodes: dict[str, Node] = {}  # guarded by: self._lock
         self._pods: dict[str, Pod] = {}  # guarded by: self._lock
         self._assumed: dict[str, tuple[Pod, float]] = {}  # guarded by: self._lock
@@ -656,18 +659,24 @@ class SchedulerCache:
                                              cache_rows=cache_rows)
 
     def precompile_pod(self, pod: Pod) -> None:
-        """Informer-event-time half of the incremental encode: compile the
-        pod's encode record and build its row pack NOW (watch thread) so
-        the drain's encode_pods later only assembles. That moves the work
-        between two threads of one interpreter, it does not hide it: the
-        loop waits for the GIL while this runs, so what a pack costs here
-        is paid by the window all the same (PERF.md section 5), and a pack
-        holds only the constraint groups its pod populates. NON-BLOCKING
-        on the encode lock — if the scheduling loop is mid-encode, skipping
-        is strictly better than convoying the watch thread behind a
-        multi-hundred-ms encode (the pod simply compiles on the hot path
-        as before)."""
+        """Informer-event-time half of the incremental encode: give the pod
+        its encode record and its row pack NOW (watch thread) so the
+        drain's encode_pods later only assembles. Work done here is moved
+        between two threads of one interpreter, not hidden: the loop waits
+        for the GIL while this runs, so what it costs is paid by the window
+        all the same (PERF.md section 5). Hence a pack holds only the
+        constraint groups its pod populates, and record and pack are built
+        once a pod TEMPLATE (the encoder's template store): what still runs
+        once a pod is ``Pod.from_dict``, the template key, two shallow
+        copies, the pod's own ``requests`` vector and ``queue.add``.
+        NON-BLOCKING on the encode lock — if the scheduling loop is
+        mid-encode, skipping is strictly better than convoying the watch
+        thread behind a multi-hundred-ms encode: the pod is counted a
+        ``bypass`` of ``scheduler_encode_pod_template_total`` and meets its
+        template on the hot path instead (``encode_pods``' miss branch)."""
         if not self._encode_lock.acquire(blocking=False):
+            with self._lock_busy_count:
+                self._encoder.pod_template_lock_busy += 1
             return
         try:
             self._encoder.precompile_pod(pod)
@@ -680,10 +689,13 @@ class SchedulerCache:
         """Hit/miss counters of the pod compile cache plus the row-pack
         assembly split (benchmarks report these: a healthy connected run
         shows hits >> misses and rows_stacked >> rows_filled). A stacked
-        row was built on the informer's thread rather than the loop's —
-        moved, not saved; how much a pack holds is the encoder's
-        ``row_groups_built`` / ``row_groups_default`` pair
-        (``scheduler_encode_row_groups_total``)."""
+        row's pack was on the pod's record when the drain popped it, made
+        on the informer's thread rather than the loop's — moved, not saved.
+        What was saved is the template store's account, pods that took an
+        earlier pod's compiled record and pack against pods that built one
+        (``scheduler_encode_pod_template_total``); how much a built pack
+        holds is the encoder's ``row_groups_built`` / ``row_groups_default``
+        pair (``scheduler_encode_row_groups_total``)."""
         return {"hits": self._encoder.pod_cache_hits,
                 "misses": self._encoder.pod_cache_misses,
                 "rows_stacked": self._encoder.pod_rows_stacked,

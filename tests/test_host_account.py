@@ -383,12 +383,15 @@ def test_every_span_and_series_of_the_account_feeds_a_metric_file():
         m.name for m in REGISTRY._metrics.values()}
     assert collected and collected <= read, sorted(collected - read)
     assert "scheduler_queue_wait_seconds_sum" in read
-    # the collector's two series (sched/gcpolicy.py), each by its own file
+    # the collector's two series (sched/gcpolicy.py) and the encoder's
+    # template store's (encode/snapshot.py), each by its own file
     for series_name, metric in (
             ("scheduler_gc_pause_seconds_total",
              "gc_pause_us_per_pod_event.burst"),
             ("scheduler_gc_collections_total",
-             "gc_full_collections_per_kevent.burst")):
+             "gc_full_collections_per_kevent.burst"),
+            ("scheduler_encode_pod_template_total",
+             "pod_template_hit_share.burst")):
         assert series_name in collected
         with open(os.path.join(root, "yardstick", "layer_metrics",
                                metric + ".json")) as f:
