@@ -269,9 +269,6 @@ SCHEDULE_ATTEMPTS = REGISTRY.counter(
 ATTEMPT_DURATION = REGISTRY.histogram(
     "scheduler_scheduling_attempt_duration_seconds",
     "End-to-end scheduling attempt latency by result")
-BATCH_DURATION = REGISTRY.histogram(
-    "scheduler_gang_batch_duration_seconds",
-    "Device-side gang batch latency")
 E2E_DURATION = REGISTRY.histogram(
     "scheduler_pod_scheduling_sli_duration_seconds",
     "Pod queue-add to bound latency")
@@ -705,12 +702,20 @@ def _cpu_lines() -> list[str]:
 
 @REGISTRY.collector
 def _span_lines() -> list[str]:
-    """Blocked time by span name, from the tracer's own sums
-    (utils/tracing.Tracer.blocked_totals). Wall time and count of a span
-    are read off the ring; this is the one road CPU time takes out."""
+    """Blocked time and self time by span name, from the tracer's own sums
+    (utils/tracing.Tracer.blocked_totals, .self_totals). Wall time and
+    count of a span are read off the ring; this is the one road CPU time
+    takes out."""
     from kubernetes_tpu.utils.tracing import TRACER
+    own, own_blocked = TRACER.self_totals()
     return series_lines(
         "scheduler_span_blocked_seconds_total", "counter",
         "Wall time less the thread's CPU time inside finished spans "
         "(waiting for a transfer, a lock or the GIL), by span name",
-        "span", TRACER.blocked_totals())
+        "span", TRACER.blocked_totals()) + series_lines(
+        "scheduler_span_self_seconds_total", "counter",
+        "Wall time of finished spans less what their child spans cover, "
+        "by span name", "span", own) + series_lines(
+        "scheduler_span_self_blocked_seconds_total", "counter",
+        "Self wall time less the thread's self CPU time (the part of a "
+        "span's own time spent waiting), by span name", "span", own_blocked)

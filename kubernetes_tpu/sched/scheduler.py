@@ -28,7 +28,6 @@ from kubernetes_tpu.config.features import DEFAULT_FEATURE_GATE
 from kubernetes_tpu.config.types import SchedulerConfiguration
 from kubernetes_tpu.metrics.registry import (
     ATTEMPT_DURATION,
-    BATCH_DURATION,
     DRAIN_SHARD_MS,
     GANG_ROUNDS,
     GANG_ROUNDS_EXHAUSTED,
@@ -47,7 +46,7 @@ from kubernetes_tpu.sched.queue import SchedulingQueue
 from kubernetes_tpu.sched.resilience import DeviceCircuitBreaker
 from kubernetes_tpu.utils import sanity
 from kubernetes_tpu.utils.events import NullRecorder
-from kubernetes_tpu.utils.tracing import FLIGHT
+from kubernetes_tpu.utils.tracing import FLIGHT, TRACER
 
 _LOG = logging.getLogger(__name__)
 
@@ -325,7 +324,6 @@ class Scheduler:
         transfer again."""
         import jax
         from kubernetes_tpu.sched.staging import _tree_nbytes
-        from kubernetes_tpu.utils.tracing import TRACER
         with TRACER.span("scheduler/stage_batch", pods=n_pods,
                          path="arena" if ticket is not None else "inline"
                          ) as sp:
@@ -481,7 +479,6 @@ class Scheduler:
 
     def _resolver_loop(self, q: "queue_mod.Queue") -> None:
         import jax
-        from kubernetes_tpu.utils.tracing import TRACER
         while True:
             pend = q.get()
             if pend is None:  # poison pill from close()/restart
@@ -510,7 +507,6 @@ class Scheduler:
         backlog takes the fused drain path (one device program for many
         batches, models/gang.py drain_step) while shallow pops run the
         single-batch program."""
-        from kubernetes_tpu.utils.tracing import TRACER
         self._fold_staged_nominations()
         # The scheduling thread's time is pop_wait + cycle, end to end:
         # scheduler/cycle is the root of every span of the work below, so
@@ -596,27 +592,9 @@ class Scheduler:
         self._staged_once = False
         self._last_pop_full = len(batch) >= cap
         self._carve_plans.clear()  # plans never outlive their cycle
-        stats = self.queue.stats()
-        for q, v in stats.items():
-            QUEUE_DEPTH.set(v, {"queue": q})
-        # Slot headroom = everything still pending (this batch + queued):
-        # the snapshot reserves that many existing-pod slots so the whole
-        # drain binds via incremental patches with stable tensor shapes.
-        headroom = len(batch) + sum(stats.values())
-
-        by_profile: dict[str, list[tuple[Pod, int]]] = {}
-        for pod, attempts in batch:
-            by_profile.setdefault(pod.spec.scheduler_name, []).append((pod, attempts))
-
+        with TRACER.span("scheduler/batch_head", pods=len(batch)):
+            headroom, level, plan = self._batch_head(batch)
         n_bound = n_landed = 0
-        serial = not self.features.enabled("TPUBatchScheduling")
-        # degrade-don't-die routing: the breaker picks the level this cycle
-        # attempts — the current degraded mode, or one better when the
-        # half-open window opened (the probe). "mesh"/"single" still run
-        # the tensor programs (mesh installed or dropped to match);
-        # "oracle" bypasses the device entirely.
-        level = self.breaker.attempt_level()
-        self._attempt_level = level
         if level != "oracle":
             want = self._configured_mesh if level == "mesh" else None
             if want is not self._mesh:
@@ -629,26 +607,12 @@ class Scheduler:
             # oracle mode dispatches nothing new; in-flight drains from
             # before the degrade must not linger (bounded waits inside)
             n_landed += self._resolve_pending()
-        for sched_name, items in by_profile.items():
-            profile = self.cfg.profile_for(sched_name)
-            if profile is None:
-                # Not ours. The informer layer normally filters these out; if
-                # one slips through, park it rather than losing it.
-                for pod, attempts in items:
-                    self.queue.park_unschedulable(pod, attempts)
-                continue
+        serial = not self.features.enabled("TPUBatchScheduling")
+        for profile, items, slice_items in plan:
             if level == "oracle":
                 n_bound += self._schedule_oracle(profile, items)
                 continue
-            # slice-shaped gangs never ride the drain path: the carve is a
-            # group-path stage (_schedule_group), and a resident drain would
-            # place members as independent pods — feasible but not
-            # contiguous. Split them out and route them per gang.
-            slice_items = [it for it in items
-                           if self._slice_shape_of(it[0]) is not None]
             if slice_items:
-                items = [it for it in items
-                         if self._slice_shape_of(it[0]) is None]
                 for chunk in self._slice_chunks(slice_items):
                     n_bound += self._schedule_group(profile, chunk, headroom)
             if not items:
@@ -661,6 +625,53 @@ class Scheduler:
                 for chunk in self._tenant_chunks(items, self.cfg.batch_size):
                     n_bound += self._schedule_group(profile, chunk, headroom)
         return n_landed + n_bound
+
+    def _batch_head(self, batch) -> tuple[int, str, list]:
+        """A cycle's bookkeeping before any pod is scheduled (span
+        ``scheduler/batch_head``): the queue gauges, the slot headroom, the
+        breaker's level and the pop split by profile. -> (headroom, level,
+        [(profile, items, slice items)])."""
+        stats = self.queue.stats()
+        for q, v in stats.items():
+            QUEUE_DEPTH.set(v, {"queue": q})
+        # Slot headroom = everything still pending (this batch + queued):
+        # the snapshot reserves that many existing-pod slots so the whole
+        # drain binds via incremental patches with stable tensor shapes.
+        headroom = len(batch) + sum(stats.values())
+
+        by_profile: dict[str, list[tuple[Pod, int]]] = {}
+        for pod, attempts in batch:
+            by_profile.setdefault(pod.spec.scheduler_name, []).append((pod, attempts))
+
+        # degrade-don't-die routing: the breaker picks the level this cycle
+        # attempts — the current degraded mode, or one better when the
+        # half-open window opened (the probe). "mesh"/"single" still run
+        # the tensor programs (mesh installed or dropped to match);
+        # "oracle" bypasses the device entirely.
+        level = self.breaker.attempt_level()
+        self._attempt_level = level
+        plan = []
+        for sched_name, items in by_profile.items():
+            profile = self.cfg.profile_for(sched_name)
+            if profile is None:
+                # Not ours. The informer layer normally filters these out; if
+                # one slips through, park it rather than losing it.
+                for pod, attempts in items:
+                    self.queue.park_unschedulable(pod, attempts)
+                continue
+            slice_items = []
+            if level != "oracle":
+                # slice-shaped gangs never ride the drain path: the carve is
+                # a group-path stage (_schedule_group), and a resident drain
+                # would place members as independent pods — feasible but not
+                # contiguous. Split them out and route them per gang.
+                slice_items = [it for it in items
+                               if self._slice_shape_of(it[0]) is not None]
+                if slice_items:
+                    items = [it for it in items
+                             if self._slice_shape_of(it[0]) is None]
+            plan.append((profile, items, slice_items))
+        return headroom, level, plan
 
     def _tenant_chunks(self, items: list, P: int) -> list[list]:
         """Split a popped batch into device chunks of up to ``P`` pods.
@@ -978,7 +989,6 @@ class Scheduler:
                 "carves": stats}
 
     def _schedule_group(self, profile, items, slot_headroom: int = 0) -> int:
-        from kubernetes_tpu.utils.tracing import TRACER
         t0 = time.time()
         pods = [p for p, _ in items]
         with TRACER.span("scheduler/snapshot", pods=len(pods)):
@@ -1060,9 +1070,8 @@ class Scheduler:
         oot = (None if profile.out_of_tree is None
                else set(profile.out_of_tree))
         plugins = self.registry.tensor_plugins(oot)
-        with BATCH_DURATION.time(), TRACER.span(
-                "scheduler/gang_schedule", pods=len(pods),
-                nodes=len(nodes)) as sp_gang:
+        with TRACER.span("scheduler/gang_schedule", pods=len(pods),
+                         nodes=len(nodes)) as sp_gang:
             try:
                 assignment, rounds = gang_schedule(
                     ct, pb, seed=self.cfg.seed,
@@ -1174,7 +1183,6 @@ class Scheduler:
         -> (ctx, use_ctx, fused_patch, pods bound by drains it had to
         resolve first, delta-log entries looked at). ``use_ctx`` False
         means the caller rebuilds from a host snapshot."""
-        from kubernetes_tpu.utils.tracing import TRACER
         ctx = self._drain_ctx
         use_ctx = False
         fused_patch = None  # churn deltas riding THIS dispatch (fused fold)
@@ -1308,7 +1316,6 @@ class Scheduler:
         from kubernetes_tpu.models.gang import (
             apply_ctx_patch, batch_shapes, build_drain_context, drain_step,
             drain_widths_fit, pad_batch_to, unify_batches)
-        from kubernetes_tpu.utils.tracing import TRACER
         t0 = time.time()
         pods = [p for p, _ in items]
         batch_keys = {p.key for p in pods}
@@ -1351,8 +1358,10 @@ class Scheduler:
                 meta, min_p=P,
                 cache_rows=not profile.added_affinity) for c in chunks]
         if FLIGHT.enabled:
-            for pod, _a in items:
-                FLIGHT.record(pod.key, "drain_fill", span=sp_enc)
+            with TRACER.span("scheduler/flight", stage="drain_fill",
+                             pods=len(items)):
+                for pod, _a in items:
+                    FLIGHT.record(pod.key, "drain_fill", span=sp_enc)
         # pad to the fixed drain width with all-invalid batches (their pods
         # propose nothing; the scan converges them in one dead round)
         B = max(1, self.cfg.max_drain_batches)
@@ -1522,8 +1531,10 @@ class Scheduler:
         if parity_cap is not None:
             pend["parity"] = parity_cap
         if FLIGHT.enabled:
-            for pod, _a in items:
-                FLIGHT.record(pod.key, "dispatch", span=sp_disp)
+            with TRACER.span("scheduler/flight", stage="dispatch",
+                             pods=len(items)):
+                for pod, _a in items:
+                    FLIGHT.record(pod.key, "dispatch", span=sp_disp)
         self._submit_resolve(pend)
         self._pending.append(pend)
         PIPELINE_DEPTH.observe(len(self._pending))
@@ -1536,6 +1547,11 @@ class Scheduler:
         n_prev += self._resolve_ready()
         while len(self._pending) > max(1, self.cfg.pipeline_depth):
             n_prev += self._resolve_one()
+        # the staged batch's device buffers are released here, where the
+        # return would release them: the drain just dispatched may still
+        # be reading them, and the runtime holds the release until then
+        with TRACER.span("scheduler/stage_release", pods=len(pods)):
+            del pb_staged
         return n_prev
 
     def _ctx_reason(self, why: str):
@@ -1565,12 +1581,10 @@ class Scheduler:
         PIPELINE_INFLIGHT.set(len(self._pending))
         import jax
         import numpy as np
-        from kubernetes_tpu.utils.tracing import TRACER
         t_wait = time.time()
         fetch_failed = False
-        with BATCH_DURATION.time(), TRACER.span(
-                "scheduler/resolve_wait",
-                depth=len(self._pending) + 1) as sp_res:
+        with TRACER.span("scheduler/resolve_wait",
+                         depth=len(self._pending) + 1) as sp_res:
             # fill_bound is maintained purely by the dispatch-side
             # reservation arithmetic (adjusted below); the device fill stays
             # resident as ctx["fill_dev"] and is never fetched
@@ -1604,63 +1618,70 @@ class Scheduler:
                                    "requeueing the drain's pods")
             if not fetch_failed:
                 assignments, rounds = res
-        if fetch_failed:
-            # the drain's winners are lost: requeue every pod (the cache
-            # never assumed them), release the fold reservation, and taint
-            # the resident context — the device-side fold state is unknown
-            self.breaker.fail(pend.get("level", self._attempt_level))
-            ctx = pend["ctx"]
+        # the loop's bookkeeping between the fetch and the apply: the
+        # breaker, the gauges, the rounds, the nominations to re-check
+        with TRACER.span("scheduler/resolve_head",
+                         depth=len(self._pending) + 1):
+            if fetch_failed:
+                # the drain's winners are lost: requeue every pod (the
+                # cache never assumed them), release the fold reservation,
+                # and taint the resident context — the device-side fold
+                # state is unknown
+                self.breaker.fail(pend.get("level", self._attempt_level))
+                ctx = pend["ctx"]
+                pend_count = sum(len(c) for c in pend["chunks"])
+                if self._drain_ctx is ctx:
+                    ctx["cs"].tainted = True
+                    ctx["fill_bound"] -= pend_count
+                for chunk in pend["chunks"]:
+                    for pod, attempts in chunk:
+                        if not self.cache.is_bound(pod.key):
+                            self.queue.add_unschedulable(pod, attempts + 1)
+                SCHEDULE_ATTEMPTS.inc({"result": "error"}, by=pend_count)
+                return 0
+            # results landed: the device executed this drain end to end —
+            # the breaker's success signal for the fused path (dispatch
+            # alone is async and proves nothing). Attributed to the level
+            # and time the drain was DISPATCHED at, not this cycle's.
+            self.breaker.succeed(pend.get("level", self._attempt_level),
+                                 dispatched_at=pend.get("dispatched_at"))
+            wait_ms = round((time.time() - t_wait) * 1000.0, 3)
+            RESOLVE_BYTES.set(np.asarray(assignments).nbytes
+                              + np.asarray(rounds).nbytes)
+            # the drain is ONE SPMD program — every shard runs it lock-step,
+            # so there is exactly one honest wall time (per-shard labels
+            # would duplicate it N ways and leave stale series after a
+            # reshape); stragglers surface in collective time, which this
+            # number includes
+            DRAIN_SHARD_MS.set(wait_ms)
+            ctx, meta, profile = pend["ctx"], pend["meta"], pend["profile"]
+            active = self._drain_ctx is ctx
             pend_count = sum(len(c) for c in pend["chunks"])
-            if self._drain_ctx is ctx:
-                ctx["cs"].tainted = True
-                ctx["fill_bound"] -= pend_count
-            for chunk in pend["chunks"]:
-                for pod, attempts in chunk:
-                    if not self.cache.is_bound(pod.key):
-                        self.queue.add_unschedulable(pod, attempts + 1)
-            SCHEDULE_ATTEMPTS.inc({"result": "error"}, by=pend_count)
-            return 0
-        # results landed: the device executed this drain end to end — the
-        # breaker's success signal for the fused path (dispatch alone is
-        # async and proves nothing). Attributed to the level and time the
-        # drain was DISPATCHED at, not this cycle's.
-        self.breaker.succeed(pend.get("level", self._attempt_level),
-                             dispatched_at=pend.get("dispatched_at"))
-        wait_ms = round((time.time() - t_wait) * 1000.0, 3)
-        RESOLVE_BYTES.set(np.asarray(assignments).nbytes
-                          + np.asarray(rounds).nbytes)
-        # the drain is ONE SPMD program — every shard runs it lock-step, so
-        # there is exactly one honest wall time (per-shard labels would
-        # duplicate it N ways and leave stale series after a reshape);
-        # stragglers surface in collective time, which this number includes
-        DRAIN_SHARD_MS.set(wait_ms)
-        ctx, meta, profile = pend["ctx"], pend["meta"], pend["profile"]
-        active = self._drain_ctx is ctx
-        pend_count = sum(len(c) for c in pend["chunks"])
-        # one observation a batch that held pods (the drain's padding
-        # batches converge in one dead round and are not a gang batch)
-        GANG_ROUNDS.observe_many(
-            int(r) for chunk, r in zip(pend["chunks"], rounds) if chunk)
-        # of those, the batches that ran every round they may and still
-        # hold a pod they did not place: out of rounds, whatever the nodes
-        GANG_ROUNDS_EXHAUSTED.inc(by=sum(
-            1 for chunk, r, assignment
-            in zip(pend["chunks"], rounds, assignments)
-            if chunk and int(r) >= self.cfg.max_gang_rounds
-            and (np.asarray(assignment)[:len(chunk)] < 0).any()))
-        # nominations that arrived while this drain was on the device (the
-        # descheduler writes them right before evicting): the dispatched
-        # program could not reserve them, so winners re-check here — same
-        # contract as _schedule_group's assume-time re-check
-        self._fold_staged_nominations()
-        fresh: dict[str, int] = {}
-        if self._nominated:
-            known = pend.get("nom_keys", set())
-            drain_keys = {pod.key for chunk in pend["chunks"]
-                          for pod, _ in chunk}
-            for k, (n, prio, _p, _ts) in self._nominated.items():
-                if k not in known and k not in drain_keys:
-                    fresh[n] = max(prio, fresh.get(n, prio))
+            # one observation a batch that held pods (the drain's padding
+            # batches converge in one dead round and are not a gang batch)
+            GANG_ROUNDS.observe_many(
+                int(r) for chunk, r in zip(pend["chunks"], rounds) if chunk)
+            # of those, the batches that ran every round they may and still
+            # hold a pod they did not place: out of rounds, whatever the
+            # nodes
+            GANG_ROUNDS_EXHAUSTED.inc(by=sum(
+                1 for chunk, r, assignment
+                in zip(pend["chunks"], rounds, assignments)
+                if chunk and int(r) >= self.cfg.max_gang_rounds
+                and (np.asarray(assignment)[:len(chunk)] < 0).any()))
+            # nominations that arrived while this drain was on the device
+            # (the descheduler writes them right before evicting): the
+            # dispatched program could not reserve them, so winners re-check
+            # here — same contract as _schedule_group's assume-time re-check
+            self._fold_staged_nominations()
+            fresh: dict[str, int] = {}
+            if self._nominated:
+                known = pend.get("nom_keys", set())
+                drain_keys = {pod.key for chunk in pend["chunks"]
+                              for pod, _ in chunk}
+                for k, (n, prio, _p, _ts) in self._nominated.items():
+                    if k not in known and k not in drain_keys:
+                        fresh[n] = max(prio, fresh.get(n, prio))
         lost_races = 0
         to_bind: list[tuple[Pod, str]] = []
         bound_rows: list[int] = []  # node index per to_bind entry
@@ -1744,10 +1765,12 @@ class Scheduler:
         with TRACER.span("scheduler/resolve_tail", bound=n_bound,
                          failed=n_unsched):
             if FLIGHT.enabled:
-                for pod, _n in to_bind:
-                    FLIGHT.record(pod.key, "resolve", span=sp_res)
-                for pod, _a in failures:
-                    FLIGHT.record(pod.key, "resolve", span=sp_res)
+                with TRACER.span("scheduler/flight", stage="resolve",
+                                 pods=n_bound + n_unsched):
+                    for pod, _n in to_bind:
+                        FLIGHT.record(pod.key, "resolve", span=sp_res)
+                    for pod, _a in failures:
+                        FLIGHT.record(pod.key, "resolve", span=sp_res)
             self._handle_failures(failures)
             # fill_bound is ADJUSTED, never overwritten: drains dispatched
             # after this one already reserved their own += len(pods) on top,
@@ -1992,7 +2015,6 @@ class Scheduler:
         A drain in which every pod was placed opens no span."""
         if not failures:
             return
-        from kubernetes_tpu.utils.tracing import TRACER
         with TRACER.span("scheduler/handle_failures", pods=len(failures)):
             preemptable: list[tuple[Pod, int]] = []
             preempt_on = self.features.enabled("PreemptionSimulation")
@@ -2307,7 +2329,6 @@ class Scheduler:
         snapshot, no re-encode, no per-wave re-staging of cluster tensors.
         Only when the context is stale/tainted does the wave fall back to
         one cache snapshot (which itself reuses the cached encoding)."""
-        from kubernetes_tpu.utils.tracing import TRACER
         resident = None
         if self._attempt_level != "oracle":
             # bound is captured BEFORE the staleness check: a foreign bind
@@ -2465,8 +2486,10 @@ class Scheduler:
 
     def _bind_bulk(self, pairs: list[tuple[Pod, str]]):
         """One API call binds the whole chunk; per-item results fan back out
-        into the same success/failure handling as _bind_one."""
-        from kubernetes_tpu.utils.tracing import TRACER
+        into the same success/failure handling as _bind_one. Three passes
+        over the chunk: every pod's result first, then the bound pods'
+        flight records, then their ``Scheduled`` events — each of the last
+        two its own span, so the binders' work after the POST is named."""
         with TRACER.span("scheduler/bind_bulk", pods=len(pairs)):
             try:
                 with TRACER.span("scheduler/bind_call", pods=len(pairs)):
@@ -2477,13 +2500,11 @@ class Scheduler:
             if len(results) != len(pairs):
                 results = list(results) + [False] * (
                     len(pairs) - len(results))
+            bound: list[tuple[Pod, str]] = []
             for (pod, node_name), ok in zip(pairs, results):
                 if ok:
                     self.cache.finish_binding(pod.key)
-                    FLIGHT.record(pod.key, "bind", node=node_name)
-                    self.recorder.event(
-                        pod, "Normal", "Scheduled",
-                        f"Successfully assigned {pod.key} to {node_name}")
+                    bound.append((pod, node_name))
                 elif ok is None:
                     # the pod vanished while its binding was in flight
                     # (e.g. a churn delete): drop the assumption quietly —
@@ -2500,6 +2521,18 @@ class Scheduler:
                         if self.cache.is_bound(pod.key):
                             self.queue.delete(pod)  # event raced the requeue
                     SCHEDULE_ATTEMPTS.inc({"result": "error"})
+            if not bound:
+                return
+            if FLIGHT.enabled:
+                with TRACER.span("scheduler/flight", stage="bind",
+                                 pods=len(bound)):
+                    for pod, node_name in bound:
+                        FLIGHT.record(pod.key, "bind", node=node_name)
+            with TRACER.span("scheduler/bind_events", pods=len(bound)):
+                for pod, node_name in bound:
+                    self.recorder.event(
+                        pod, "Normal", "Scheduled",
+                        f"Successfully assigned {pod.key} to {node_name}")
 
     def close(self, timeout: float = 5.0):
         """Stop the binding pool: poison-pill every worker and join them.

@@ -19,6 +19,7 @@ import time
 from typing import Optional
 
 from kubernetes_tpu.metrics.registry import EVENTS_DROPPED
+from kubernetes_tpu.utils.tracing import TRACER
 
 EVENT_NORMAL, EVENT_WARNING = "Normal", "Warning"
 
@@ -109,50 +110,62 @@ class EventRecorder:
                     batch.append(self._q.get_nowait())
             except queue.Empty:
                 pass
-            creates: dict[str, list] = {}
-            pending: dict[tuple, dict] = {}  # (ns, ev_name) -> queued create
+            # one span a batch, on this sink's own thread: encoding and
+            # writing what the scheduler recorded
             try:
-                for it in batch:
-                    if it is None:
-                        continue
-                    (ns, name, kind, uid, ev_name, aggregate,
-                     type_, reason, message, now) = it
-                    if aggregate:
-                        prior = pending.get((ns, ev_name))
-                        if prior is not None:
-                            # original create is in THIS batch: fold in place
-                            prior["count"] += 1
-                            prior["lastTimestamp"] = now
-                            continue
-                        try:
-                            self._write_aggregate(ns, ev_name, now)
-                            continue
-                        except Exception:  # ktpu-lint: disable=KTL002 -- compaction probe lost a race; falling through writes a fresh event instead
-                            pass  # fall through: write a fresh event
-                    pending[(ns, ev_name)] = obj = {
-                        "apiVersion": "v1", "kind": "Event",
-                        "metadata": {"name": ev_name, "namespace": ns},
-                        "involvedObject": {"kind": kind, "name": name,
-                                           "namespace": ns, "uid": uid},
-                        "type": type_, "reason": reason, "message": message,
-                        "source": {"component": self.component},
-                        "count": 1, "firstTimestamp": now,
-                        "lastTimestamp": now}
-                    creates.setdefault(ns, []).append(obj)
-                for ns, objs in creates.items():
-                    try:
-                        self.client.resource("events", ns).create_many(objs)
-                    except Exception:
-                        # best-effort: a failing client must neither raise
-                        # into the sink loop nor spin it — but every event
-                        # it eats is counted
-                        EVENTS_DROPPED.inc({"reason": "write_failed"},
-                                           by=len(objs))
+                with TRACER.span("events/flush", events=len(batch)) as sp:
+                    n_creates = self._write_batch(batch)
+                    if sp is not None:
+                        sp.attributes["creates"] = n_creates
             except Exception:
                 EVENTS_DROPPED.inc({"reason": "sink_error"}, by=len(batch))
             finally:
                 for _ in batch:
                     self._q.task_done()
+
+    def _write_batch(self, batch: list) -> int:
+        """One batch of queued events to the API: aggregates folded into
+        a create of the same batch or written as an update, creates in ONE
+        bulk call a namespace. -> the creates it sent."""
+        creates: dict[str, list] = {}
+        pending: dict[tuple, dict] = {}  # (ns, ev_name) -> queued create
+        for it in batch:
+            if it is None:
+                continue
+            (ns, name, kind, uid, ev_name, aggregate,
+             type_, reason, message, now) = it
+            if aggregate:
+                prior = pending.get((ns, ev_name))
+                if prior is not None:
+                    # original create is in THIS batch: fold in place
+                    prior["count"] += 1
+                    prior["lastTimestamp"] = now
+                    continue
+                try:
+                    self._write_aggregate(ns, ev_name, now)
+                    continue
+                except Exception:  # ktpu-lint: disable=KTL002 -- compaction probe lost a race; falling through writes a fresh event instead
+                    pass  # fall through: write a fresh event
+            pending[(ns, ev_name)] = obj = {
+                "apiVersion": "v1", "kind": "Event",
+                "metadata": {"name": ev_name, "namespace": ns},
+                "involvedObject": {"kind": kind, "name": name,
+                                   "namespace": ns, "uid": uid},
+                "type": type_, "reason": reason, "message": message,
+                "source": {"component": self.component},
+                "count": 1, "firstTimestamp": now,
+                "lastTimestamp": now}
+            creates.setdefault(ns, []).append(obj)
+        for ns, objs in creates.items():
+            try:
+                self.client.resource("events", ns).create_many(objs)
+            except Exception:
+                # best-effort: a failing client must neither raise
+                # into the sink loop nor spin it — but every event
+                # it eats is counted
+                EVENTS_DROPPED.inc({"reason": "write_failed"},
+                                   by=len(objs))
+        return sum(len(objs) for objs in creates.values())
 
     def _write_aggregate(self, ns, ev_name, now) -> None:
         ev = self.client.resource("events", ns).get(ev_name)

@@ -13,7 +13,9 @@ Two layers:
   it ran on and that thread's CPU time, so wall − CPU says how long the
   thread stood blocked (a transfer, a lock, the GIL). The blocked time is
   summed by span name beside the ring (``blocked_totals()``;
-  metrics/registry.py exposes it).
+  metrics/registry.py exposes it), and so is each span's SELF time — its
+  wall and its blocked time less what its child spans cover
+  (``self_totals()``).
   Exports OTLP/JSON (the apiserver's ``/debug/traces``) and Chrome
   trace-event JSON (``export_chrome`` — loads directly in Perfetto /
   chrome://tracing). ``Tracer.annotate`` mirrors every sampled span into
@@ -60,6 +62,10 @@ class Span:
     # set inside the span to keep it out of the ring and the totals (the
     # scheduling loop folds an idle stretch's empty waits into one span)
     discard: bool = False
+    # wall and CPU seconds of the finished spans directly inside this one
+    # (same thread); what they leave is the span's self time
+    child_wall_s: float = 0.0
+    child_cpu_s: float = 0.0
 
     @property
     def duration_ms(self) -> float:
@@ -75,7 +81,15 @@ class Span:
 class Tracer:
     """Minimal tracer: nested spans via a thread-local stack, finished spans
     collected in a RING buffer (oldest dropped first, drops counted in
-    ``dropped``; sampling via ``ratio``)."""
+    ``dropped``; sampling via ``ratio``).
+
+    Self time: a span that closes adds its wall and CPU seconds to the
+    enclosing span (the top of the same thread's stack). When that one
+    closes, self wall = wall − children's wall and self blocked =
+    max(self wall − (CPU − children's CPU), 0), summed by name beside the
+    blocked totals (``self_totals()``). A ``discard``ed span adds nothing
+    anywhere; an unsampled span is on no stack, so its time stays in its
+    parent's self time."""
 
     def __init__(self, ratio: float = 1.0, max_spans: int = 4096):
         self.ratio = ratio
@@ -90,6 +104,10 @@ class Tracer:
         # start — never reset with the ring, so a reader diffs two reads.
         # Wall time and count are not kept here: the ring's readers sum them
         self._blocked: dict[str, float] = {}  # guarded by: self._lock
+        # self wall and self blocked seconds by span name (the span less
+        # its children), kept and read like the blocked totals
+        self._self: dict[str, float] = {}  # guarded by: self._lock
+        self._self_blocked: dict[str, float] = {}  # guarded by: self._lock
         # optional name -> context manager, entered inside every sampled
         # span (sched/runner.py sets jax.profiler.TraceAnnotation)
         self.annotate: Optional[Callable[[str], Any]] = None
@@ -138,12 +156,22 @@ class Tracer:
             sp.end = time.time()
             stack.pop()
             if not sp.discard:
+                wall = sp.end - sp.start
+                if top is not None:
+                    top.child_wall_s += wall
+                    top.child_cpu_s += sp.cpu_s
+                self_wall = wall - sp.child_wall_s
+                self_blocked = max(
+                    self_wall - (sp.cpu_s - sp.child_cpu_s), 0.0)
                 with self._lock:
                     if len(self._spans) == self._spans.maxlen:
                         self.dropped += 1
                     self._spans.append(sp)
                     self._blocked[name] = (self._blocked.get(name, 0.0)
                                            + sp.blocked_s)
+                    self._self[name] = self._self.get(name, 0.0) + self_wall
+                    self._self_blocked[name] = (
+                        self._self_blocked.get(name, 0.0) + self_blocked)
 
     def spans(self, name: Optional[str] = None) -> list[Span]:
         with self._lock:
@@ -154,6 +182,14 @@ class Tracer:
         process start (``reset`` does not touch them)."""
         with self._lock:
             return dict(self._blocked)
+
+    def self_totals(self) -> tuple[dict[str, float], dict[str, float]]:
+        """({span name: self wall seconds}, {span name: self blocked
+        seconds}) over every span finished since process start: the
+        span's time less what its child spans cover (class docstring).
+        Like ``blocked_totals``, ``reset`` does not touch them."""
+        with self._lock:
+            return dict(self._self), dict(self._self_blocked)
 
     def reset(self):
         with self._lock:

@@ -42,6 +42,13 @@ SPAN_THREADS = {
     # the failure path (PR 37): only a drain that left a pod unplaced
     "scheduler/handle_failures": "scheduler-loop",
     "explain/capture": "scheduler-loop",
+    # the loop's and the binders' work that had no span (PR 39)
+    "scheduler/batch_head": "scheduler-loop",
+    "scheduler/resolve_head": "scheduler-loop",
+    "scheduler/stage_release": "scheduler-loop",
+    "scheduler/flight": ("scheduler-loop", "binder-"),
+    "scheduler/bind_events": "binder-",
+    "events/flush": "events/",
 }
 
 _SERIES = re.compile(r"^([^#\s]+)\s+(\S+)$")
@@ -198,7 +205,7 @@ def served():
         runner.scheduler.wait_for_bindings(10.0)
         runner.scheduler.explainer.drain(60.0)
         yield {"runner": runner, "before": before, "after": series(),
-               "spans": TRACER.spans(),
+               "spans": TRACER.spans(), "dropped": TRACER.dropped,
                "threads": {t.name for t in threading.enumerate()}}
     finally:
         FLIGHT.enabled = flight_was
@@ -233,6 +240,40 @@ def test_every_span_of_the_served_path_fires_on_its_thread(served):
                for sp in staged)
     assert all(0.0 <= sp.cpu_s <= (sp.end - sp.start) + 0.05
                for sp in served["spans"])
+    # the four stages of the flight recorder's per-pod loops, each its own
+    # span: three on the loop, one on the binders
+    stages = {sp.attributes["stage"]: sp.thread for sp in served["spans"]
+              if sp.name == "scheduler/flight"}
+    assert sorted(stages) == ["bind", "dispatch", "drain_fill", "resolve"]
+    assert stages.pop("bind").startswith("binder-")
+    assert set(stages.values()) == {"scheduler-loop"}
+
+
+def test_a_cycle_is_its_children_and_its_self_time(served):
+    """Every served scheduler/cycle span's wall is the sum of its direct
+    children's wall plus its self share, and the self shares sum to what
+    the self series grew by over the run."""
+    spans = served["spans"]
+    assert served["dropped"] == 0
+    kids: dict = {}
+    for sp in spans:
+        kids.setdefault(sp.parent_id, []).append(sp)
+    cycles = [sp for sp in spans if sp.name == "scheduler/cycle"]
+    assert cycles
+    self_sum = 0.0
+    for sp in cycles:
+        wall = sp.end - sp.start
+        children = sum(c.end - c.start for c in kids.get(sp.span_id, ()))
+        assert sp.child_wall_s == pytest.approx(children, abs=1e-6)
+        self_share = wall - sp.child_wall_s
+        assert 0.0 <= self_share <= wall + 1e-9
+        self_sum += self_share
+    before, after = served["before"], served["after"]
+    key = 'scheduler_span_self_seconds_total{span="scheduler/cycle"}'
+    assert after[key] - before.get(key, 0.0) == pytest.approx(
+        self_sum, abs=1e-6 * len(cycles))
+    blocked = 'scheduler_span_self_blocked_seconds_total{span="scheduler/cycle"}'
+    assert 0.0 <= after[blocked] - before.get(blocked, 0.0) <= self_sum + 1e-6
 
 
 def test_served_threads_have_names_and_their_cpu_series_grow(served):
@@ -383,9 +424,14 @@ def test_every_span_and_series_of_the_account_feeds_a_metric_file():
         m.name for m in REGISTRY._metrics.values()}
     assert collected and collected <= read, sorted(collected - read)
     assert "scheduler_queue_wait_seconds_sum" in read
-    # the collector's two series (sched/gcpolicy.py) and the encoder's
-    # template store's (encode/snapshot.py), each by its own file
+    # the tracer's self time (utils/tracing.py), the collector's two
+    # series (sched/gcpolicy.py) and the encoder's template store's
+    # (encode/snapshot.py), each by its own file
     for series_name, metric in (
+            ("scheduler_span_self_seconds_total",
+             "cycle_self_ms_per_drain.burst"),
+            ("scheduler_span_self_blocked_seconds_total",
+             "cycle_self_blocked_ms_per_drain.burst"),
             ("scheduler_gc_pause_seconds_total",
              "gc_pause_us_per_pod_event.burst"),
             ("scheduler_gc_collections_total",
@@ -398,6 +444,70 @@ def test_every_span_and_series_of_the_account_feeds_a_metric_file():
             spec = json.load(f)
         assert spec["args"]["num"][0].split("{")[0] == series_name
         assert spec["reader"] == "series_ratio"  # absent reads None, not 0
+
+
+# ------------------------------------------------------------- bulk bind
+
+def test_bind_bulk_handles_every_result_before_the_records_and_events(
+        monkeypatch):
+    """_bind_bulk's three passes (result, flight record, event) keep what
+    each pod gets: a pod bound stays assumed, gets one flight record and
+    one Scheduled event; a pod gone is forgotten quietly; a pod whose bind
+    failed is forgotten, requeued and counted an error. Every result is
+    handled before the first record or event."""
+    from kubernetes_tpu.metrics.registry import SCHEDULE_ATTEMPTS
+    from kubernetes_tpu.sched import scheduler as scheduler_mod
+    from kubernetes_tpu.sched.cache import SchedulerCache
+    from kubernetes_tpu.sched.scheduler import Scheduler
+    from kubernetes_tpu.utils.tracing import FlightRecorder
+    cache = SchedulerCache()
+    cache.add_node(make_node("n0").capacity(
+        {"cpu": "8", "memory": "16Gi", "pods": "32"}).obj())
+    queue = SchedulingQueue(backoff_initial=600.0, backoff_max=600.0)
+    pods = [make_pod(f"bulk-p{i}").req({"cpu": "100m"}).obj()
+            for i in range(3)]
+    keys = [p.key for p in pods]
+    sched = Scheduler(SchedulerConfiguration(batch_size=4), cache, queue,
+                      lambda pod, node: True,
+                      bulk_binder=lambda pairs: [True, None, False])
+    flight = FlightRecorder(enabled=True)
+    monkeypatch.setattr(scheduler_mod, "FLIGHT", flight)
+    for k in keys:
+        flight.record(k, "informer")
+    events = []
+
+    class Recorder:
+        def event(self, obj, type_, reason, message):
+            # the results pass is over: the failed pod is already queued
+            events.append((obj.key, type_, reason, set(queue._entries)))
+
+    sched.recorder = Recorder()
+    pairs = [(p, "n0") for p in pods]
+    cache.assume_many(pairs)
+    errors = SCHEDULE_ATTEMPTS.get({"result": "error"})
+    TRACER.reset()
+    try:
+        sched._bind_bulk(pairs)
+    finally:
+        sched.close()
+    assert [cache.is_assumed_or_bound(k) for k in keys] == [
+        True, False, False]
+    assert set(queue._entries) == {keys[2]}
+    assert queue._entries[keys[2]].attempts == 1
+    assert queue.stats()["backoff"] == 1
+    assert SCHEDULE_ATTEMPTS.get({"result": "error"}) == errors + 1
+    assert events == [(keys[0], "Normal", "Scheduled", {keys[2]})]
+    assert [[st["stage"] for st in flight.timeline(k)] for k in keys] == [
+        ["informer", "bind"], ["informer"], ["informer"]]
+    spans = {sp.name: sp for sp in TRACER.spans()}
+    bulk = spans["scheduler/bind_bulk"]
+    for name in ("scheduler/bind_call", "scheduler/flight",
+                 "scheduler/bind_events"):
+        assert spans[name].parent_id == bulk.span_id, name
+    assert spans["scheduler/flight"].attributes == {"stage": "bind",
+                                                    "pods": 1}
+    assert spans["scheduler/bind_events"].attributes == {"pods": 1}
+    TRACER.reset()
 
 
 # ------------------------------------------------------------- gang rounds

@@ -54,8 +54,17 @@ NEW_METRICS = {
 }
 LISTED = [n for n, cells in NEW_METRICS.items() if cells is not None]
 # entries later PRs appended after this deployment's, each over all four
-# cells (PR 38: the encoder's template store)
-LATER = ["pod_template_hit_share.burst"]
+# cells (PR 38: the encoder's template store; PR 39: the loop's, binders'
+# and events sink's self time and spans)
+LATER = ["pod_template_hit_share.burst",
+         "cycle_self_ms_per_drain.burst",
+         "cycle_self_blocked_ms_per_drain.burst",
+         "batch_head_ms_per_drain.burst",
+         "resolve_head_ms_per_drain.burst",
+         "flight_ms_per_drain.burst",
+         "bind_events_ms_per_drain.burst",
+         "events_flush_ms_per_drain.burst",
+         "stage_release_ms_per_drain.burst"]
 UNSCHEDULABLE = 'scheduler_schedule_attempts_total{result="unschedulable"}'
 DRAINS = "scheduler_pipeline_depth_count"
 
